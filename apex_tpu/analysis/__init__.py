@@ -19,6 +19,9 @@ Entry points:
   and compare_bench comm gates are stated in it);
 - :func:`check_shard_specs` — standalone PartitionSpec-vs-mesh
   verification (the mesh-rebase pre-trace gate);
+- :func:`kernel_inventory` — every ``pallas_call`` a program traces
+  to, with its name and whether it is compiled or interpreted (what
+  ``chip_smoke.py`` asserts the kernels from);
 - ``RULES`` — the rule registry (``donation``, ``host_sync``,
   ``dtype_flow``, ``constants``, ``packing``, ``scopes``,
   ``collectives``, ``sharding``).
@@ -47,7 +50,13 @@ from .rules import (  # noqa: F401
     check_pack_spec,
     check_reshard,
 )
-from .walk import WalkCtx, collect_consts, walk  # noqa: F401
+from .walk import (  # noqa: F401
+    KernelRecord,
+    WalkCtx,
+    collect_consts,
+    kernel_inventory,
+    walk,
+)
 
 __all__ = [
     "AuditConfig",
@@ -55,6 +64,7 @@ __all__ = [
     "CollectiveBudget",
     "CollectiveRecord",
     "Finding",
+    "KernelRecord",
     "RULES",
     "SEVERITIES",
     "StepTrace",
@@ -68,6 +78,7 @@ __all__ = [
     "collect_consts",
     "collective_inventory",
     "comm_volume",
+    "kernel_inventory",
     "trace_step",
     "walk",
 ]

@@ -143,7 +143,8 @@ def _donated_flags(closed, n_leaves: int, args: Tuple,
             if int(argnum) in donate:
                 flags[i] = True
     jaxpr = closed.jaxpr
-    if len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "pjit":
+    # jax 0.9 spells the jit call primitive "jit" (older: "pjit")
+    if len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "jit":
         eqn = jaxpr.eqns[0]
         don = eqn.params.get("donated_invars")
         if don is not None:

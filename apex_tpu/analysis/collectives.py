@@ -442,21 +442,21 @@ def rule_sharding(trace, cfg) -> List[Finding]:
             sizes = _axis_sizes(mesh)
         except Exception:  # pragma: no cover - mesh API drift
             continue
-        in_names = eqn.params.get("in_names") or ()
-        out_names = eqn.params.get("out_names") or ()
-        for io, names, vars_ in (("in", in_names, eqn.invars),
-                                 ("out", out_names, eqn.outvars)):
+        in_specs = eqn.params.get("in_specs") or ()
+        out_specs = eqn.params.get("out_specs") or ()
+        for io, names, vars_ in (("in", in_specs, eqn.invars),
+                                 ("out", out_specs, eqn.outvars)):
             shapes = [getattr(v, "aval", None) and tuple(v.aval.shape)
                       for v in vars_]
             out.extend(
                 f for f in check_shard_specs(
                     {a: s for a, s in sizes.items()}, names,
-                    shapes=shapes, where=f"{where} [{io}_names]")
+                    shapes=shapes, where=f"{where} [{io}_specs]")
             )
         # replicated operands a named axis could shard, largest first
         repl = []
-        for i, (names, v) in enumerate(zip(in_names, eqn.invars)):
-            if names or not hasattr(v, "aval"):
+        for i, (spec, v) in enumerate(zip(in_specs, eqn.invars)):
+            if any(_norm_spec(spec)) or not hasattr(v, "aval"):
                 continue
             b = _aval_bytes(v.aval)
             if b >= replicated_bytes:

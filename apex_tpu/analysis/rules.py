@@ -43,7 +43,12 @@ import numpy as np
 
 from ..multi_tensor_apply.packing import ROW, PackSpec
 from .report import Finding
-from .walk import name_stack_str, transparent_subjaxprs, walk
+from .walk import (
+    name_stack_str,
+    pallas_kernel_name,
+    transparent_subjaxprs,
+    walk,
+)
 
 _CALLBACK_PRIMS = ("debug_callback", "io_callback", "pure_callback")
 _MATMUL_PRIMS = ("dot_general", "conv_general_dilated")
@@ -661,7 +666,7 @@ def rule_scopes(trace, cfg: AuditConfig) -> List[Finding]:
         name = eqn.primitive.name
         ns = name_stack_str(eqn)
         if name == "pallas_call" and "apex_tpu." not in ns:
-            kname = getattr(eqn.params.get("name_and_src_info"), "name", "?")
+            kname = pallas_kernel_name(eqn)
             out.append(Finding(
                 "scopes", "unscoped_kernel", "warning",
                 f"pallas_call kernel '{kname}' carries no apex_tpu.* "

@@ -18,8 +18,9 @@ surface.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator, List, Tuple
+from typing import Iterator, List, NamedTuple, Tuple
 
+import jax
 from jax._src import core as jax_core
 
 ClosedJaxpr = jax_core.ClosedJaxpr
@@ -135,3 +136,37 @@ def name_stack_str(eqn) -> str:
         return str(eqn.source_info.name_stack)
     except Exception:  # pragma: no cover - source info shape drift
         return ""
+
+
+def pallas_kernel_name(eqn) -> str:
+    """A ``pallas_call`` eqn's kernel name: its ``name=``, else the
+    kernel function's own name."""
+    return eqn.params["name"] or eqn.params["jaxpr"].debug_info.func_name
+
+
+class KernelRecord(NamedTuple):
+    """One ``pallas_call`` of a traced program."""
+
+    name: str        # the kernel's ``name=`` (its function name if unset)
+    compiled: bool   # False when the call runs under the interpreter
+
+
+def kernel_inventory(program, *args) -> List[KernelRecord]:
+    """Every ``pallas_call`` that ``program(*args)`` traces to, at any
+    depth (cond branches, scans, remat, custom-AD) — trace only, no
+    execution. ``program`` may also be an already-traced
+    ``ClosedJaxpr`` (``jax.jit(f).trace(*args).jaxpr``), which saves
+    re-tracing a large step.
+
+    Model code selects XLA paths silently when a gate says no, and
+    several wrappers flip ``interpret=True`` off-TPU; this is how a
+    caller proves from the program itself that the kernel it expects is
+    present and was handed to Mosaic rather than the interpreter.
+    """
+    closed = (program if isinstance(program, ClosedJaxpr)
+              else jax.make_jaxpr(program)(*args))
+    return [
+        KernelRecord(pallas_kernel_name(eqn), not eqn.params["interpret"])
+        for eqn, _ in walk(closed.jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    ]

@@ -41,6 +41,8 @@ from __future__ import annotations
 
 import os
 import shutil
+import threading
+import time
 from typing import Any, Optional
 
 import jax
@@ -69,6 +71,37 @@ def _checkpointer():
     import orbax.checkpoint as ocp
 
     return ocp.StandardCheckpointer()
+
+
+# orbax keys the signals between its save threads by a PROCESS-global
+# operation id: two saves in flight in one process (async manager
+# threads, in-process fake hosts) read each other's id, and the loser
+# waits out orbax's 300 s signal timeout. One save at a time per process.
+_SAVE_LOCK = threading.Lock()
+
+
+def _save_and_wait(ckptr, path: str, state: Pytree, *, force: bool) -> None:
+    with _SAVE_LOCK:
+        ckptr.save(path, state, force=force)
+        ckptr.wait_until_finished()
+
+
+def _sweep_failed_write(tmp: str) -> None:
+    """Remove a failed write's staging trees: ours and orbax's own
+    ``<tmp>.orbax-checkpoint-tmp`` sibling. orbax creates that sibling on
+    a background thread nothing joins, so after a save that failed
+    validation it can land a moment AFTER the error surfaced — look
+    again, briefly, until it has shown up."""
+    import glob
+
+    deadline = time.monotonic() + 1.0
+    while True:
+        staged = glob.glob(glob.escape(tmp) + ".orbax-checkpoint-tmp*")
+        for leftover in [tmp] + staged:
+            shutil.rmtree(leftover, ignore_errors=True)
+        if staged or time.monotonic() > deadline:
+            return
+        time.sleep(0.05)
 
 
 def fsync_file(path: str) -> None:
@@ -179,8 +212,7 @@ def save_checkpoint(path: str, state: Pytree, *, overwrite: bool = True,
             f"checkpoint exists at {path} and overwrite=False")
     ckptr = _checkpointer()
     if not staged or jax.process_count() > 1:
-        ckptr.save(path, state, force=overwrite)
-        ckptr.wait_until_finished()
+        _save_and_wait(ckptr, path, state, force=overwrite)
         return
     tmp = f"{path}.tmp-{os.getpid()}"
     # sweep stale partials — ours, and any whose writer pid is dead (a
@@ -189,19 +221,14 @@ def save_checkpoint(path: str, state: Pytree, *, overwrite: bool = True,
     # leak one state-size tree each)
     for stale in glob.glob(glob.escape(path) + ".tmp-*"):
         # matches both our staging dirs (<path>.tmp-<pid>) and orbax's
-        # own staging siblings (<path>.tmp-<pid>.orbax-checkpoint-tmp-N)
+        # own staging siblings (<path>.tmp-<pid>.orbax-checkpoint-tmp)
         m = re.search(r"\.tmp-(\d+)", os.path.basename(stale))
         if m is not None and stale_writer(int(m.group(1))):
             shutil.rmtree(stale, ignore_errors=True)
     try:
-        ckptr.save(tmp, state, force=True)
-        ckptr.wait_until_finished()
+        _save_and_wait(ckptr, tmp, state, force=True)
     except BaseException:
-        # orbax stages into its own `<tmp>.orbax-checkpoint-tmp-*`
-        # sibling before finalizing; sweep both on failure
-        for leftover in [tmp] + glob.glob(
-                glob.escape(tmp) + ".orbax-checkpoint-tmp-*"):
-            shutil.rmtree(leftover, ignore_errors=True)
+        _sweep_failed_write(tmp)
         raise
     if os.path.exists(path):
         if not overwrite:  # appeared during the write

@@ -76,31 +76,8 @@ _warned_fully_masked = False
 
 
 def _is_traced(x) -> bool:
-    """True for values that are abstract at this point (inside a trace).
-
-    Deliberately avoids ``isinstance(x, jax.core.Tracer)`` — the
-    ``jax.core`` re-export is semi-private and deprecation-warned in
-    newer JAX. ``jax.core.is_concrete`` is preferred when present;
-    otherwise an ``aval``-based check that tolerates API moves: a value
-    with a non-concrete aval cannot be materialised by ``jax.device_get``.
-    """
-    if not hasattr(x, "aval"):
-        return False  # numpy array / python scalar: concrete
-    core = getattr(jax, "core", None)
-    is_concrete = getattr(core, "is_concrete", None)
-    if is_concrete is not None:
-        try:
-            return not is_concrete(x)
-        except Exception:
-            pass
-    tracer_cls = getattr(core, "Tracer", None)
-    if tracer_cls is not None:
-        return isinstance(x, tracer_cls)
-    try:  # last resort: concrete values materialise, tracers raise
-        jax.device_get(x)
-        return False
-    except Exception:
-        return True
+    """True for values that are abstract at this point (inside a trace)."""
+    return not jax.core.is_concrete(x)
 
 
 def _maybe_warn_fully_masked(key_mask):

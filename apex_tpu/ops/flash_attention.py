@@ -51,11 +51,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; CPU-only envs use interpret mode or the XLA path
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -119,7 +115,7 @@ def flash_attention_available(
         return False
     if interpret:
         return True
-    if pltpu is None or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return False
     # need tileable seq blocks and a head dim the MXU can use
     return s_q % 8 == 0 and s_k % 8 == 0 and d <= 256
@@ -485,8 +481,6 @@ def _fwd(
 
 
 def _compiler_params():
-    if pltpu is None:
-        return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
@@ -781,6 +775,7 @@ def _bwd(
                 n_heads=n, have_bias=have_bias, emit_dbias=emit_dbias,
                 have_mask=have_mask, have_segs=have_segs, dropout_p=dropout_p,
             ),
+            name="apex_tpu_flash_bwd_dq",
             grid=(b, n, n_q, n_k),
             in_specs=[
                 q_spec(lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -826,6 +821,7 @@ def _bwd(
             n_heads=n, have_bias=have_bias, have_mask=have_mask,
             have_segs=have_segs, dropout_p=dropout_p, emit_dq=fuse_dq,
         ),
+        name="apex_tpu_flash_bwd_dkv",
         grid=(b, n, n_k, n_q),
         in_specs=[
             q_spec(lambda ib, ih, ik, iq: (ib, ih, iq, 0)),

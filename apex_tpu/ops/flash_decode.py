@@ -54,11 +54,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is importable on CPU-only hosts too; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -66,18 +62,24 @@ _NEG_INF = -1e30
 def _kernel_ok(use_kernel: Optional[bool], interpret: bool) -> bool:
     """Kernel path on TPU or when explicitly interpreted; XLA fallback
     elsewhere (the ``packed_optimizer.py`` selection contract)."""
-    if pltpu is None:
-        return False
     if use_kernel is not None:
         return bool(use_kernel)
     return bool(interpret) or jax.default_backend() == "tpu"
 
 
-def flash_decode_available(page_size: int, head_dim: int) -> bool:
-    """Kernel tileability: the page is the sublane dim of the K/V blocks
-    (Mosaic wants multiples of 8) and head_dim <= 256 keeps the MXU
-    happy (same rule as ``flash_attention_available``)."""
-    return page_size % 8 == 0 and head_dim <= 256
+def page_sublanes(dtype) -> int:
+    """Rows of one vreg tile for ``dtype``: 8 at 32 bits, 16 at 16 bits
+    (bf16 packs two rows per sublane), 32 at 8 bits."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def flash_decode_available(page_size: int, head_dim: int,
+                           dtype=jnp.float32) -> bool:
+    """Kernel tileability: the page is the sublane dim of the K/V blocks,
+    so it must be a whole number of ``dtype`` tiles (8 tokens of bf16 is
+    half a 16 x 128 tile), and head_dim <= 256 (the
+    ``flash_attention_available`` bound)."""
+    return page_size % page_sublanes(dtype) == 0 and head_dim <= 256
 
 
 # ---------------------------------------------------------------------------
@@ -87,12 +89,18 @@ def flash_decode_available(page_size: int, head_dim: int) -> bool:
 
 def _decode_kernel(
     pt_ref, len_ref,  # scalar-prefetch: [b, mp] page table, [b] kv lens
-    q_ref,            # [1, n, d] this slot's query
+    q_ref,            # [1, n, 1, d] this slot's query
     k_ref, v_ref,     # [1, n, ps, d] the page pt_ref[b, i]
-    o_ref,            # [1, n, d]
-    m_scr, l_scr, acc_scr,
+    o_ref,            # [1, n, 1, d]
+    m_scr, l_scr, acc_scr,  # [n, 1, 1], [n, 1, 1], [n, 1, d]
     *, scale, page_size, n_pages_per_seq,
 ):
+    """One query row per head is a matrix-VECTOR product, so the scores
+    and the value sum run on the VPU as broadcast-multiply-reduce. Every
+    intermediate stays rank 3 ``[head, token, dim]`` with ``keepdims``
+    reductions — heads on the untiled major dim, tokens on sublanes, dim
+    on lanes, the K/V page's own layout — so nothing is relaid out
+    between the page load and the accumulator."""
     b, i = pl.program_id(0), pl.program_id(1)
 
     @pl.when(i == 0)
@@ -107,38 +115,31 @@ def _decode_kernel(
     # the table points them at the garbage page — but no flops/scratch)
     @pl.when(i * page_size < kv_len)
     def _compute():
-        # fp32 q, scale folded in (one row per head — negligible work)
-        q = q_ref[0].astype(jnp.float32) * scale          # [n, d]
-        k = k_ref[0]                                      # [n, ps, d]
-        # s[n, ps] = per-head q . k — head-major pages make this a
-        # batched dot with NO transpose
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [n, ps]
+        q = q_ref[0].astype(jnp.float32) * scale          # [n, 1, d]
+        k = k_ref[0].astype(jnp.float32)                  # [n, ps, d]
+        s = jnp.sum(q * k, axis=2, keepdims=True)         # [n, ps, 1]
         pos = i * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(pos < kv_len, s, _NEG_INF)
 
-        m_prev = m_scr[:, :1]                             # [n, 1]
+        m_prev = m_scr[:]                                 # [n, 1, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         p = jnp.where(s <= _NEG_INF / 2, 0.0, p)          # ragged tail
         alpha = jnp.exp(m_prev - m_new)
         alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0, alpha)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0],
-            (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [n, d]
+        # p is rounded to the pool dtype before it weighs V — the MXU
+        # flash kernels' convention, kept so all paths agree
+        p_v = p.astype(v_ref.dtype).astype(jnp.float32)
+        pv = jnp.sum(p_v * v_ref[0].astype(jnp.float32), axis=1,
+                     keepdims=True)                       # [n, 1, d]
         acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[:] = m_new
 
     @pl.when(i == n_pages_per_seq - 1)
     def _finish():
-        l = l_scr[:, :1]
+        l = l_scr[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         # kv_len == 0 slots never ran _compute: acc/l are zero -> zeros out
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
@@ -155,35 +156,31 @@ def _decode_pallas(q, k_pages, v_pages, page_table, kv_lens, scale,
         num_scalar_prefetch=2,
         grid=(b, mp),
         in_specs=[
-            pl.BlockSpec((1, n, d), lambda b, i, pt, ln: (b, 0, 0)),
+            pl.BlockSpec((1, n, 1, d), lambda b, i, pt, ln: (b, 0, 0, 0)),
             pl.BlockSpec((1, n, ps, d),
                          lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
             pl.BlockSpec((1, n, ps, d),
                          lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, n, d), lambda b, i, pt, ln: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, n, 1, d),
+                               lambda b, i, pt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((n, 128), jnp.float32),
-            pltpu.VMEM((n, 128), jnp.float32),
-            pltpu.VMEM((n, d), jnp.float32),
+            pltpu.VMEM((n, 1, 1), jnp.float32),
+            pltpu.VMEM((n, 1, 1), jnp.float32),
+            pltpu.VMEM((n, 1, d), jnp.float32),
         ],
     )
-    # jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-    cp_cls = getattr(pltpu, "CompilerParams",
-                     getattr(pltpu, "TPUCompilerParams", None))
-    compiler_params = None
-    if cp_cls is not None:
-        compiler_params = cp_cls(
-            dimension_semantics=("parallel", "arbitrary"))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         name="apex_tpu_flash_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n, d), q.dtype),
-        compiler_params=compiler_params,
+        out_shape=jax.ShapeDtypeStruct((b, n, 1, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q.reshape(b, n, 1, d), k_pages, v_pages)
+    return out.reshape(b, n, d)
 
 
 # ---------------------------------------------------------------------------
@@ -277,10 +274,12 @@ def flash_decode(
                            kv_lens.astype(jnp.int32), float(scale))
     if not interpret and jax.default_backend() != "tpu":
         interpret = True
-    if not flash_decode_available(k_pages.shape[2], q.shape[2]):
+    if not flash_decode_available(k_pages.shape[2], q.shape[2],
+                                  k_pages.dtype):
         raise ValueError(
-            f"flash_decode kernel needs page_size {k_pages.shape[2]} % 8 "
-            f"== 0 and head_dim {q.shape[2]} <= 256 "
+            f"flash_decode kernel needs page_size {k_pages.shape[2]} % "
+            f"{page_sublanes(k_pages.dtype)} == 0 ({k_pages.dtype} tiles) "
+            f"and head_dim {q.shape[2]} <= 256 "
             "(use_kernel=False for the XLA fallback)")
     return _decode_pallas(q, k_pages, v_pages, page_table,
                           kv_lens.astype(jnp.int32), float(scale),

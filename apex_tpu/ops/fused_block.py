@@ -53,11 +53,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; import lazily so CPU-only envs still work
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _keep_mask
 from .layer_norm import _row_block, _use_pallas
@@ -149,9 +145,7 @@ def _row_spec(br: int, n: int):
 
 
 def _seed_spec():
-    if pltpu is not None:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec((1,), lambda i: (0,))  # pragma: no cover
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------

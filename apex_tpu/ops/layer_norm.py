@@ -61,10 +61,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-only module; import lazily so CPU-only envs still work
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
 
 
 def _use_pallas(hidden: int, interpret: bool, *, fused: bool = False) -> bool:
@@ -86,23 +82,23 @@ def _use_pallas(hidden: int, interpret: bool, *, fused: bool = False) -> bool:
         # APEX_TPU_FORCE_PALLAS_LN.
         if not os.environ.get("APEX_TPU_FORCE_PALLAS_LN"):
             return False
-    return (
-        pltpu is not None
-        and jax.default_backend() == "tpu"
-        and hidden % 128 == 0
-    )
+    return jax.default_backend() == "tpu" and hidden % 128 == 0
 
 
-def _row_block(rows: int, hidden: int) -> int:
+def _row_block(rows: int, hidden: int, budget_bytes: int = 1 << 20) -> int:
     # whole hidden stays in VMEM; pick the largest row block that divides
-    # rows and keeps the block under ~1MB fp32. Empirically 256-row blocks
+    # rows and keeps the block under ``budget_bytes`` (~1MB) of fp32. Empirically 256-row blocks
     # run at memory bandwidth while 512-row blocks hit a Mosaic DMA
     # pathology ~10x slower (measured on v5e at hidden 1024).
-    budget = max(1, (1024 * 1024) // max(hidden * 4, 1))
-    for cand in (256, 128, 64, 32, 16, 8, 4, 2, 1):
+    # Mosaic takes a block's row dim as a multiple of 8 sublanes or the
+    # whole array — and the backward kernels accumulate dgamma/dbias over
+    # every row they see, so a ragged last block is not an option: a row
+    # count no multiple of 8 divides runs as ONE block.
+    budget = max(8, budget_bytes // max(hidden * 4, 1))
+    for cand in (256, 128, 64, 32, 16, 8):
         if cand <= budget and rows % cand == 0:
             return cand
-    return 1
+    return rows
 
 
 # ---------------------------------------------------------------------------

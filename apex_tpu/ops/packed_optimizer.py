@@ -55,11 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is importable on CPU-only hosts too; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..multi_tensor_apply.packing import DEFAULT_CHUNK, ROW, _round_up
 
@@ -71,9 +67,7 @@ _NSCAL = 8  # fixed-width SMEM scalar bundle
 # ---------------------------------------------------------------------------
 def _kernel_ok(use_kernel: Optional[bool], interpret: bool) -> bool:
     """Kernel path on TPU or when explicitly interpreted; XLA fallback
-    elsewhere. ``use_kernel`` overrides (but never without pallas-tpu)."""
-    if pltpu is None:
-        return False
+    elsewhere. ``use_kernel`` overrides."""
     if use_kernel is not None:
         return bool(use_kernel)
     return bool(interpret) or jax.default_backend() == "tpu"
@@ -87,15 +81,21 @@ def _scalars(*vals) -> jax.Array:
     ).reshape(1, _NSCAL)
 
 
+# Sublane multiple every (rows, ROW) block keeps: bf16 tiles are 16 x 128
+# (fp32's 8 x 128 divides it), and grads/params ride these kernels in bf16.
+_SUBLANES = 16
+
+
 def _block_rows(n_rows: int, chunk_size: int) -> int:
-    """Rows per grid step: ``chunk_size`` elements, shrunk to the largest
-    divisor of ``n_rows`` (the buffer is chunk-padded by PackSpec, so the
-    spec's own chunk divides exactly; foreign chunk sizes still work)."""
-    want = max(1, int(chunk_size) // ROW)
-    b = min(want, n_rows)
-    while n_rows % b:
-        b -= 1
-    return b
+    """Rows per grid step: ``chunk_size`` elements rounded up to a
+    ``_SUBLANES`` multiple — or the whole buffer when it is smaller
+    (Mosaic accepts a block dim that is a tile multiple or the array's
+    own). The grid is ``pl.cdiv(n_rows, block)``: a ragged last block
+    reads past the end and its out-of-range rows are dropped on write,
+    which is exact here because every output is row-aligned with the
+    input."""
+    want = _round_up(max(1, int(chunk_size) // ROW), _SUBLANES)
+    return n_rows if n_rows <= want else want
 
 
 def _sspec():
@@ -108,12 +108,12 @@ def _tspec(b):
                         memory_space=pltpu.VMEM)
 
 
-def _rspec(b):
-    return pl.BlockSpec((1, b), lambda i: (i, 0), memory_space=pltpu.VMEM)
-
-
-def _flagspec():
-    return pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.VMEM)
+def _cspec(b):
+    """One value per row, as a ``(rows, 1)`` column: the block's rows sit
+    on sublanes exactly like the ``(b, ROW)`` data block's, so a
+    ``keepdims`` lane reduction stores (and a per-row coefficient
+    broadcasts) with no relayout."""
+    return pl.BlockSpec((b, 1), lambda i: (i, 0), memory_space=pltpu.VMEM)
 
 
 def _rows(flat: jax.Array) -> jax.Array:
@@ -125,24 +125,29 @@ def _rows(flat: jax.Array) -> jax.Array:
     return flat.reshape(n // ROW, ROW)
 
 
-def _pad_to_rows(flat: jax.Array,
-                 chunk_size: Optional[int] = None) -> Tuple[jax.Array, int]:
+def _pad_to_rows(flat: jax.Array) -> Tuple[jax.Array, int]:
     """Zero-pad an arbitrary 1-D buffer to a ROW multiple (zeros are
-    neutral for every op here: finite, |.|=0, scale->0).
-
-    With ``chunk_size``, pad further to a chunk multiple so
-    ``_block_rows`` always gets its full block — otherwise an awkward
-    (e.g. prime) row count would shrink the divisor search toward
-    1-row blocks and a grid of n_rows steps (launch overhead instead of
-    one streaming sweep). Costs at most one chunk (256 KB f32) of zero
-    padding."""
+    neutral for every op here: finite, |.|=0, scale->0). No chunk
+    padding: ``_block_rows`` keeps full-size blocks for any row count
+    and the grid's ragged last block covers the remainder."""
     n = flat.shape[0]
     total = _round_up(max(n, 1), ROW)
-    if chunk_size:
-        total = _round_up(total, _round_up(int(chunk_size), ROW))
     if total != n:
         flat = jnp.concatenate([flat, jnp.zeros((total - n,), flat.dtype)])
     return flat, n
+
+
+def _nonfinite(x: jax.Array) -> jax.Array:
+    """fp32 {0, 1}: 1 where ``x`` is inf or nan."""
+    return jnp.where(jnp.isfinite(x), 0.0, 1.0)
+
+
+def _row_flags(x: jax.Array) -> jax.Array:
+    """``(rows, 1)`` fp32, > 0 where the row holds a non-finite value.
+    The flat scale/axpby kernels always flag per ROW (1/1024 of the
+    sweep's traffic): a per-chunk scalar would have to leave the kernel
+    through a one-element vector store, which Mosaic does not tile."""
+    return jnp.max(_nonfinite(x), axis=1, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
@@ -262,7 +267,8 @@ def packed_adam_apply(
 
     outs = pl.pallas_call(
         body,
-        grid=(R // B,),
+        name="apex_tpu_packed_adam",
+        grid=(pl.cdiv(R, B),),
         in_specs=[_sspec(), _tspec(B), _tspec(B), _tspec(B), _tspec(B)],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -373,7 +379,8 @@ def packed_sgd_apply(
 
     outs = pl.pallas_call(
         body,
-        grid=(R // B,),
+        name="apex_tpu_packed_sgd",
+        grid=(pl.cdiv(R, B),),
         in_specs=[_sspec(), _tspec(B), _tspec(B), _tspec(B)],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -452,20 +459,21 @@ def packed_lamb_stage1(
         u_out[:] = u
         m_out[:] = new_m
         v_out[:] = new_v
-        ru_out[0, :] = jnp.sum(u * u, axis=1)
-        rp_out[0, :] = jnp.sum(p32 * p32, axis=1)
+        ru_out[:] = jnp.sum(u * u, axis=1, keepdims=True)
+        rp_out[:] = jnp.sum(p32 * p32, axis=1, keepdims=True)
 
     u, new_m, new_v, ru, rp = pl.pallas_call(
         body,
-        grid=(R // B,),
+        name="apex_tpu_packed_lamb_stage1",
+        grid=(pl.cdiv(R, B),),
         in_specs=[_sspec(), _tspec(B), _tspec(B), _tspec(B), _tspec(B)],
-        out_specs=[_tspec(B), _tspec(B), _tspec(B), _rspec(B), _rspec(B)],
+        out_specs=[_tspec(B), _tspec(B), _tspec(B), _cspec(B), _cspec(B)],
         out_shape=[
             jax.ShapeDtypeStruct((R, ROW), jnp.float32),
             jax.ShapeDtypeStruct((R, ROW), jnp.float32),
             jax.ShapeDtypeStruct((R, ROW), jnp.float32),
-            jax.ShapeDtypeStruct((R // B, B), jnp.float32),
-            jax.ShapeDtypeStruct((R // B, B), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         input_output_aliases={2: 1, 3: 2},
         interpret=interpret,
@@ -503,8 +511,8 @@ def packed_scale_update(
     B = _block_rows(R, chunk_size)
 
     def body(s_ref, u_ref, p_ref, c_ref, *outs):
-        coef = c_ref[0, :][:, None]
-        new_p = p_ref[:].astype(jnp.float32) - s_ref[0, 0] * coef * u_ref[:]
+        new_p = (p_ref[:].astype(jnp.float32)
+                 - s_ref[0, 0] * c_ref[:] * u_ref[:])
         outs[0][:] = new_p.astype(param_dtype)
         if write_master:
             outs[1][:] = new_p
@@ -518,14 +526,15 @@ def packed_scale_update(
         aliases[2] = 1  # flat_src -> new master
     outs = pl.pallas_call(
         body,
-        grid=(R // B,),
-        in_specs=[_sspec(), _tspec(B), _tspec(B), _rspec(B)],
+        name="apex_tpu_packed_scale_update",
+        grid=(pl.cdiv(R, B),),
+        in_specs=[_sspec(), _tspec(B), _tspec(B), _cspec(B)],
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
     )(_scalars(lr), _rows(flat_u), _rows(flat_src),
-      row_coef.reshape(R // B, B))
+      row_coef.reshape(R, 1))
     p_out = outs[0].reshape(-1)
     return p_out, (outs[1].reshape(-1) if write_master else None)
 
@@ -581,16 +590,16 @@ def packed_novograd_apply(
     B = _block_rows(R, chunk_size)
 
     def body(s_ref, g_ref, m_ref, p_ref, d_ref, p_out, m_out):
-        denom = d_ref[0, :][:, None]
-        new_p, new_m = math(g_ref[:], m_ref[:], p_ref[:], denom,
+        new_p, new_m = math(g_ref[:], m_ref[:], p_ref[:], d_ref[:],
                             s_ref[0, 0], s_ref[0, 1], s_ref[0, 2])
         p_out[:] = new_p.astype(param_dtype)
         m_out[:] = new_m
 
     p_out, new_m = pl.pallas_call(
         body,
-        grid=(R // B,),
-        in_specs=[_sspec(), _tspec(B), _tspec(B), _tspec(B), _rspec(B)],
+        name="apex_tpu_packed_novograd",
+        grid=(pl.cdiv(R, B),),
+        in_specs=[_sspec(), _tspec(B), _tspec(B), _tspec(B), _cspec(B)],
         out_specs=[_tspec(B), _tspec(B)],
         out_shape=[
             jax.ShapeDtypeStruct((R, ROW), param_dtype),
@@ -600,7 +609,7 @@ def packed_novograd_apply(
         interpret=interpret,
     )(_scalars(inv_scale, lr, bc1),
       _rows(flat_g), _rows(flat_m), _rows(flat_src),
-      row_denom.reshape(R // B, B))
+      row_denom.reshape(R, 1))
     return p_out.reshape(-1), new_m.reshape(-1)
 
 
@@ -625,26 +634,27 @@ def packed_row_reduce(
         raise ValueError(f"unknown row reduction {op!r}")
 
     def red(x):
-        return (jnp.sum(x * x, axis=1) if op == "sqsum"
-                else jnp.max(jnp.abs(x), axis=1))
+        return (jnp.sum(x * x, axis=1, keepdims=True) if op == "sqsum"
+                else jnp.max(jnp.abs(x), axis=1, keepdims=True))
 
     if not _kernel_ok(use_kernel, interpret):
         x = flat.reshape(-1, ROW).astype(jnp.float32)
-        return red(x * jnp.asarray(inv_scale, jnp.float32))
+        return red(x * jnp.asarray(inv_scale, jnp.float32)).reshape(-1)
 
     R = flat.shape[0] // ROW
     B = _block_rows(R, chunk_size)
 
     def body(s_ref, x_ref, out_ref):
         x = x_ref[:].astype(jnp.float32) * s_ref[0, 0]
-        out_ref[0, :] = red(x)
+        out_ref[:] = red(x)
 
     out = pl.pallas_call(
         body,
-        grid=(R // B,),
+        name="apex_tpu_packed_row_reduce",
+        grid=(pl.cdiv(R, B),),
         in_specs=[_sspec(), _tspec(B)],
-        out_specs=_rspec(B),
-        out_shape=jax.ShapeDtypeStruct((R // B, B), jnp.float32),
+        out_specs=_cspec(B),
+        out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
         interpret=interpret,
     )(_scalars(inv_scale), _rows(flat))
     return out.reshape(-1)
@@ -672,40 +682,35 @@ def packed_row_stats(
     All outputs fp32 ``(rows,)`` covering the input's rows (zero padding
     added here is finite and reduction-neutral).
     """
-    flat, n = _pad_to_rows(flat, chunk_size)
-    rows_n = -(-n // ROW)
+    flat, _ = _pad_to_rows(flat)
 
     def stats(x):
-        return (jnp.sum(x * x, axis=1),
-                jnp.max(jnp.abs(x), axis=1),
-                jnp.sum((~jnp.isfinite(x)).astype(jnp.float32), axis=1))
+        return (jnp.sum(x * x, axis=1, keepdims=True),
+                jnp.max(jnp.abs(x), axis=1, keepdims=True),
+                jnp.sum(_nonfinite(x), axis=1, keepdims=True))
 
     if not _kernel_ok(use_kernel, interpret):
         x = flat.reshape(-1, ROW).astype(jnp.float32)
         x = x * jnp.asarray(inv_scale, jnp.float32)
-        sq, ma, nf = stats(x)
-        return sq[:rows_n], ma[:rows_n], nf[:rows_n]
+        return tuple(o.reshape(-1) for o in stats(x))
 
     R = flat.shape[0] // ROW
     B = _block_rows(R, chunk_size)
 
     def body(s_ref, x_ref, sq_ref, ma_ref, nf_ref):
         x = x_ref[:].astype(jnp.float32) * s_ref[0, 0]
-        sq, ma, nf = stats(x)
-        sq_ref[0, :] = sq
-        ma_ref[0, :] = ma
-        nf_ref[0, :] = nf
+        sq_ref[:], ma_ref[:], nf_ref[:] = stats(x)
 
     sq, ma, nf = pl.pallas_call(
         body,
-        grid=(R // B,),
+        name="apex_tpu_packed_row_stats",
+        grid=(pl.cdiv(R, B),),
         in_specs=[_sspec(), _tspec(B)],
-        out_specs=[_rspec(B), _rspec(B), _rspec(B)],
-        out_shape=[jax.ShapeDtypeStruct((R // B, B), jnp.float32)] * 3,
+        out_specs=[_cspec(B), _cspec(B), _cspec(B)],
+        out_shape=[jax.ShapeDtypeStruct((R, 1), jnp.float32)] * 3,
         interpret=interpret,
     )(_scalars(inv_scale), _rows(flat))
-    return (sq.reshape(-1)[:rows_n], ma.reshape(-1)[:rows_n],
-            nf.reshape(-1)[:rows_n])
+    return sq.reshape(-1), ma.reshape(-1), nf.reshape(-1)
 
 
 packed_row_stats.accepts_chunk_size = True
@@ -724,12 +729,11 @@ def multi_tensor_l2norm_flat(
     ``(norm, row_sq)`` — ``row_sq`` are the per-ROW partials (segment-sum
     them with ``PackSpec.row_leaf_ids()`` for per-tensor norms, the
     ``per_tensor`` mode of ``multi_tensor_l2norm_kernel.cu``)."""
-    flat, n = _pad_to_rows(flat, chunk_size)
+    flat, _ = _pad_to_rows(flat)
     row_sq = packed_row_reduce(
         flat, op="sqsum", inv_scale=inv_scale, chunk_size=chunk_size,
         use_kernel=use_kernel, interpret=interpret)
-    # chunk padding added whole zero rows; report only the input's rows
-    return jnp.sqrt(jnp.sum(row_sq)), row_sq[:-(-n // ROW)]
+    return jnp.sqrt(jnp.sum(row_sq)), row_sq
 
 
 multi_tensor_l2norm_flat.accepts_chunk_size = True
@@ -749,17 +753,16 @@ def multi_tensor_scale_flat(
     """``out = flat * scale`` with non-finite flagging, one chunked sweep
     (``csrc/multi_tensor_scale_kernel.cu``). Returns ``(out, found_inf)``.
 
-    ``per_row_flags=True`` widens the flag output from per-chunk to
-    per-ROW and returns ``(out, found_inf, row_bad)`` with ``row_bad`` a
-    bool ``(rows,)`` over the input's rows — same sweep, no extra read.
+    ``per_row_flags=True`` also returns the sweep's per-ROW flags:
+    ``(out, found_inf, row_bad)`` with ``row_bad`` a bool ``(rows,)``
+    over the input's rows — same sweep, no extra read.
     Rows are leaf-aligned under ``PackSpec``, so segment-reducing
     ``row_bad`` with ``row_leaf_ids()`` names exactly the non-finite
     leaves (the overflow-provenance path of
     ``apex_tpu.telemetry.numerics``).
     """
     out_dtype = jnp.dtype(out_dtype) if out_dtype is not None else flat.dtype
-    padded, n = _pad_to_rows(flat, chunk_size)
-    rows_n = -(-n // ROW)
+    padded, n = _pad_to_rows(flat)
 
     if not _kernel_ok(use_kernel, interpret):
         if not per_row_flags:
@@ -770,8 +773,7 @@ def multi_tensor_scale_flat(
         # (padding is trailing zeros, so the slice recovers the result)
         pad32 = padded.astype(jnp.float32) * jnp.asarray(scale, jnp.float32)
         out = pad32[:n].astype(out_dtype)
-        row_bad = ~jnp.all(
-            jnp.isfinite(pad32).reshape(-1, ROW), axis=1)[:rows_n]
+        row_bad = ~jnp.all(jnp.isfinite(pad32).reshape(-1, ROW), axis=1)
         return out, jnp.any(row_bad), row_bad
 
     R = padded.shape[0] // ROW
@@ -779,30 +781,25 @@ def multi_tensor_scale_flat(
 
     def body(s_ref, x_ref, out_ref, flag_ref):
         out32 = x_ref[:].astype(jnp.float32) * s_ref[0, 0]
-        fin = jnp.isfinite(out32)
-        if per_row_flags:
-            flag_ref[0, :] = 1.0 - jnp.all(fin, axis=1).astype(jnp.float32)
-        else:
-            flag_ref[0, 0] = 1.0 - jnp.all(fin).astype(jnp.float32)
+        flag_ref[:] = _row_flags(out32)
         out_ref[:] = out32.astype(out_dtype)
 
     out, flags = pl.pallas_call(
         body,
-        grid=(R // B,),
+        name="apex_tpu_multi_tensor_scale_flat",
+        grid=(pl.cdiv(R, B),),
         in_specs=[_sspec(), _tspec(B)],
-        out_specs=[_tspec(B),
-                   _rspec(B) if per_row_flags else _flagspec()],
+        out_specs=[_tspec(B), _cspec(B)],
         out_shape=[
             jax.ShapeDtypeStruct((R, ROW), out_dtype),
-            jax.ShapeDtypeStruct(
-                (R // B, B if per_row_flags else 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
     )(_scalars(scale), _rows(padded))
     out = out.reshape(-1)[:n]
+    row_bad = flags.reshape(-1) > 0.0
     if not per_row_flags:
-        return out, jnp.any(flags > 0.0)
-    row_bad = (flags.reshape(-1) > 0.0)[:rows_n]
+        return out, jnp.any(row_bad)
     return out, jnp.any(row_bad), row_bad
 
 
@@ -828,8 +825,8 @@ def multi_tensor_axpby_flat(
     if flat_x.shape != flat_y.shape:
         raise ValueError(
             f"axpby buffers must match: {flat_x.shape} vs {flat_y.shape}")
-    px, n = _pad_to_rows(flat_x, chunk_size)
-    py, _ = _pad_to_rows(flat_y, chunk_size)
+    px, n = _pad_to_rows(flat_x)
+    py, _ = _pad_to_rows(flat_y)
 
     if not _kernel_ok(use_kernel, interpret):
         out32 = (jnp.asarray(a, jnp.float32) * flat_x.astype(jnp.float32)
@@ -842,18 +839,18 @@ def multi_tensor_axpby_flat(
     def body(s_ref, x_ref, y_ref, out_ref, flag_ref):
         out32 = (s_ref[0, 0] * x_ref[:].astype(jnp.float32)
                  + s_ref[0, 1] * y_ref[:].astype(jnp.float32))
-        flag_ref[0, 0] = 1.0 - jnp.all(jnp.isfinite(out32)).astype(
-            jnp.float32)
+        flag_ref[:] = _row_flags(out32)
         out_ref[:] = out32.astype(out_dtype)
 
     out, flags = pl.pallas_call(
         body,
-        grid=(R // B,),
+        name="apex_tpu_multi_tensor_axpby_flat",
+        grid=(pl.cdiv(R, B),),
         in_specs=[_sspec(), _tspec(B), _tspec(B)],
-        out_specs=[_tspec(B), _flagspec()],
+        out_specs=[_tspec(B), _cspec(B)],
         out_shape=[
             jax.ShapeDtypeStruct((R, ROW), out_dtype),
-            jax.ShapeDtypeStruct((R // B, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
     )(_scalars(a, b), _rows(px), _rows(py))

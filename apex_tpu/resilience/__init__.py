@@ -76,7 +76,6 @@ from .manager import (  # noqa: F401
 )
 from .retry import (  # noqa: F401
     ELASTIC_BARRIER_POLICY,
-    TRANSIENT_COMPILE_POLICY,
     TRANSPORT_POLICY,
     BarrierNotReady,
     RetryPolicy,
@@ -102,8 +101,7 @@ from .watchdog import (  # noqa: F401
 
 __all__ = [
     "CHECKPOINT_IO_POLICY", "CheckpointManager", "PreemptionError",
-    "ELASTIC_BARRIER_POLICY", "TRANSIENT_COMPILE_POLICY",
-    "TRANSPORT_POLICY",
+    "ELASTIC_BARRIER_POLICY", "TRANSPORT_POLICY",
     "BarrierNotReady", "RetryPolicy", "retry_call",
     "RewindController", "RewindExhaustedError",
     "IndexedBatches", "ResumableIterator", "TrainState", "capture",

@@ -33,10 +33,8 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+import jax
+import jax.numpy as jnp
 
 # PRNG determinism across harnesses: the pytest conftest flips
 # jax_threefry_partitionable (for its 8-virtual-device mesh), which
@@ -161,6 +159,10 @@ def reference_records(world: int, steps: int, *, start_state=None):
 
 
 def main() -> int:
+    # a fake host is a CPU stand-in: a chip belongs to ONE process (the
+    # supervisor's, if any), so the host must never ask for it — whatever
+    # platform its environment exports
+    jax.config.update("jax_platforms", "cpu")
     ap = argparse.ArgumentParser()
     ap.add_argument("--host", type=int, required=True)
     ap.add_argument("--world", type=int, required=True)

@@ -70,12 +70,16 @@ from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import telemetry
 from ..amp import cast_params_for_inference
-from ..ops.flash_decode import _kernel_ok, flash_decode_available
+from ..ops.flash_decode import (
+    _kernel_ok,
+    flash_decode_available,
+    page_sublanes,
+)
 from ..resilience.watchdog import HangError
 from ..transformer import parallel_state
 from .decode_model import (  # noqa: F401
@@ -137,17 +141,21 @@ class SlotState(NamedTuple):
     hist: jax.Array         # [B, max_seq_len + 1] i32 — consumed tokens
 
 
-def default_page_size(num_heads: int, head_dim: int) -> int:
-    """Smallest power-of-two page (>= 8 tokens) whose K/V page is
-    ROW-aligned (``kv_cache.PagedKVSpec`` requirement)."""
+def default_page_size(num_heads: int, head_dim: int,
+                      dtype: Any = jnp.float32) -> int:
+    """Smallest power-of-two page that is a whole number of ``dtype``
+    vreg tiles (8 tokens at fp32, 16 at bf16 — the flash_decode kernel's
+    page block) and whose K/V page is ROW-aligned
+    (``kv_cache.PagedKVSpec`` requirement)."""
     from ..multi_tensor_apply.packing import ROW
 
     for ps in (8, 16, 32, 64, 128, 256):
-        if (num_heads * ps * head_dim) % ROW == 0:
+        if (ps % page_sublanes(dtype) == 0
+                and (num_heads * ps * head_dim) % ROW == 0):
             return ps
     raise ValueError(
         f"no power-of-two page size <= 256 aligns {num_heads} heads x "
-        f"{head_dim} dim pages to {ROW} elements")
+        f"{head_dim} dim {jnp.dtype(dtype).name} pages to {ROW} elements")
 
 
 class ServingEngine:
@@ -221,7 +229,8 @@ class ServingEngine:
         # shard holds n/tp heads of every page), so the default page
         # size derives from the LOCAL head count — spec.shard() below
         # re-validates whatever the caller forces
-        ps = page_size or default_page_size(n // self.tp, d)
+        kv_dtype = jnp.dtype(kv_dtype or cfg.compute_dtype)
+        ps = page_size or default_page_size(n // self.tp, d, kv_dtype)
         max_seq = cfg.max_position_embeddings
         # mp*ps may overshoot max_seq (pages quantize); submit() holds
         # requests to max_position_embeddings either way
@@ -229,7 +238,7 @@ class ServingEngine:
         num_pages = num_pages or (n_slots * mp + 1)
         self.spec = PagedKVSpec(
             cfg.num_layers, n, d, page_size=ps, num_pages=num_pages,
-            pages_per_seq=mp, dtype=kv_dtype or cfg.compute_dtype)
+            pages_per_seq=mp, dtype=kv_dtype)
         self.n_slots = int(n_slots)
         self.max_prompt_len = int(max_prompt_len or max_seq)
         # the on-device prompt buffer must hold preemption-replay
@@ -278,12 +287,13 @@ class ServingEngine:
         # kernel path would be selected, its tileability contract must
         # hold for this (page_size, head_dim)
         if (_kernel_ok(use_kernel, self._interpret)
-                and not flash_decode_available(ps, d)):
+                and not flash_decode_available(ps, d, kv_dtype)):
             raise ValueError(
                 f"flash_decode kernel cannot tile page_size={ps}, "
-                f"head_dim={d} (needs page_size % 8 == 0 and head_dim "
-                "<= 256); pass use_kernel=False for the XLA fallback "
-                "or pick a compatible page_size")
+                f"head_dim={d} (needs page_size % "
+                f"{page_sublanes(kv_dtype)} == 0 for {kv_dtype.name} pages "
+                "and head_dim <= 256); pass use_kernel=False for the XLA "
+                "fallback or pick a compatible page_size")
         self._chaos = chaos
         self.prefill_chunk = max(1, int(prefill_chunk))
         if self.prefill_chunk > self._buf_len:
@@ -472,7 +482,7 @@ class ServingEngine:
         """Wrap a step core ``core(params, kv, *rep_args) -> (kv,
         slots, emitted)`` in ``shard_map`` over the TP mesh — the
         identity at tp=1, so the replicated engine's traced program is
-        exactly the historical one. ``check_rep=False`` is the ddp_step
+        exactly the historical one. ``check_vma=False`` is the ddp_step
         precedent: slot math runs redundantly per shard on replicated
         inputs and collectives keep it bitwise identical across shards,
         which vma tracking cannot see."""
@@ -484,7 +494,7 @@ class ServingEngine:
             in_specs=(self._tp_param_pspecs(self.params),
                       self._kv_pspec()) + (rep,) * n_rep,
             out_specs=(self._kv_pspec(), rep, rep),
-            check_rep=False)
+            check_vma=False)
 
     def program_comm_volume(self) -> Optional[Dict[str, Dict]]:
         """Static ``{program: {collective: {count, bytes, axes}}}``

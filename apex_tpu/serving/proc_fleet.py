@@ -86,6 +86,7 @@ class _Worker:
         self.state = "down"      # down | ready | dead
         self.deaths = 0
         self.steps_done = 0      # this incarnation (first step compiles)
+        self.platform: Optional[str] = None  # jax backend the worker reports
 
     @property
     def ready(self) -> bool:
@@ -174,7 +175,9 @@ class FleetSupervisor:
         w.log_fh = open(os.path.join(
             self.workdir, f"worker-{w.idx}.{w.incarnation}.log"), "w")
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # set, not defaulted: an exported TPU platform must not reach the
+        # child (the worker pins itself to the CPU as well)
+        env["JAX_PLATFORMS"] = "cpu"
         # the worker must draw the SAME init params as the router's
         # reference: mirror the parent's PRNG-impl config (the test
         # harness flips it in-process, where child env can't see it)
@@ -207,9 +210,11 @@ class FleetSupervisor:
                 f"replica {w.idx} (incarnation {w.incarnation}) sent "
                 f"{hello!r} instead of ready")
         w.state = "ready"
+        w.platform = hello.get("platform")
         self._record({"event": "worker_ready", "replica": w.idx,
                       "incarnation": w.incarnation,
-                      "pid": hello.get("pid")})
+                      "pid": hello.get("pid"),
+                      "platform": w.platform})
 
     def _kill(self, w: _Worker) -> None:
         """SIGKILL, reap, and retire this incarnation's channel
@@ -525,6 +530,9 @@ class FleetSupervisor:
         return {
             "mode": "process",
             "n_replicas": len(self._workers),
+            # where the engines that produced these numbers ran
+            "worker_platforms": sorted(
+                {w.platform for w in self._workers if w.platform}),
             "n_requests": len(reqs),
             "completed": len(completed),
             "by_status": by_status,

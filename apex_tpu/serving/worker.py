@@ -42,8 +42,6 @@ import sys
 import time
 from typing import Dict, Optional
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 # script-mode safety: repo root importable when run as a file
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
@@ -233,6 +231,13 @@ def main(argv=None) -> int:
                    help="WorkerChaos spec, e.g. 'killmid@6,wedge@9:30'")
     args = p.parse_args(argv)
 
+    # a worker subprocess is a CPU stand-in: a chip belongs to ONE process
+    # (the supervisor's, if any), so the worker must never ask for it —
+    # whatever platform its environment exports
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
     # fd discipline: the frame channel owns fd 1; stray prints go to
     # stderr so they can never corrupt a frame
     out_fd = os.dup(1)
@@ -264,7 +269,8 @@ def main(argv=None) -> int:
     sink.record({"event": "worker_ready", "pid": os.getpid()})
     write_frame(out_fd, {"op": "ready", "replica": args.replica,
                          "incarnation": args.incarnation,
-                         "pid": os.getpid()})
+                         "pid": os.getpid(),
+                         "platform": jax.default_backend()})
 
     server = _WorkerServer(engine, hb, chaos, sink, out_fd,
                            args.telemetry or None)

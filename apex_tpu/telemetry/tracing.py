@@ -344,8 +344,10 @@ def cost_analysis_breakdown(step_fn, state) -> Optional[dict]:
 
 def profile_step(step_fn, state, n_steps: int = 3, top: int = 10):
     """One-shot step profile: trace ``n_steps`` chained executions and
-    return the top-``top`` device-time table, or the
-    ``cost_analysis()`` attribution on backends with no device plane.
+    return the top-``top`` device-time table. Off-TPU there is no device
+    plane and the answer is the static ``cost_analysis()`` attribution;
+    on a TPU a trace without a device plane is an error — a caller on
+    the chip is never handed the static table in a device table's place.
 
     ``step_fn(*state) -> state`` must be chainable (the bench step
     contract). The final state is fenced inside the trace so every step
@@ -367,8 +369,9 @@ def profile_step(step_fn, state, n_steps: int = 3, top: int = 10):
             cur[-1],
         )
     table = sess.op_breakdown(n_steps=n_steps, top=top)
-    if table is not None:
-        return table
-    # no device plane (CPU backend, or tensorflow protobuf missing):
-    # static attribution instead of None
-    return cost_analysis_breakdown(step_fn, state)
+    if table is None:
+        raise RuntimeError(
+            f"profile_step traced {n_steps} steps on a TPU but the "
+            "profile holds no readable device plane (is the xplane "
+            "protobuf reader importable?)")
+    return table

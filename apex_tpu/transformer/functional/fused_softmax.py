@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...ops.layer_norm import _row_block as _ln_row_block
 from ..enums import AttnMaskType
 
 _NEG_INF = -10000.0  # reference mask fill value (scaled_masked_softmax.h)
@@ -50,13 +51,9 @@ def _use_pallas(sk: int, interpret: bool) -> bool:
 
 
 def _row_block(rows: int, sk: int) -> int:
-    # whole sk row stays in VMEM; largest row block that divides rows while
-    # keeping one fp32 block under ~4MB (same budget as ops/layer_norm.py)
-    budget = max(1, (4 * 1024 * 1024) // max(sk * 4, 1))
-    for br in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if br <= budget and rows % br == 0:
-            return br
-    return 1
+    # whole sk row stays in VMEM; one fp32 block under ~4MB. The block
+    # rule (a multiple of 8 rows or the whole array) is layer_norm's.
+    return _ln_row_block(rows, sk, budget_bytes=4 << 20)
 
 
 def _softmax_fwd_kernel(x_ref, y_ref, *, scale, causal, sq, sk, br):

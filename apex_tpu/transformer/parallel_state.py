@@ -175,17 +175,8 @@ def tp_submesh(tp: int, *, replica: int = 0, devices=None) -> Mesh:
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, from inside a traced program.
-
-    ``jax.lax.axis_size`` where it exists; on older jax (this tree's
-    0.4.x floor) ``jax.core.axis_frame`` already returns the bound
-    axis size. The pipeline/context-parallel modules skip their tests
-    when ``lax.axis_size`` is missing — the serving TP path must not,
-    so it routes through this shim.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.core.axis_frame(axis_name)
+    """Static size of a named mesh axis, from inside a traced program."""
+    return jax.lax.axis_size(axis_name)
 
 
 def _axis_index_or_raise(axis: str, what: str):

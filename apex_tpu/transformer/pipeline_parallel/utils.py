@@ -242,18 +242,12 @@ def get_ltor_masks_and_position_ids(
 
 
 def pvary(x: jax.Array, axis_names) -> jax.Array:
-    """Mark ``x`` varying over ``axis_names`` — ``jax.lax.pcast`` on new JAX,
-    falling back to the deprecated ``jax.lax.pvary``; identity where neither
-    exists (pre-vma JAX)."""
+    """Mark ``x`` varying over ``axis_names`` (``jax.lax.pcast``)."""
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
     if not axis_names:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, tuple(axis_names), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, tuple(axis_names))
-    return x
+    return jax.lax.pcast(x, tuple(axis_names), to="varying")
 
 
 def vma_tracking_active(axis_name: str) -> bool:

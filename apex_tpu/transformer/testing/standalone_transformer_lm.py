@@ -800,20 +800,9 @@ def _selective_policy(prim, *args, **kwargs):
         prim, _SELECTIVE_SAVEABLE_KERNELS, *args, **kwargs)
 
 
-def _pallas_kernel_name(params) -> Optional[str]:
-    """Kernel name off a traced pallas_call's params. Modern jaxprs carry
-    it in ``name_and_src_info`` — the bare ``"name"`` param the original
-    policy matched on no longer exists there, which silently reduced
-    'selective' to dots-only saving (every kernel replayed in backward)."""
-    nsi = params.get("name_and_src_info")
-    if nsi is not None and getattr(nsi, "name", None):
-        return nsi.name
-    return params.get("name")
-
-
 def _policy_with_saveable_kernels(prim, kernels, *args, **kwargs):
     if getattr(prim, "name", "") == "pallas_call":
-        return _pallas_kernel_name(kwargs) in kernels
+        return kwargs["name"] in kernels
     return jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
         prim, *args, **kwargs
     )

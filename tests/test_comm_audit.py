@@ -19,7 +19,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
@@ -63,7 +63,7 @@ def test_comm_volume_counts_axes_and_bytes():
         return g.sum()
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     vol = comm_volume(f, jnp.zeros((128,), jnp.float32))
     assert vol["psum"] == {"count": 1, "bytes": 64, "axes": ["data"]}
     assert vol["all_gather"] == {"count": 1, "bytes": 512, "axes": ["data"]}
@@ -82,7 +82,7 @@ def test_comm_volume_counts_loop_bodies_once():
         return c
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     vol = comm_volume(f, jnp.zeros((64,), jnp.float32))
     assert vol["psum"]["count"] == 1
 
@@ -91,7 +91,7 @@ def test_comm_volume_abstract_args():
     """ShapeDtypeStruct args trace without any real buffers."""
     mesh = _mesh("data")
     f = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
-                  in_specs=P("data"), out_specs=P(), check_rep=False)
+                  in_specs=P("data"), out_specs=P(), check_vma=False)
     vol = comm_volume(f, jax.ShapeDtypeStruct((64,), jnp.bfloat16))
     assert vol["psum"]["count"] == 1 and vol["psum"]["bytes"] == 16
 
@@ -102,7 +102,7 @@ def test_comm_volume_abstract_args():
 def _psum_program():
     mesh = _mesh("data")
     f = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
-                  in_specs=P("data"), out_specs=P(), check_rep=False)
+                  in_specs=P("data"), out_specs=P(), check_vma=False)
     return f, (jnp.zeros((64,), jnp.float32),)
 
 
@@ -136,7 +136,7 @@ def test_budget_red_oversized_gather():
     mesh = _mesh("data")
     f = shard_map(lambda x: jax.lax.all_gather(x, "data"), mesh=mesh,
                   in_specs=P("data"), out_specs=P(None, "data"),
-                  check_rep=False)
+                  check_vma=False)
     x = jnp.zeros((8 * 1024,), jnp.float32)  # gathered output: 32 KiB
     rep = audit_step(f, x, collective_budget=CollectiveBudget(
         max_gather_bytes=1 << 14))
@@ -177,7 +177,7 @@ def test_red_cond_divergent_collective():
             x)
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                  check_rep=False)
+                  check_vma=False)
     rep = audit_step(f, jnp.zeros((64,), jnp.float32))
     assert "cond_divergent_collective" in _codes(rep.findings, "warning")
     br = [x for x in rep.findings
@@ -196,7 +196,7 @@ def test_green_cond_with_matching_branches():
             x)
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                  check_rep=False)
+                  check_vma=False)
     rep = audit_step(f, jnp.zeros((64,), jnp.float32))
     assert "cond_divergent_collective" not in rep.codes()
 
@@ -216,7 +216,7 @@ def test_red_unbucketed_loop_collectives():
         return c
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     rep = audit_step(f, jnp.zeros((64,), jnp.float32))
     hits = [x for x in rep.findings
             if x.code == "unbucketed_loop_collectives"]
@@ -267,7 +267,7 @@ def test_red_unpaired_psum_tail():
 
     f = shard_map(body, mesh=mesh,
                   in_specs=(P(None, "tensor"), P("tensor", None)),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     rep = audit_step(f, jnp.zeros((16, 64), jnp.float32),
                      jnp.zeros((64, 16), jnp.float32))
     assert "unpaired_psum_tail" in _codes(rep.findings, "warning")
@@ -284,7 +284,7 @@ def test_green_column_row_psum_pairing():
 
     f = shard_map(body, mesh=mesh,
                   in_specs=(P(), P(None, "tensor"), P("tensor", None)),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     rep = audit_step(f, jnp.zeros((16, 64), jnp.float32),
                      jnp.zeros((64, 32), jnp.float32),
                      jnp.zeros((32, 64), jnp.float32))
@@ -298,7 +298,7 @@ def test_red_large_replicated_operand():
         return (x @ w).sum()
 
     f = shard_map(body, mesh=mesh, in_specs=(P(), P("data", None)),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     w = jnp.zeros((512, 512), jnp.float32)  # 1 MiB, replicated
     x = jnp.zeros((64, 512), jnp.float32)
     rep = audit_step(f, w, x)
@@ -331,7 +331,7 @@ def _deeply_nested_program():
         return c
 
     f = shard_map(body, mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     return f, (jnp.zeros((4, 64), jnp.float32),)
 
 

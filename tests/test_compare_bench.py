@@ -104,21 +104,19 @@ def test_load_bench_handles_raw_capture_and_garbage(tmp_path):
     assert load_bench(str(trunc)) is None
 
 
-@pytest.mark.parametrize("name", ["BENCH_r01", "BENCH_r02", "BENCH_r03",
-                                  "BENCH_r04"])
-def test_archived_captures_still_extract(name):
-    """Schema-drift canary: the real driver captures must keep yielding
-    the headline leg (bench.py output format and the extractor evolve
-    together or this fails)."""
-    bench = load_bench(str(REPO / f"{name}.json"))
-    assert bench is not None
-    legs = extract_legs(bench)
-    assert "gpt_tokens_per_sec" in legs
-    assert legs["gpt_tokens_per_sec"] > 0
-
-
-def test_trajectory_over_archived_captures():
-    paths = [str(REPO / f"BENCH_r0{i}.json") for i in (1, 2, 3, 4)]
+def test_trajectory_over_driver_captures(tmp_path):
+    """A trajectory over captures in the driver's format (the JSON line
+    at the end of a noisy ``tail``, ``parsed`` null) — the schema-drift
+    canary: bench.py's output and the extractor evolve together."""
+    paths = []
+    for i, tokens in enumerate((27600.0, 33700.0, 40700.0, 46200.0)):
+        path = tmp_path / f"capture_{i}.json"
+        path.write_text(json.dumps(
+            {"n": i, "rc": 0, "parsed": None,
+             "tail": "a warning line\n" + json.dumps(_bench(tokens=tokens))}))
+        paths.append(str(path))
+        assert extract_legs(load_bench(paths[-1]))[
+            "gpt_tokens_per_sec"] == tokens
     rep = compare_trajectory(paths, threshold=0.05)
     assert len(rep["steps"]) == 3
     for step in rep["steps"]:

@@ -186,9 +186,12 @@ def test_raln_matches_unfused_chain(interp):
     assert jnp.abs(y - y_ref).max() <= tol
 
 
+# 12 rows: no multiple of 8 divides them — the old block rule picked
+# 4-row blocks, which Mosaic refuses; now they run as one block
+@pytest.mark.parametrize("rows", [(4, 8), (3, 4)])
 @pytest.mark.parametrize("interp", [False, True])
-def test_raln_grads_match_unfused_chain(interp):
-    x, b, r = _data(key=10)
+def test_raln_grads_match_unfused_chain(interp, rows):
+    x, b, r = _data(key=10, rows=rows)
     w = jnp.ones((128,)) * 0.9
     lb = jnp.zeros((128,))
 

@@ -114,11 +114,26 @@ def test_rms_memory_efficient_grads_equal():
         np.testing.assert_allclose(np.asarray(a), np.asarray(e), rtol=1e-4, atol=1e-5)
 
 
+def test_row_block_is_a_sublane_multiple_or_the_whole_array():
+    """Mosaic's rule for a block's second-to-last dim; the fused-block
+    tails share this helper. (The old rule returned 4/2/1-row blocks.)"""
+    from apex_tpu.ops.layer_norm import _row_block
+
+    for rows in (8192, 4096, 24, 12, 7, 1, 520):
+        for hidden in (128, 1024, 4096, 65536):
+            br = _row_block(rows, hidden)
+            assert rows % br == 0 and (br % 8 == 0 or br == rows), (
+                rows, hidden, br)
+    assert _row_block(8192, 1024) == 256
+    assert _row_block(12, 1024) == 12
+
+
 class TestPallasKernelInterpret:
     """Run the Pallas kernels in interpreter mode on CPU and compare with XLA."""
 
-    def test_ln_fwd_bwd(self):
-        x = jnp.asarray(_np(8, (16, H)))
+    @pytest.mark.parametrize("rows", [16, 12])  # 12: one whole-array block
+    def test_ln_fwd_bwd(self, rows):
+        x = jnp.asarray(_np(8, (rows, H)))
         w = jnp.asarray(_np(9, (H,)) * 0.1 + 1.0)
         b = jnp.asarray(_np(10, (H,)) * 0.1)
 

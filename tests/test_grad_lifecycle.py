@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.amp import LossScaler
@@ -145,7 +145,7 @@ def _run(steps, batches, flat, always_fp32, parity_downcast,
     step = jax.jit(shard_map(
         shard_step, mesh=_mesh(),
         in_specs=(P(), P(), P(), P("data"), P("data")),
-        out_specs=(P(), P(), P(), P()), check_rep=False))
+        out_specs=(P(), P(), P(), P()), check_vma=False))
 
     records = []
     for x, y in batches:
@@ -315,7 +315,7 @@ def test_autobuilt_fp32_reduction_sizes_cap_at_fp32():
             allreduce_always_fp32=True)[0]
 
     f = shard_map(reduce_fn, mesh=_mesh(), in_specs=P(), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     from apex_tpu.analysis import comm_volume
     assert comm_volume(f, tree)["psum"]["count"] == 2
 
@@ -372,7 +372,7 @@ def test_bucketed_reduce_one_psum_per_bucket_with_named_scopes():
         return sync_gradients_bucketed(tree, "data", buckets=buckets)[0]
 
     f = shard_map(reduce_fn, mesh=_mesh(), in_specs=P(),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     # one data psum per bucket (the world-size psum of a literal 1
     # constant-folds at trace time) — eqn-counted by the walker, not
     # text-matched (ISSUE-19)
@@ -407,7 +407,7 @@ def test_sync_gradients_keep_fp32_is_audit_clean():
 
     def run(fn):
         mapped = shard_map(fn, mesh=_mesh(), in_specs=P(), out_specs=P(),
-                           check_rep=False)
+                           check_vma=False)
         return audit_step(mapped, grads, rules=("dtype_flow",))
 
     assert "double_cast" in run(legacy).codes()

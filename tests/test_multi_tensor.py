@@ -198,29 +198,37 @@ def test_flat_l2norm(interpret):
     assert row_sq.shape == (3,)
 
 
-def test_flat_ops_pad_awkward_lengths_to_full_chunks():
-    """A buffer whose row count has no divisor near the chunk (e.g. a
-    prime row count) must be chunk-padded, not silently degraded to
-    1-row blocks / an n_rows-step grid."""
-    from apex_tpu.ops.packed_optimizer import _block_rows, _pad_to_rows
+def test_flat_ops_block_rows_are_tileable_for_awkward_lengths():
+    """Mosaic takes a block's sublane dim only as a tile multiple (16
+    covers bf16) or the array's own. The old rule shrank blocks to the
+    largest divisor of the row count — 4 rows for a 4-row chunk, 1 for a
+    prime count — which the chip refuses; now the block is the chunk
+    rounded up to 16 rows (or the whole buffer) and the grid's ragged
+    last block covers an odd remainder."""
+    from apex_tpu.ops.packed_optimizer import _block_rows
 
-    x = jnp.ones((13 * ROW - 5,), jnp.float32)  # 13 rows: prime count
-    padded, n = _pad_to_rows(x, chunk_size=4 * ROW)
-    assert n == 13 * ROW - 5
-    assert padded.shape[0] == 16 * ROW  # next chunk multiple
-    assert _block_rows(16, 4 * ROW) == 4  # full blocks, not 1-row fallback
-    # and end-to-end correctness through the public op (kernel body)
-    v = jnp.asarray(np.random.RandomState(7).randn(13 * ROW - 5), jnp.float32)
+    assert _block_rows(16, 4 * ROW) == 16   # small chunk: whole buffer
+    assert _block_rows(13, 64 * ROW) == 13  # prime, under one block
+    assert _block_rows(37, 4 * ROW) == 16   # odd R//B: grid of 3, ragged
+    assert _block_rows(5681, 64 * ROW) == 64
+    # end-to-end through the kernel bodies: 37 rows in 16-row blocks, the
+    # last block 5 rows, with the only non-finite value inside it
+    rng = np.random.RandomState(7)
+    v = jnp.asarray(rng.randn(37 * ROW - 5), jnp.float32)
     out, found = multi_tensor_scale_flat(
         v, 0.5, chunk_size=4 * ROW, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(v) * 0.5,
                                rtol=1e-6)
     assert out.shape == v.shape and not bool(found)
+    _, found, row_bad = multi_tensor_scale_flat(
+        v.at[36 * ROW + 3].set(np.inf), 0.5, chunk_size=4 * ROW,
+        per_row_flags=True, interpret=True)
+    assert bool(found) and np.flatnonzero(np.asarray(row_bad)).tolist() == [36]
     norm, row_sq = multi_tensor_l2norm_flat(
         v, chunk_size=4 * ROW, interpret=True)
     np.testing.assert_allclose(float(norm), np.linalg.norm(np.asarray(v)),
                                rtol=1e-5)
-    assert row_sq.shape == (13,)  # padding rows not reported
+    assert row_sq.shape == (37,)
 
 
 def test_chunk_size_is_honored():

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from apex_tpu.parallel import (
     DistributedDataParallel,
@@ -93,7 +93,7 @@ def test_ddp_wrap_grad_fn_and_broadcast():
     g_sync = shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(None, None), P("data", None)),
-        out_specs=P(None, None), check_rep=False,
+        out_specs=P(None, None), check_vma=False,
     )(w, x)
     # synced grads equal the mean of per-shard grads
     per = [np.asarray(jax.grad(loss_fn)(w, x[i : i + 1])) for i in range(8)]

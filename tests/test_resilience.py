@@ -41,7 +41,6 @@ from apex_tpu.resilience import (  # noqa: E402
     RewindController,
     RewindExhaustedError,
     StallingSink,
-    TRANSIENT_COMPILE_POLICY,
     capture,
     corrupt_checkpoint,
     poison_grads,
@@ -55,12 +54,13 @@ from tools import resilience_check  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
-# retry.py (satellite: promoted from bench.py)
+# retry.py
 # ---------------------------------------------------------------------------
 class TestRetry:
     def test_success_no_retry(self):
         calls = []
-        assert retry_call(lambda: calls.append(1) or 42) == 42
+        assert retry_call(lambda: calls.append(1) or 42,
+                          policy=RetryPolicy(attempts=3)) == 42
         assert len(calls) == 1
 
     def test_non_transient_surfaces_immediately(self):
@@ -103,12 +103,12 @@ class TestRetry:
             retry_call(lambda: (_ for _ in ()).throw(OSError("x")),
                        policy=policy)
 
-    def test_compile_transport_filter(self):
-        # the historical bench filter: class AND message must match
-        good = Exception("remote_compile: HTTP 500 mid-stream")
-        bad = Exception("HTTP 500")  # no remote_compile marker
-        assert TRANSIENT_COMPILE_POLICY.is_transient(good)
-        assert not TRANSIENT_COMPILE_POLICY.is_transient(bad)
+    def test_message_filter_narrows_the_class_match(self):
+        # class AND message must match
+        policy = RetryPolicy(attempts=3, retry_on=(Exception,),
+                             message_filter=lambda e: "busy" in str(e))
+        assert policy.is_transient(Exception("device busy, try again"))
+        assert not policy.is_transient(Exception("invalid argument"))
 
     def test_zero_base_delay_never_sleeps(self):
         policy = RetryPolicy(attempts=3, retry_on=(OSError,))

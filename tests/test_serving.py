@@ -175,6 +175,36 @@ def test_flash_decode_shape_validation():
     assert flash_decode_available(16, 64)
     assert not flash_decode_available(12, 64)   # page % 8
     assert not flash_decode_available(16, 512)  # head dim
+    # the page is the block's sublane dim: 8 tokens is one fp32 tile but
+    # half a bf16 (16 x 128) tile — the old rule let ps = 8 bf16 through
+    assert flash_decode_available(8, 64, jnp.float32)
+    assert not flash_decode_available(8, 64, jnp.bfloat16)
+    assert flash_decode_available(16, 64, jnp.bfloat16)
+    kb, vb = (x[:, :, :8].astype(jnp.bfloat16) for x in (k_pages, v_pages))
+    with pytest.raises(ValueError, match="bfloat16 tiles"):
+        flash_decode(q, kb, vb, pt, lens // 2, interpret=True)
+
+
+def test_default_page_size_follows_kv_dtype():
+    """16 heads x 64 dim (GPT-2 345M): the ROW rule alone picks 8
+    tokens; a bf16 pool needs 16 for the kernel's page block — and the
+    engine derives it from the pool dtype, not the caller."""
+    from apex_tpu.serving import default_page_size
+
+    assert default_page_size(16, 64) == 8
+    assert default_page_size(16, 64, jnp.bfloat16) == 16
+    assert default_page_size(4, 64, jnp.bfloat16) == 16  # tp=4 shard
+    cfg = GPTConfig(
+        num_layers=1, hidden_size=256, num_attention_heads=4,
+        vocab_size=128, max_position_embeddings=32,
+        hidden_dropout=0.0, attention_dropout=0.0,
+        compute_dtype=jnp.bfloat16)
+    params = init_gpt_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, n_slots=2, interpret=True)
+    assert eng.spec.page_size == 16 and eng.spec.dtype == jnp.bfloat16
+    eng32 = ServingEngine(cfg, params, n_slots=2, interpret=True,
+                          kv_dtype=jnp.float32)
+    assert eng32.spec.page_size == 8
 
 
 # ---------------------------------------------------------------------------

@@ -393,7 +393,7 @@ def test_scopes_green_packed_kernels_are_scoped():
 # ---------------------------------------------------------------------------
 def seeded_violation_report():
     """One deterministic step violating every rule family at once."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from apex_tpu.analysis import CollectiveBudget
@@ -411,7 +411,7 @@ def seeded_violation_report():
         return jax.lax.psum(t, "tensor")  # unpaired double reduction
 
     tp = shard_map(tp_body, mesh=mesh, in_specs=(P(), P()),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
 
     def step(state, x16, w16, scale):
         jax.debug.callback(lambda v: None, x16)       # ungated callback

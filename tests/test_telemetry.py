@@ -366,12 +366,6 @@ def test_no_pipelining_hook_fires_on_gradient_path():
     assert jnp.allclose(grads["w"], grads_bare["w"])
 
 
-@pytest.mark.skipif(
-    not (hasattr(jax.lax, "axis_size") and hasattr(jax, "shard_map")),
-    reason="pipeline schedules need jax.lax.axis_size/jax.shard_map "
-           "(newer jax); schedule runtime is already untestable on this "
-           "version",
-)
 def test_1f1b_tick_hook_timeline_matches_analytic():
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P

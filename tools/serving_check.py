@@ -4,8 +4,9 @@ Like ``tools/static_audit.py --self`` and ``tools/resilience_check.py``,
 this self-hosts the subsystem on a tiny model, small enough for the
 tier-1 CPU lane:
 
-- ``decode_parity``   the flash-decode kernel (interpret mode — the
-                      REAL kernel body) and the XLA fallback both match
+- ``decode_parity``   the flash-decode kernel (the REAL kernel body:
+                      compiled on a TPU, interpreted elsewhere) and
+                      the XLA fallback both match
                       the dense gathered reference on ragged page
                       tables, including empty (fully-masked) slots.
 - ``token_identity``  ``ServingEngine.generate`` over a staggered
@@ -157,6 +158,7 @@ def _tiny_params(cfg):
 
 
 def check_decode_parity() -> dict:
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -175,8 +177,11 @@ def check_decode_parity() -> dict:
     ref = np.asarray(paged_decode_reference(q, k_pages, v_pages, pt, lens))
     xla = np.asarray(flash_decode(q, k_pages, v_pages, pt, lens,
                                   use_kernel=False))
-    kern = np.asarray(flash_decode(q, k_pages, v_pages, pt, lens,
-                                   interpret=True))
+    # the REAL kernel body either way: compiled on a TPU, interpreted
+    # anywhere else
+    kern = np.asarray(flash_decode(
+        q, k_pages, v_pages, pt, lens,
+        interpret=jax.default_backend() != "tpu"))
     xla_err = float(np.abs(xla - ref).max())
     kern_err = float(np.abs(kern - ref).max())
     empty_zero = float(np.abs(kern[0]).max()) == 0.0
@@ -813,8 +818,12 @@ def check_proc_fleet_failover() -> dict:
           and all(r.status is RequestStatus.COMPLETED for r in reqs)
           and leaks == 0
           and telem_records > 0
-          and telem_stats.get("torn_lines", 0) >= 1)
+          and telem_stats.get("torn_lines", 0) >= 1
+          # workers are CPU stand-ins whatever this process runs on, and
+          # their records say so
+          and st["worker_platforms"] == ["cpu"])
     return {"ok": ok, "incidents": kinds,
+            "worker_platforms": st["worker_platforms"],
             "requests_lost": st["requests_lost"],
             "migrated": st["migrated"], "mttr_s": st["mttr_s"],
             "torn_frames": st["torn_frames"],

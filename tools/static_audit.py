@@ -187,7 +187,7 @@ def build_ddp_step():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from apex_tpu.amp import LossScaler
@@ -248,7 +248,7 @@ def build_ddp_step():
         shard_step, mesh=mesh,
         in_specs=(P(), P(), P(), P("data"), P("data")),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
     step = jax.jit(lambda p, s, ss: wrapped(p, s, ss, tokens, labels),
                    donate_argnums=(0, 1, 2))
     return step, (params, opt_state, sstate), {}
