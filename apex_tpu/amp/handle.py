@@ -20,6 +20,7 @@ from .scaler import LossScaler, LossScaleState
 Pytree = Any
 
 
+@jax.named_scope("apex_tpu.amp_scaler")
 def scale_loss(loss: jax.Array, scaler_state: LossScaleState) -> jax.Array:
     """loss * current scale (use inside your loss function)."""
     return loss * scaler_state.loss_scale.astype(loss.dtype)
@@ -63,6 +64,7 @@ def scaled_value_and_grad(
     return fn
 
 
+@jax.named_scope("apex_tpu.amp_scaler")
 def apply_updates_skip_on_overflow(
     params: Pytree, new_params: Pytree, found_inf: jax.Array
 ) -> Pytree:

@@ -84,10 +84,12 @@ class LossScaler:
         )
 
     # -- step-time ops (pure, jittable) ------------------------------------
+    @jax.named_scope("apex_tpu.amp_scaler")
     def scale_loss(self, state: LossScaleState, loss: jax.Array) -> jax.Array:
         """loss * scale (``apex/amp/handle.py:107-113``)."""
         return loss * state.loss_scale.astype(loss.dtype)
 
+    @jax.named_scope("apex_tpu.amp_scaler")
     def unscale(
         self, state: LossScaleState, grads: Pytree, out_dtype=None,
         numerics=None,
@@ -116,6 +118,7 @@ class LossScaler:
         nstate = monitor.observe(nstate, leaf_nonfinite=leaf_flags)
         return out, state._replace(found_inf=state.found_inf | found), nstate
 
+    @jax.named_scope("apex_tpu.amp_scaler")
     def unscale_flat(
         self, state: LossScaleState, flat_grads, out_dtype=None,
         numerics=None, *, chunk_size: Optional[int] = None,
@@ -157,6 +160,7 @@ class LossScaler:
         nstate = monitor.observe(nstate, row_nonfinite=row_bad)
         return out, new_state, nstate
 
+    @jax.named_scope("apex_tpu.amp_scaler")
     def found_inf_flat(self, state: LossScaleState, flat_grads):
         """Record overflow from flat SCALED gradients without unscaling
         them — the read-only half of the fused one-sweep lifecycle.
@@ -198,6 +202,7 @@ class LossScaler:
             found = found | jnp.any(~jnp.isfinite(b) | (jnp.abs(b32) > lim))
         return state._replace(found_inf=found)
 
+    @jax.named_scope("apex_tpu.amp_scaler")
     def unscale_with_stashed(
         self, state: LossScaleState, new_scaled_grads: Pytree, stashed_grads: Pytree
     ) -> Tuple[Pytree, LossScaleState]:
@@ -210,6 +215,7 @@ class LossScaler:
         out, found = multi_tensor_axpby(inv, 1.0, new_scaled_grads, stashed_grads)
         return out, state._replace(found_inf=state.found_inf | found)
 
+    @jax.named_scope("apex_tpu.amp_scaler")
     def update_scale(self, state: LossScaleState, metrics=None,
                      numerics=None):
         """End-of-step scale adjustment (``apex/amp/scaler.py:197-216``).

@@ -682,7 +682,46 @@ def rule_scopes(trace, cfg: AuditConfig) -> List[Finding]:
                 "an apex_tpu.* named scope — schedule ticks are "
                 "unattributable in traces",
                 where=ns or ctx.describe(), data=None))
+    out.extend(_unscoped_layer_findings(trace.closed.jaxpr))
     return out
+
+
+def _unscoped_layer_findings(jaxpr) -> List[Finding]:
+    """A step that names its layers (some equation stands under a
+    ``telemetry.tracing.LAYER_SCOPES`` scope) and has equations outside
+    every one of them: their device time reaches no layer of the by-scope
+    table. One finding for the step, naming the first such equation. An
+    inner jaxpr's name stacks are relative to the equation that calls it,
+    so the path is built on the way down."""
+    from ..telemetry.tracing import scope_of
+
+    scoped = 0
+    outside: List[Tuple[str, str]] = []     # (primitive, path)
+
+    def rec(jaxpr, prefix):
+        nonlocal scoped
+        for eqn in jaxpr.eqns:
+            path = f"{prefix}/{name_stack_str(eqn)}"
+            subs = transparent_subjaxprs(eqn)
+            if subs:        # a call, branch or loop: its body does the work
+                for sub in subs:
+                    rec(sub, path)
+            elif scope_of(path)[0] is not None:
+                scoped += 1
+            else:
+                outside.append((eqn.primitive.name, path.strip("/")))
+
+    rec(jaxpr, "")
+    if not scoped or not outside:
+        return []
+    prim, path = outside[0]
+    return [Finding(
+        "scopes", "unscoped_layer", "info",
+        f"{len(outside)} of {scoped + len(outside)} equations stand "
+        "outside every layer scope (telemetry.tracing.LAYER_SCOPES) — "
+        f"their device time reaches no layer; first: '{prim}'",
+        where=path or "<top>",
+        data={"outside": len(outside), "scoped": scoped})]
 
 
 # imported last: collectives.py depends on report/walk only, never on

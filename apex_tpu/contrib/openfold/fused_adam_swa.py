@@ -66,6 +66,7 @@ class FusedAdamSWA:
             exp_avg=zeros(), exp_avg_sq=zeros(),
         )
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(self, grads: Pytree, state: FusedAdamSWAState, params: Pytree,
              compute_params: Pytree, swa_params: Pytree, lr=None):
         """One fused Adam+SWA step. ``params`` fp32 masters; grads may be

@@ -265,6 +265,7 @@ class DistributedFusedAdam:
         )
         return new_params, new_state
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(
         self,
         grads: Pytree,

@@ -148,6 +148,7 @@ class DistributedFusedLAMB(DistributedFusedAdam):
         )
         return new_params, new_state
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(
         self,
         grads: Pytree,

@@ -122,6 +122,7 @@ class LegacyFusedAdam(FusedAdam):
         new_p32 = p32 - step_size * update
         return new_p32, new_m, new_v
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(  # legacy signature
         self,
         grads: Pytree,
@@ -168,6 +169,7 @@ class LegacyFusedSGD(FusedSGD):
         )
         del materialize_master_grads  # CUDA master-grad plumbing; n/a
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(  # legacy signature
         self,
         grads: Pytree,

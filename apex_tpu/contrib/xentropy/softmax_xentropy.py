@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("apex_tpu.cross_entropy")
 def softmax_cross_entropy_loss(
     logits: jax.Array,
     labels: jax.Array,
@@ -79,6 +80,7 @@ def _maybe_scan(body, carry, xs, unroll):
     return carry, stacked
 
 
+@jax.named_scope("apex_tpu.cross_entropy")
 def lm_head_cross_entropy(
     hidden: jax.Array,  # [N, h] pre-head activations (any float dtype)
     head_weight: jax.Array,  # [V, h] (tied-embedding layout)

@@ -178,6 +178,7 @@ class PackSpec:
                 f"reused for a different model?): spec {self!r} vs "
                 f"{len(leaves)} leaves")
 
+    @jax.named_scope("apex_tpu.pack")
     def pack(self, tree: Pytree, dtype: Optional[Any] = None) -> jax.Array:
         """Ravel + per-leaf zero-pad + concat to ``(total,)``.
 
@@ -197,6 +198,7 @@ class PackSpec:
             pieces.append(jnp.zeros((tail,), dtype))
         return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
 
+    @jax.named_scope("apex_tpu.pack")
     def pack_bucket(self, tree: Pytree, bucket: int,
                     dtype: Optional[Any] = None) -> jax.Array:
         """Ravel + zero-pad ONLY bucket ``bucket``'s leaves to its extent
@@ -230,6 +232,7 @@ class PackSpec:
         b0, b1 = self.bucket_bounds[bucket], self.bucket_bounds[bucket + 1]
         return jax.lax.slice(flat, (b0,), (b1,))
 
+    @jax.named_scope("apex_tpu.pack")
     def concat_buckets(self, buffers) -> jax.Array:
         """Per-bucket buffers (in order) -> the ``(total,)`` global
         buffer; the inverse of packing/slicing bucket-by-bucket."""
@@ -246,6 +249,7 @@ class PackSpec:
                     f"expected ({extent},)")
         return buffers[0] if len(buffers) == 1 else jnp.concatenate(buffers)
 
+    @jax.named_scope("apex_tpu.unpack")
     def unpack(self, flat: jax.Array, cast: bool = True) -> Pytree:
         """``(total,)`` -> pytree; each leaf cast back to its template
         dtype unless ``cast=False``."""

@@ -64,6 +64,7 @@ class FusedAdagrad(FusedOptimizer):
         new_params = jax.tree_util.tree_map(lambda p32, p: p32.astype(p.dtype), p32s, params)
         return new_params, FusedAdagradState(step=state.step + 1, sum=hs)
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(
         self,
         grads: Pytree,

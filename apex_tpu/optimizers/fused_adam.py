@@ -228,6 +228,7 @@ class FusedAdam(FusedOptimizer):
         return new_params, new_state
 
     # -- public API --------------------------------------------------------
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(
         self,
         grads: Pytree,
@@ -248,6 +249,7 @@ class FusedAdam(FusedOptimizer):
             (params, state),
         )
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step_flat(
         self,
         grads,
@@ -340,6 +342,7 @@ class FusedAdam(FusedOptimizer):
             spec=spec,
         )
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def no_update_mv_step(
         self,
         grads: Pytree,

@@ -211,6 +211,7 @@ class FusedLAMB(FusedOptimizer):
             spec=spec,
         )
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(
         self,
         grads: Pytree,

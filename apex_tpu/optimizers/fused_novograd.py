@@ -173,6 +173,7 @@ class FusedNovoGrad(FusedOptimizer):
             step=new_step, exp_avg=ms, exp_avg_sq=new_v,
             master_params=None, spec=spec)
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(
         self,
         grads: Pytree,

@@ -153,6 +153,7 @@ class FusedSGD(FusedOptimizer):
             spec=spec,
         )
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(
         self,
         grads: Pytree,

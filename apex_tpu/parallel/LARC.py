@@ -92,6 +92,7 @@ class LARC:
     def init(self, params: Pytree):
         return self.optim.init(params)
 
+    @jax.named_scope("apex_tpu.optimizer_step")
     def step(self, grads: Pytree, state, params: Pytree, **kwargs):
         lr = getattr(self.optim, "lr", None)
         wd = getattr(self.optim, "weight_decay", 0.0) or 0.0

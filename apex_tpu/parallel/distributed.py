@@ -271,6 +271,7 @@ class GradBuckets:
                 f"bucket_cap_mb={self.bucket_cap_mb})")
 
 
+@jax.named_scope("apex_tpu.sync_gradients")
 def sync_gradients_bucketed(
     grads: Pytree,
     axis_name: str = "data",

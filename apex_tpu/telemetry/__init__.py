@@ -102,13 +102,17 @@ from .timeseries import (  # noqa: F401
     label_key,
 )
 from .tracing import (  # noqa: F401
+    LAYER_SCOPES,
     TraceSession,
     aggregate_op_times,
+    aggregate_scope_times,
     breakdown_table,
     categorize_op,
     cost_analysis_breakdown,
     parse_xspace_op_times,
     profile_step,
+    scope_index,
+    scope_of,
     short_op_name,
     trace_session,
 )
@@ -131,7 +135,8 @@ __all__ = [
     "default_serving_slos",
     "AlertManager", "EscalationResponder", "FleetResponder",
     "HealthMonitor",
-    "TraceSession", "aggregate_op_times", "breakdown_table",
-    "categorize_op", "cost_analysis_breakdown", "parse_xspace_op_times",
-    "profile_step", "short_op_name", "trace_session",
+    "LAYER_SCOPES", "TraceSession", "aggregate_op_times",
+    "aggregate_scope_times", "breakdown_table", "categorize_op",
+    "cost_analysis_breakdown", "parse_xspace_op_times", "profile_step",
+    "scope_index", "scope_of", "short_op_name", "trace_session",
 ]

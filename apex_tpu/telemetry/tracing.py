@@ -1,21 +1,29 @@
-"""Trace sessions and per-op device-time attribution.
+"""Trace sessions and device-time attribution, by operation and by layer.
 
 The reference publishes per-kernel timings through nvprof/nsys ranges;
 the TPU analogue is a ``jax.profiler`` xplane trace. This module owns
 
+- :data:`LAYER_SCOPES` — the ``apex_tpu.<name>`` named scopes that bound
+  the layers of a train step, and :func:`scope_of` / :func:`scope_index`,
+  which read them back: every instruction of a compiled step carries its
+  whole scope path in ``metadata={op_name=...}``, and the device trace
+  names each event by its instruction, so joining the two gives device
+  time by layer and by forward / backward / recompute;
 - :func:`trace_session` — a context manager around ``jax.profiler.trace``
   that yields a session handle whose :meth:`~TraceSession.op_breakdown`
-  parses the captured device plane into a categorized top-op table;
+  parses the captured device plane into a top-op table with per-category
+  and (given the step's HLO text) per-scope totals;
 - :func:`profile_step` — one-shot: run a step function ``n_steps`` times
   under a trace and return the breakdown table, falling back to the
   compiled step's ``cost_analysis()`` (flops/bytes attribution) on
   backends with no device plane (CPU CI) so every environment gets a
   table rather than ``None``;
-- the pure xplane/HLO op-name helpers (:func:`short_op_name`,
-  :func:`categorize_op`, :func:`aggregate_op_times`,
-  :func:`breakdown_table`) — factored out of ``tools/op_breakdown.py``
-  so they unit-test on canned fixtures without a TPU or tensorflow.
+- the pure op-name helpers (:func:`short_op_name`, :func:`categorize_op`,
+  :func:`aggregate_op_times`, :func:`aggregate_scope_times`,
+  :func:`breakdown_table`), which unit-test on canned fixtures without a
+  TPU.
 
+The xplane is read with ``jax.profiler.ProfileData`` (ships with jax).
 ``tools/op_breakdown.py`` re-exports all of this for script use.
 """
 from __future__ import annotations
@@ -30,7 +38,108 @@ from typing import Dict, Iterable, Optional, Tuple
 
 
 # ---------------------------------------------------------------------------
-# pure helpers (fixture-testable, no jax/tf imports)
+# layer scopes (pure, no jax import)
+# ---------------------------------------------------------------------------
+
+# The named scopes that bound the layers of a train step, and what each
+# bounds. Kernel scopes (``apex_tpu.flash_attention``, ``apex_tpu.
+# packed_adam``, ...) nest inside these and count to the enclosing layer.
+LAYER_SCOPES = (
+    "apex_tpu.embed",              # token + position lookup
+    "apex_tpu.layer_stack",        # the layers' scan: slices of the stacked parameters, stacking of their gradients
+    "apex_tpu.transformer_layer",  # one layer; directly under it, under neither child: norms and residual tails
+    "apex_tpu.attention",          # qkv GEMM, layout changes, flash kernel, out projection
+    "apex_tpu.mlp",                # both GEMMs and the activation
+    "apex_tpu.lm_head",            # final layer norm and the logits GEMM
+    "apex_tpu.cross_entropy",      # the loss and its scan (with the head GEMM where gpt_loss chunk-fuses the two)
+    "apex_tpu.amp_scaler",         # loss scaling, unscale, overflow probe, scale update
+    "apex_tpu.optimizer_step",     # a fused optimizer's whole step: casts, norms, trust ratios, kernels
+    "apex_tpu.pack",               # pytree -> flat buffer (in optimizer_step or sync_gradients, or alone)
+    "apex_tpu.unpack",             # flat buffer -> pytree
+    "apex_tpu.sync_gradients",     # bucket fill + all-reduce
+    "apex_tpu.grad_bucket",        # one bucket's all-reduce (inside sync_gradients)
+)
+# scopes that hold other layers: time is theirs only where no layer scope
+# stands inside them
+CONTAINER_SCOPES = ("apex_tpu.layer_stack", "apex_tpu.transformer_layer")
+PHASES = ("fwd", "bwd", "recompute")
+
+_SCOPE_NAME = re.compile(r"apex_tpu\.\w+")
+_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+) = ")
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+) [^=]*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def scope_of(op_name_path: str) -> Tuple[Optional[str], str]:
+    """``(layer_scope, phase)`` of one instruction's ``op_name`` path.
+
+    The layer is the outermost :data:`LAYER_SCOPES` scope of the path,
+    read through jax's wrappers (``jvp(...)``, ``transpose(...)``,
+    ``checkpoint``); a container (:data:`CONTAINER_SCOPES`) gives way to a
+    layer scope inside it, and ``None`` means the path names no layer. The
+    phase is ``recompute`` where the path holds ``rematted_computation``,
+    ``bwd`` where it holds ``transpose(``, else ``fwd`` — jax writes both
+    into the path itself."""
+    layer = None
+    for name in _SCOPE_NAME.findall(op_name_path):
+        if name in CONTAINER_SCOPES:
+            layer = name
+        elif name in LAYER_SCOPES:
+            layer = name
+            break
+    if "rematted_computation" in op_name_path:
+        phase = "recompute"
+    elif "transpose(" in op_name_path:
+        phase = "bwd"
+    else:
+        phase = "fwd"
+    return layer, phase
+
+
+def instruction_name(raw: str) -> str:
+    """``'%fusion.12 = bf16[...] fusion(...)'`` -> ``'fusion.12'``: a
+    trace event's name as the HLO text spells the instruction."""
+    return raw.split(" ", 1)[0].lstrip("%")
+
+
+def scope_index(hlo_text: str) -> Dict[str, str]:
+    """``{instruction: op_name path}`` of a compiled step's HLO text
+    (``compiled.as_text()``). A fusion (or call) that carries no path of
+    its own takes the path of its computation's root.
+
+    The path is the metadata of the executable as it was compiled: one
+    loaded from the persistent compilation cache keeps the names of the
+    source that compiled it (jax leaves metadata out of the cache key
+    unless ``jax_compilation_cache_include_metadata_in_key`` is set)."""
+    paths: Dict[str, str] = {}
+    callee: Dict[str, str] = {}      # instruction without a path -> calls=
+    roots: Dict[str, str] = {}       # computation -> its root's path
+    computation = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                computation = c.group(1)
+            continue
+        path = _OP_NAME.search(line)
+        if path is not None:
+            paths[m.group(2)] = path.group(1)
+            if m.group(1) and computation is not None:
+                roots[computation] = path.group(1)
+        else:
+            calls = _CALLS.search(line)
+            if calls is not None:
+                callee[m.group(2)] = calls.group(1)
+    for name, comp in callee.items():
+        if comp in roots:
+            paths[name] = roots[comp]
+    return paths
+
+
+# ---------------------------------------------------------------------------
+# op-name helpers (fixture-testable, no jax import)
 # ---------------------------------------------------------------------------
 
 def short_op_name(hlo_text: str) -> str:
@@ -123,8 +232,7 @@ def aggregate_op_times(
     separate through the merge.
 
     This is the parsing core of the xplane breakdown, taking already
-    decoded events so it is unit-testable on a canned fixture (no
-    tensorflow protobuf needed).
+    decoded events so it is unit-testable on a canned fixture.
     """
     per_op: Dict[Tuple[str, str], int] = defaultdict(int)
     total = 0
@@ -139,109 +247,89 @@ def aggregate_op_times(
     return total, dict(per_op)
 
 
-def _normalize_per_op(per_op) -> Dict[Tuple[str, str], int]:
-    """Accept both the (name, category)-keyed dict and the legacy
-    name-keyed dict (pre-fix captures, e.g. archived BENCH_r0* parsing)."""
-    out: Dict[Tuple[str, str], int] = defaultdict(int)
-    for k, ps in per_op.items():
-        if isinstance(k, tuple):
-            out[k] += int(ps)
-        else:
-            out[(k, categorize_op(k))] += int(ps)
-    return dict(out)
+def aggregate_scope_times(
+    events: Iterable[Tuple], index: Dict[str, str],
+) -> Dict[Tuple[str, str], int]:
+    """Fold the same events into ``{(layer_scope, phase): ps}`` through a
+    :func:`scope_index` of the step that ran. Containers are dropped as in
+    :func:`aggregate_op_times`; an event whose instruction the index does
+    not hold, or whose path names no layer, goes to ``"_unscoped_"``."""
+    per_scope: Dict[Tuple[str, str], int] = defaultdict(int)
+    for item in events:
+        name = instruction_name(item[0])
+        if name.startswith(_CONTAINER_PREFIXES):
+            continue
+        layer, phase = scope_of(index.get(name, ""))
+        per_scope[(layer or "_unscoped_", phase)] += int(item[1])
+    return dict(per_scope)
 
 
 def breakdown_table(total_ps: int, per_op, n_steps: int = 1,
-                    top: int = 10) -> Optional[dict]:
-    """The published table: top-``top`` ops + per-category totals.
+                    top: int = 10, per_scope=None) -> Optional[dict]:
+    """The published table: top-``top`` ops, per-category totals and,
+    given :func:`aggregate_scope_times`' fold, per-layer totals
+    (``"scopes"``: layer scope -> ms per step, share, and the same by
+    phase).
 
     Ops on the device ``XLA Ops`` line are leaf HLO instructions, so
     durations are self-times. Returns ``None`` when nothing was captured.
     """
     if not total_ps:
         return None
-    norm = _normalize_per_op(per_op)
-    rows = sorted(norm.items(), key=lambda kv: -kv[1])
-    ops = [
-        {
-            "op": name,
-            "category": cat,
-            "ms_per_step": round(ps / 1e9 / n_steps, 3),
-            "pct": round(100.0 * ps / total_ps, 2),
-        }
-        for (name, cat), ps in rows[:top]
-    ]
+
+    def cell(ps):
+        return {"ms_per_step": round(ps / 1e9 / n_steps, 3),
+                "pct": round(100.0 * ps / total_ps, 2)}
+
+    rows = sorted(per_op.items(), key=lambda kv: -kv[1])
+    ops = [{"op": name, "category": cat, **cell(ps)}
+           for (name, cat), ps in rows[:top]]
     by_cat: Dict[str, int] = defaultdict(int)
-    for (name, cat), ps in norm.items():
+    for (name, cat), ps in per_op.items():
         by_cat[cat] += ps
-    categories = {
-        cat: {
-            "ms_per_step": round(ps / 1e9 / n_steps, 3),
-            "pct": round(100.0 * ps / total_ps, 2),
-        }
-        for cat, ps in sorted(by_cat.items(), key=lambda kv: -kv[1])
-    }
-    return {
+    table = {
         "source": "xplane",
         "device_ms_per_step": round(total_ps / 1e9 / n_steps, 3),
         "ops": ops,
-        "categories": categories,
+        "categories": {
+            cat: cell(ps)
+            for cat, ps in sorted(by_cat.items(), key=lambda kv: -kv[1])},
     }
+    if per_scope is not None:
+        by_layer: Dict[str, int] = defaultdict(int)
+        for (layer, phase), ps in per_scope.items():
+            by_layer[layer] += ps
+        table["scopes"] = {
+            layer: {**cell(ps), "phases": {
+                phase: cell(per_scope[(layer, phase)]) for phase in PHASES
+                if (layer, phase) in per_scope}}
+            for layer, ps in sorted(by_layer.items(), key=lambda kv: -kv[1])}
+    return table
 
 
 # ---------------------------------------------------------------------------
-# xplane extraction (needs the tensorflow protobuf; TPU images have it)
+# xplane extraction (jax's own reader)
 # ---------------------------------------------------------------------------
-
-def _stat_value(plane, st):
-    """String value of one XStat, following ref_value indirection."""
-    if st.str_value:
-        return st.str_value
-    if st.ref_value and st.ref_value in plane.stat_metadata:
-        return plane.stat_metadata[st.ref_value].name
-    return ""
-
-
-def _event_hlo_category(plane, ev, md) -> Optional[str]:
-    """The profiler's per-op category stat (``hlo_category``), from the
-    event's stats or the event-metadata's constant stats. This is XLA's
-    own attribution (derived from the fused computation's root op), so a
-    generic ``%fusion.N`` whose root is a convolution reports
-    "convolution fusion" — the signal the breakdown's categories key on.
-    """
-    for stats in (ev.stats, md.stats):
-        for st in stats:
-            smd = plane.stat_metadata.get(st.metadata_id)
-            if smd is not None and smd.name == "hlo_category":
-                return _stat_value(plane, st) or None
-    return None
-
 
 def iter_xplane_events(trace_dir: str):
     """Yield ``(raw_op_name, duration_ps, hlo_category_or_None)`` for
-    every event on a device plane's ``XLA Ops`` line under ``trace_dir``.
-    Empty iterator when the tensorflow protobuf is unavailable or nothing
+    every event on a device plane's ``XLA Ops`` line under ``trace_dir``
+    (``hlo_category`` is the profiler's per-op category stat, XLA's own
+    attribution from the fused computation's root). Empty when nothing
     was captured."""
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except Exception:  # tensorflow not present on this image
-        return
-    for path in glob.glob(
-        os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True
-    ):
-        xs = xplane_pb2.XSpace()
-        with open(path, "rb") as f:
-            xs.ParseFromString(f.read())
-        for plane in xs.planes:
+    from jax.profiler import ProfileData
+
+    for path in sorted(glob.glob(
+            os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)):
+        for plane in ProfileData.from_file(path).planes:
             if "/device:TPU" not in plane.name:
                 continue
             for line in plane.lines:
                 if line.name != "XLA Ops":
                     continue
                 for ev in line.events:
-                    md = plane.event_metadata[ev.metadata_id]
-                    yield (md.name, ev.duration_ps,
-                           _event_hlo_category(plane, ev, md))
+                    yield (ev.name, int(round(ev.duration_ns * 1e3)),
+                           dict(ev.stats).get("hlo_category") or None)
 
 
 def parse_xspace_op_times(trace_dir: str):
@@ -262,15 +350,24 @@ class TraceSession:
         self.logdir = logdir
         self.active = True
 
-    def op_breakdown(self, n_steps: int = 1, top: int = 10):
+    def op_breakdown(self, n_steps: int = 1, top: int = 10,
+                     hlo_text: Optional[str] = None):
         """Parse the capture into a categorized table (after the ``with``
-        block exits). ``None`` when no device plane was captured."""
+        block exits). ``None`` when no device plane was captured. With
+        ``hlo_text`` — ``compiled.as_text()`` of the step that ran — the
+        table also holds ``"scopes"``: device time by :data:`LAYER_SCOPES`
+        layer and phase."""
         if self.active:
             raise RuntimeError(
                 "trace_session is still active — parse after the with "
                 "block exits (the profiler writes the xplane on stop)")
-        total_ps, per_op = parse_xspace_op_times(self.logdir)
-        return breakdown_table(total_ps, per_op, n_steps=n_steps, top=top)
+        events = list(iter_xplane_events(self.logdir))
+        total_ps, per_op = aggregate_op_times(events)
+        per_scope = None
+        if hlo_text is not None:
+            per_scope = aggregate_scope_times(events, scope_index(hlo_text))
+        return breakdown_table(total_ps, per_op, n_steps=n_steps, top=top,
+                               per_scope=per_scope)
 
 
 @contextlib.contextmanager
@@ -278,12 +375,12 @@ def trace_session(logdir: Optional[str] = None):
     """Capture a ``jax.profiler`` trace around a block of training code.
 
     Yields a :class:`TraceSession`; after the block exits, call
-    ``session.op_breakdown(n_steps=...)`` for the categorized device-time
-    table, or point ``tensorboard --logdir`` / Perfetto at
-    ``session.logdir`` for the full timeline (named scopes from
-    ``jax.named_scope`` — ``apex_tpu.flash_attention``,
-    ``apex_tpu.packed_adam``, ``apex_tpu.pipeline_rounds``, ... —
-    annotate the op names).
+    ``session.op_breakdown(n_steps=..., hlo_text=...)`` for the
+    device-time table by operation, category and layer scope, or point
+    ``tensorboard --logdir`` / Perfetto at ``session.logdir`` for the full
+    timeline, where the same names (:data:`LAYER_SCOPES`, and inside them
+    ``apex_tpu.flash_attention``, ``apex_tpu.packed_adam``, ...) annotate
+    the op names.
 
     ::
 
@@ -306,6 +403,16 @@ def trace_session(logdir: Optional[str] = None):
         session.active = False
 
 
+def _compile(step_fn, state):
+    """``step_fn`` compiled ahead of time for ``state``."""
+    import jax
+
+    lower = getattr(step_fn, "lower", None)
+    if lower is None:
+        lower = jax.jit(step_fn).lower
+    return lower(*state).compile()
+
+
 def cost_analysis_breakdown(step_fn, state) -> Optional[dict]:
     """Static flops/bytes attribution from ``Compiled.cost_analysis()``.
 
@@ -314,13 +421,8 @@ def cost_analysis_breakdown(step_fn, state) -> Optional[dict]:
     algorithmic work — enough for CI to catch a step whose flops or
     traffic regress. Returns ``None`` only if even compilation fails.
     """
-    import jax
-
     try:
-        lower = getattr(step_fn, "lower", None)
-        if lower is None:
-            lower = jax.jit(step_fn).lower
-        ca = lower(*state).compile().cost_analysis()
+        ca = _compile(step_fn, state).cost_analysis()
         if isinstance(ca, list):
             ca = ca[0] if ca else {}
         ca = dict(ca or {})
@@ -344,14 +446,17 @@ def cost_analysis_breakdown(step_fn, state) -> Optional[dict]:
 
 def profile_step(step_fn, state, n_steps: int = 3, top: int = 10):
     """One-shot step profile: trace ``n_steps`` chained executions and
-    return the top-``top`` device-time table. Off-TPU there is no device
-    plane and the answer is the static ``cost_analysis()`` attribution;
-    on a TPU a trace without a device plane is an error — a caller on
-    the chip is never handed the static table in a device table's place.
+    return the top-``top`` device-time table with its per-category and
+    per-layer (``"scopes"``) totals. Off-TPU there is no device plane and
+    the answer is the static ``cost_analysis()`` attribution; on a TPU a
+    trace without a device plane is an error — a caller on the chip is
+    never handed the static table in a device table's place.
 
     ``step_fn(*state) -> state`` must be chainable (the bench step
-    contract). The final state is fenced inside the trace so every step
-    is captured.
+    contract). The step is compiled once, ahead of time, and that
+    executable both runs under the trace and gives the HLO text the
+    scopes are read from. The final state is fenced inside the trace so
+    every step is captured.
     """
     import jax
 
@@ -359,19 +464,20 @@ def profile_step(step_fn, state, n_steps: int = 3, top: int = 10):
         # no device plane exists to capture — skip the n_steps of traced
         # execution entirely and go straight to the static attribution
         return cost_analysis_breakdown(step_fn, state)
+    compiled = _compile(step_fn, state)
     with trace_session() as sess:
         cur = state
         for _ in range(n_steps):
-            cur = step_fn(*cur)
+            cur = compiled(*cur)
         jax.tree_util.tree_map(
             lambda x: x.block_until_ready()
             if hasattr(x, "block_until_ready") else x,
             cur[-1],
         )
-    table = sess.op_breakdown(n_steps=n_steps, top=top)
+    table = sess.op_breakdown(n_steps=n_steps, top=top,
+                              hlo_text=compiled.as_text())
     if table is None:
         raise RuntimeError(
             f"profile_step traced {n_steps} steps on a TPU but the "
-            "profile holds no readable device plane (is the xplane "
-            "protobuf reader importable?)")
+            "profile holds no device plane")
     return table

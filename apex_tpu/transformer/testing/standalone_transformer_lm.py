@@ -304,6 +304,7 @@ def _fp8_dense(cfg, fp8, name, x, w, b):
     return y, new_state
 
 
+@jax.named_scope("apex_tpu.attention")
 def parallel_attention(
     cfg: GPTConfig,
     lp: Dict[str, jax.Array],
@@ -591,6 +592,7 @@ def _attn_out_proj(cfg, lp, ctx, axis_name, fp8=None, new_fp8=None,
     return out
 
 
+@jax.named_scope("apex_tpu.mlp")
 def parallel_mlp(
     cfg: GPTConfig,
     lp: Dict[str, jax.Array],
@@ -904,10 +906,13 @@ def transformer_block(
     xs = (layer_params, jnp.arange(1, L + 1))
     if with_fp8:
         xs = xs + (fp8_states, fp8_carriers)
-    (hidden, _), ys = jax.lax.scan(
-        body, (hidden, dropout_key), xs, length=L,
-        unroll=max(1, min(unroll, L)),
-    )
+    # the scan's own work is the per-layer slices of the stacked
+    # parameters and the stacking of their gradients in backward
+    with jax.named_scope("apex_tpu.layer_stack"):
+        (hidden, _), ys = jax.lax.scan(
+            body, (hidden, dropout_key), xs, length=L,
+            unroll=max(1, min(unroll, L)),
+        )
     if with_fp8:
         return hidden, ys
     return hidden
@@ -917,6 +922,7 @@ def transformer_block(
 # GPT
 # --------------------------------------------------------------------------
 
+@jax.named_scope("apex_tpu.embed")
 def gpt_embed(
     cfg: GPTConfig,
     params: Pytree,
@@ -1027,12 +1033,7 @@ def gpt_hidden(
     )
     if fp8_states is not None:
         hidden, new_fp8 = hidden
-    hidden = fused_layer_norm(
-        hidden.astype(jnp.float32),
-        params["final_ln_w"].astype(jnp.float32),
-        params["final_ln_b"].astype(jnp.float32),
-        eps=cfg.layernorm_epsilon,
-    ).astype(cfg.compute_dtype)
+    hidden = _final_layer_norm(cfg, params, hidden)
     if axis_name is not None and cfg.sequence_parallel:
         # leave the SP region before the LM head: all-gather the sequence
         # (backward reduce-scatters the partial d(hidden) — the SP linear
@@ -1065,11 +1066,24 @@ def gpt_forward(
     new_fp8 = None
     if fp8_states is not None:
         hidden, new_fp8 = hidden
-    logits = _lm_head(cfg, params, hidden, axis_name)
-    logits = jnp.transpose(logits, (1, 0, 2))  # [b, s, v(/tp)]
+    with jax.named_scope("apex_tpu.lm_head"):
+        logits = _lm_head(cfg, params, hidden, axis_name)
+        logits = jnp.transpose(logits, (1, 0, 2))  # [b, s, v(/tp)]
     if fp8_states is not None:
         return logits, new_fp8
     return logits
+
+
+@jax.named_scope("apex_tpu.lm_head")
+def _final_layer_norm(cfg, params, hidden):
+    """The LN before the output head; on the device timeline it counts to
+    the head (``apex_tpu.lm_head``), not to a layer."""
+    return fused_layer_norm(
+        hidden.astype(jnp.float32),
+        params["final_ln_w"].astype(jnp.float32),
+        params["final_ln_b"].astype(jnp.float32),
+        eps=cfg.layernorm_epsilon,
+    ).astype(cfg.compute_dtype)
 
 
 def _lm_head(cfg, params, hidden, axis_name):
@@ -1119,7 +1133,9 @@ def gpt_loss(
         )
         if fp8_states is not None:
             logits, new_fp8 = logits
-        losses = vocab_parallel_cross_entropy(logits, labels, 0.0, axis_name)
+        with jax.named_scope("apex_tpu.cross_entropy"):
+            losses = vocab_parallel_cross_entropy(
+                logits, labels, 0.0, axis_name)
     else:
         from apex_tpu.contrib.xentropy import lm_head_cross_entropy
 
@@ -1129,41 +1145,44 @@ def gpt_loss(
         )
         if fp8_states is not None:
             hidden, new_fp8 = hidden
-        s, b, h = hidden.shape
-        n = s * b
-        # largest divisor of n that is <= 2048: keeps the chunked-CE memory
-        # guarantee for any batch/seq (falling back to n would materialise
-        # exactly the [n, vocab] block this path exists to avoid)
-        chunk = 1
-        for cand in range(min(2048, n), 0, -1):
-            if n % cand == 0:
-                chunk = cand
-                break
-        losses = lm_head_cross_entropy(
-            hidden.reshape(n, h),
-            params["embedding"]["word"],
-            jnp.transpose(labels, (1, 0)).reshape(n),  # [s, b] row order
-            chunk_size=chunk,
-            save_logits_dtype=(
-                cfg.compute_dtype if cfg.ce_save_logits else None
-            ),
-            unroll=cfg.ce_unroll,
-        ).reshape(s, b)
-        losses = jnp.transpose(losses, (1, 0))  # [b, s]
-    if cfg.context_parallel_axis is not None:
-        # global masked mean over the sequence-sharded losses: psum the
-        # numerator/denominator over the cp axis (equal shard sizes)
-        a = cfg.context_parallel_axis
-        m = (jnp.ones_like(losses) if loss_mask is None
-             else loss_mask.astype(jnp.float32))
-        num = jax.lax.psum(jnp.sum(losses * m), a)
-        den = jax.lax.psum(jnp.sum(m), a)
-        loss = num / jnp.maximum(den, 1.0)
-    elif loss_mask is None:
-        loss = jnp.mean(losses)
-    else:
-        m = loss_mask.astype(jnp.float32)
-        loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
+        with jax.named_scope("apex_tpu.cross_entropy"):
+            s, b, h = hidden.shape
+            n = s * b
+            # largest divisor of n that is <= 2048: keeps the chunked-CE
+            # memory guarantee for any batch/seq (falling back to n would
+            # materialise exactly the [n, vocab] block this path exists to
+            # avoid)
+            chunk = 1
+            for cand in range(min(2048, n), 0, -1):
+                if n % cand == 0:
+                    chunk = cand
+                    break
+            losses = lm_head_cross_entropy(
+                hidden.reshape(n, h),
+                params["embedding"]["word"],
+                jnp.transpose(labels, (1, 0)).reshape(n),  # [s, b] rows
+                chunk_size=chunk,
+                save_logits_dtype=(
+                    cfg.compute_dtype if cfg.ce_save_logits else None
+                ),
+                unroll=cfg.ce_unroll,
+            ).reshape(s, b)
+            losses = jnp.transpose(losses, (1, 0))  # [b, s]
+    with jax.named_scope("apex_tpu.cross_entropy"):
+        if cfg.context_parallel_axis is not None:
+            # global masked mean over the sequence-sharded losses: psum
+            # the numerator/denominator over the cp axis (equal shard sizes)
+            a = cfg.context_parallel_axis
+            m = (jnp.ones_like(losses) if loss_mask is None
+                 else loss_mask.astype(jnp.float32))
+            num = jax.lax.psum(jnp.sum(losses * m), a)
+            den = jax.lax.psum(jnp.sum(m), a)
+            loss = num / jnp.maximum(den, 1.0)
+        elif loss_mask is None:
+            loss = jnp.mean(losses)
+        else:
+            m = loss_mask.astype(jnp.float32)
+            loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
     if fp8_states is not None:
         return loss, new_fp8
     return loss
@@ -1208,19 +1227,15 @@ def bert_forward(
         cfg_pad, params["layers"], hidden, attn_mask, axis_name, k_block,
         deterministic,
     )
-    hidden = fused_layer_norm(
-        hidden.astype(jnp.float32),
-        params["final_ln_w"].astype(jnp.float32),
-        params["final_ln_b"].astype(jnp.float32),
-        eps=cfg.layernorm_epsilon,
-    ).astype(cfg.compute_dtype)
+    hidden = _final_layer_norm(cfg, params, hidden)
     if axis_name is not None and cfg.sequence_parallel:
         hidden = mappings.gather_from_sequence_parallel_region(
             hidden, axis_name
         )
 
-    lm_logits = _lm_head(cfg, params, hidden, axis_name)
-    lm_logits = jnp.transpose(lm_logits, (1, 0, 2))
+    with jax.named_scope("apex_tpu.lm_head"):
+        lm_logits = _lm_head(cfg, params, hidden, axis_name)
+        lm_logits = jnp.transpose(lm_logits, (1, 0, 2))
 
     binary_logits = None
     if cfg.add_binary_head and "binary_head" in params:

@@ -3,8 +3,9 @@
 The profiling capture itself needs a real TPU; the parsing/classification
 logic is pure and pinned here so a refactor cannot silently misbucket the
 published bench breakdown. The golden xplane fixtures at the bottom build
-REAL xplane protobufs and pin the corrected category attribution
-end-to-end (round-5 VERDICT: generic ``%fusion.N`` ops were all booked as
+REAL xplane protobufs (with tensorflow's protobuf classes where they are
+installed; the parser itself reads them with ``jax.profiler.ProfileData``)
+and pin the corrected category attribution end-to-end (round-5 VERDICT: generic ``%fusion.N`` ops were all booked as
 "fusion(elementwise)", hiding the dense GEMMs — 42.7% of the GPT step
 mislabeled).
 """
@@ -174,12 +175,12 @@ def test_golden_xplane_ref_value_category(tmp_path):
     line.name = "XLA Ops"
     ev = line.events.add()
     ev.metadata_id = 1
-    ev.duration_ps = 42
+    ev.duration_ps = 42_000
     st = ev.stats.add()
     st.metadata_id = 1
     st.ref_value = 2
     out = tmp_path / "t.xplane.pb"
     out.write_bytes(xs.SerializeToString())
     total, per_op = parse_xspace_op_times(str(tmp_path))
-    assert total == 42
-    assert per_op == {("fusion", "matmul/conv"): 42}
+    assert total == 42_000
+    assert per_op == {("fusion", "matmul/conv"): 42_000}

@@ -388,6 +388,44 @@ def test_scopes_green_packed_kernels_are_scoped():
     assert "unscoped_kernel" not in rep.codes()
 
 
+def _layered_step(loss_scoped: bool):
+    def step(x, w):
+        def loss(w):
+            with jax.named_scope("apex_tpu.mlp"):
+                h = jnp.tanh(x @ w)
+            if loss_scoped:
+                with jax.named_scope("apex_tpu.cross_entropy"):
+                    return jnp.sum(h * h)
+            return jnp.sum(h * h)            # under no layer scope
+        l, g = jax.value_and_grad(loss)(w)
+        with jax.named_scope("apex_tpu.optimizer_step"):
+            w = w - 0.1 * g
+        return w, l
+    return jax.jit(step), (jnp.ones((8, 8)), jnp.ones((8, 8)))
+
+
+def test_scopes_red_equations_outside_every_layer_scope():
+    step, args = _layered_step(loss_scoped=False)
+    rep = audit_step(step, *args, rules=("scopes",))
+    (f,) = [x for x in rep.findings if x.code == "unscoped_layer"]
+    assert f.severity == "info"
+    # the loss's forward and backward: mul, reduce_sum and their transposes
+    assert f.data["outside"] >= 3 and f.data["scoped"] >= 6
+    assert "apex_tpu." not in f.where
+
+
+def test_scopes_green_every_equation_under_a_layer_scope():
+    step, args = _layered_step(loss_scoped=True)
+    rep = audit_step(step, *args, rules=("scopes",))
+    assert "unscoped_layer" not in rep.codes()
+
+
+def test_scopes_silent_on_a_step_that_names_no_layer():
+    rep = audit_step(jax.jit(lambda x: x * 2.0 + 1.0), jnp.ones((8,)),
+                     rules=("scopes",))
+    assert "unscoped_layer" not in rep.codes()
+
+
 # ---------------------------------------------------------------------------
 # golden JSON fixture: the report schema is pinned byte-for-byte
 # ---------------------------------------------------------------------------

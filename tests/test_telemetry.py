@@ -467,12 +467,94 @@ def test_breakdown_table_fixture():
     assert telemetry.breakdown_table(0, {}) is None
 
 
-def test_breakdown_table_accepts_legacy_name_keyed_per_op():
-    # pre-fix captures keyed per_op by bare name; the table still builds
-    table = telemetry.breakdown_table(
-        1_000_000, {"dot_fusion": 750_000, "copy": 250_000})
-    assert table["categories"]["matmul/conv"]["pct"] == pytest.approx(75.0)
-    assert table["categories"]["data-movement"]["pct"] == pytest.approx(25.0)
+# --- device time by layer scope (telemetry.tracing.LAYER_SCOPES) ----------
+_STEP_HLO = """
+HloModule jit_step
+
+%fused_computation.2 (p: f32[8,8]) -> f32[8,8] {
+  %p = f32[8,8]{1,0} parameter(0)
+  ROOT %tanh.5 = f32[8,8]{1,0} tanh(%p), metadata={op_name="jit(step)/jvp()/apex_tpu.layer_stack/checkpoint/apex_tpu.transformer_layer/apex_tpu.mlp/tanh" source_file="x.py" source_line=3}
+}
+
+ENTRY %main.3 (x: f32[8,8]) -> f32[8,8] {
+  %x = f32[8,8]{1,0} parameter(0), metadata={op_name="x"}
+  %fusion.1 = f32[8,8]{1,0} fusion(%x), kind=kLoop, calls=%fused_computation.2
+  %fusion.2 = f32[8,8]{1,0} fusion(%fusion.1), kind=kLoop, calls=%fc.9, metadata={op_name="jit(step)/transpose(jvp())/apex_tpu.layer_stack/checkpoint/rematted_computation/apex_tpu.transformer_layer/apex_tpu.attention/apex_tpu.flash_attention/mul"}
+  %custom-call.3 = f32[8,8]{1,0} custom-call(%fusion.2), metadata={op_name="jit(step)/apex_tpu.optimizer_step/cond/branch_1_fun/apex_tpu.packed_adam/apex_tpu_packed_adam"}
+  ROOT %add.4 = f32[8,8]{1,0} add(%custom-call.3, %x), metadata={op_name="jit(step)/add"}
+}
+"""
+
+
+@pytest.mark.parametrize("path, want", [
+    ("jit(s)/jvp()/closed_call/apex_tpu.transformer_layer/apex_tpu.mlp/mul",
+     ("apex_tpu.mlp", "fwd")),
+    ("jit(s)/transpose(jvp(apex_tpu.loss))/jvp(apex_tpu.loss)/checkpoint/"
+     "rematted_computation/apex_tpu.transformer_layer/apex_tpu.mlp/mul",
+     ("apex_tpu.mlp", "recompute")),
+    ("jit(s)/transpose(jvp())/apex_tpu.layer_stack/checkpoint/"
+     "apex_tpu.transformer_layer/apex_tpu.fused_block/add",
+     ("apex_tpu.transformer_layer", "bwd")),
+    ("jit(s)/transpose(jvp(apex_tpu.layer_stack))/concatenate",
+     ("apex_tpu.layer_stack", "bwd")),
+    ("jit(s)/jvp(apex_tpu.embed)/jit(_take)/gather",
+     ("apex_tpu.embed", "fwd")),
+    ("jit(s)/apex_tpu.optimizer_step/cond/apex_tpu.unpack/slice",
+     ("apex_tpu.optimizer_step", "fwd")),
+    ("jit(s)/apex_tpu.sync_gradients/apex_tpu.grad_bucket/3/psum",
+     ("apex_tpu.sync_gradients", "fwd")),
+    ("jit(s)/apex_tpu.flash_attention/apex_tpu_flash_fwd", (None, "fwd")),
+    ("", (None, "fwd")),
+])
+def test_scope_of_reads_layer_and_phase_through_jax_wrappers(path, want):
+    assert telemetry.scope_of(path) == want
+
+
+def test_layer_scopes_are_named_once_and_containers_are_among_them():
+    from apex_tpu.telemetry import tracing
+
+    assert len(set(telemetry.LAYER_SCOPES)) == len(telemetry.LAYER_SCOPES)
+    assert set(tracing.CONTAINER_SCOPES) < set(telemetry.LAYER_SCOPES)
+    assert all(s.startswith("apex_tpu.") for s in telemetry.LAYER_SCOPES)
+
+
+def test_scope_index_maps_instructions_and_a_fusion_takes_its_root():
+    index = telemetry.scope_index(_STEP_HLO)
+    assert index["fusion.1"].endswith("apex_tpu.mlp/tanh")    # the root's
+    assert index["fusion.2"].endswith("apex_tpu.flash_attention/mul")
+    assert index["add.4"] == "jit(step)/add"
+    assert "tanh.5" in index and "main.3" not in index
+
+
+def test_breakdown_table_scopes_by_layer_and_phase():
+    events = [
+        ("%fusion.1 = f32[8,8] fusion(...)", 600),
+        ("fusion.2", 200),
+        ("custom-call.3", 100),
+        ("add.4", 60),
+        ("fusion.77", 40),                       # not in the text
+        ("%while.9 = (...) while(...)", 5000),   # container: dropped
+    ]
+    total, per_op = telemetry.aggregate_op_times(events)
+    per_scope = telemetry.aggregate_scope_times(
+        events, telemetry.scope_index(_STEP_HLO))
+    assert per_scope == {
+        ("apex_tpu.mlp", "fwd"): 600,
+        ("apex_tpu.attention", "recompute"): 200,
+        ("apex_tpu.optimizer_step", "fwd"): 100,
+        ("_unscoped_", "fwd"): 100,
+    }
+    assert sum(per_scope.values()) == total
+    table = telemetry.breakdown_table(total, per_op, n_steps=2,
+                                      per_scope=per_scope)
+    scopes = table["scopes"]
+    assert list(scopes)[0] == "apex_tpu.mlp"          # largest first
+    assert scopes["apex_tpu.mlp"]["pct"] == pytest.approx(60.0)
+    assert scopes["apex_tpu.attention"]["phases"] == {
+        "recompute": {"ms_per_step": 0.0, "pct": pytest.approx(20.0)}}
+    assert scopes["_unscoped_"]["pct"] == pytest.approx(10.0)
+    # without the step's text the table is the one it was
+    assert "scopes" not in telemetry.breakdown_table(total, per_op)
 
 
 def test_profile_step_cost_analysis_fallback_on_cpu():

@@ -14,8 +14,10 @@ Usage::
     table = profile_step_breakdown(step_fn, state, n_steps=3)
 
 Returns ``{"source": "xplane", "device_ms_per_step": float, "ops":
-[{"op", "category", "ms_per_step", "pct"}, ...], "categories": {...}}``
-on TPU. On backends with no device plane (CPU CI) it now returns the
+[{"op", "category", "ms_per_step", "pct"}, ...], "categories": {...},
+"scopes": {layer scope: {"ms_per_step", "pct", "phases": {...}}}}`` on
+TPU (``scopes``: device time by the ``apex_tpu.<layer>`` named scopes of
+``telemetry.tracing.LAYER_SCOPES``, forward / backward / recompute apart). On backends with no device plane (CPU CI) it now returns the
 ``Compiled.cost_analysis()`` flops/bytes attribution (``"source":
 "cost_analysis"``) instead of ``None`` — every environment gets a table.
 
@@ -32,13 +34,17 @@ than claimed elementwise. Pinned by the golden xplane fixtures in
 from __future__ import annotations
 
 from apex_tpu.telemetry.tracing import (  # noqa: F401
+    LAYER_SCOPES,
     aggregate_op_times,
+    aggregate_scope_times,
     breakdown_table,
     categorize_op,
     cost_analysis_breakdown,
     iter_xplane_events,
     parse_xspace_op_times,
     profile_step,
+    scope_index,
+    scope_of,
     short_op_name,
     trace_session,
 )
