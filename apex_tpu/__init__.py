@@ -74,15 +74,16 @@ def set_logging_level(level) -> None:
     _library_root_logger.setLevel(level)
 
 
-# Eager, lightweight subpackages. Heavy ones (transformer, contrib) are imported
-# lazily via __getattr__ to keep `import apex_tpu` cheap.
+# Eager, lightweight subpackages. Heavy ones (transformer, contrib, and
+# normalization, whose module classes import flax) are imported lazily via
+# __getattr__ to keep `import apex_tpu` cheap.
 from . import amp  # noqa: F401,E402
 from . import optimizers  # noqa: F401,E402
-from . import normalization  # noqa: F401,E402
 from . import multi_tensor_apply  # noqa: F401,E402
 
 _LAZY_SUBMODULES = (
     "analysis",
+    "normalization",
     "parallel",
     "transformer",
     "contrib",

@@ -42,13 +42,6 @@ import jax.numpy as jnp
 import jax.scipy.special
 from jax import lax
 
-try:
-    import optax
-    _HAVE_OPTAX = True
-except Exception:  # pragma: no cover
-    optax = None
-    _HAVE_OPTAX = False
-
 
 def _entries(module, names):
     return [(module, n) for n in names if module is not None
@@ -57,7 +50,7 @@ def _entries(module, names):
 
 # -- low precision: the MXU ops (reference FP16_FUNCS) ----------------------
 
-LOW_PRECISION_FUNCS = (
+_LOW_PRECISION_JAX = (
     _entries(jnp, [
         "matmul", "dot", "vdot", "inner", "outer", "tensordot", "einsum",
         "kron", "cross", "convolve", "correlate",
@@ -90,11 +83,9 @@ def _apex_low_precision():
     return out
 
 
-LOW_PRECISION_FUNCS += _apex_low_precision()
-
 # -- fp32: numerically sensitive ops (reference FP32_FUNCS) -----------------
 
-FP32_FUNCS = (
+_FP32_JAX = (
     # pointwise transcendentals (reference torch_overrides FP32_FUNCS:
     # acos asin cosh erfinv exp expm1 log log10 log2 log1p reciprocal
     # rsqrt sinh tan pow; + numpy-side spellings and inverses).
@@ -142,7 +133,8 @@ def _loss_fp32():
     home for those; apex_tpu's own xentropy/focal contrib losses force
     fp32 internally already but are listed so O1 users see one policy."""
     out = []
-    if _HAVE_OPTAX:
+    try:
+        import optax
         out += _entries(optax, [
             "softmax_cross_entropy",
             "softmax_cross_entropy_with_integer_labels",
@@ -154,6 +146,8 @@ def _loss_fp32():
             "squared_error", "safe_softmax_cross_entropy",
             "sigmoid_focal_loss", "ntxent",
         ])
+    except Exception:  # pragma: no cover
+        pass
     try:
         from apex_tpu.contrib import xentropy as _xent
         out += _entries(_xent, ["softmax_cross_entropy_loss"])
@@ -167,7 +161,21 @@ def _loss_fp32():
     return out
 
 
-FP32_FUNCS += _loss_fp32()
+
+# ``LOW_PRECISION_FUNCS`` and ``FP32_FUNCS`` are built at their first
+# lookup (PEP 562): their apex_tpu and optax entries import flax, optax and
+# ``apex_tpu.contrib`` (about 1.5 s), which only an O1 ``autocast`` needs.
+_LAZY_LISTS = {
+    "LOW_PRECISION_FUNCS": lambda: _LOW_PRECISION_JAX + _apex_low_precision(),
+    "FP32_FUNCS": lambda: _FP32_JAX + _loss_fp32(),
+}
+
+
+def __getattr__(name):
+    if name in _LAZY_LISTS:
+        value = globals()[name] = _LAZY_LISTS[name]()
+        return value
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 # -- promote: mixed-dtype n-ary ops (reference tensor_overrides CASTS) ------
 # JAX's numpy promotion already yields the widest float dtype for every

@@ -53,7 +53,9 @@ class BucketBuffers(NamedTuple):
 
 # One fp32 vector register tile: 8 sublanes x 128 lanes. Leaf offsets are
 # aligned to this so (rows, ROW)-shaped kernel blocks never straddle a
-# leaf boundary.
+# leaf boundary. It is also the tile XLA lays a 1-D buffer out in on the
+# chip, which is what makes the elementwise sweeps' (n // 128, 128) view
+# of a ROW-multiple buffer a bitcast (``ops/packed_optimizer.py``).
 ROW = 8 * 128
 
 # The reference's default chunk: 2048*32 elements

@@ -12,12 +12,18 @@ update, master->bf16 recast each walk ~GBs of fp32 state separately).
 This module is the real TPU ``multi_tensor_apply``: optimizer state lives
 in contiguous 1-D flat buffers (see
 ``apex_tpu.multi_tensor_apply.packing.PackSpec``), and one Pallas kernel
-per optimizer step grids over fixed-size chunks — viewing each buffer as
-``(rows, ROW)`` with ``chunk_size // ROW`` rows per grid step — and fuses
-grad unscale (``inv_scale``), the noop_flag overflow contract, the
-optimizer math, and the fp32-master -> param-dtype recast into a single
-read-modify-write pass. ``input_output_aliases`` donate m/v/master so the
-update is in place, exactly the CUDA kernels' contract.
+per optimizer step grids over fixed-size chunks and fuses grad unscale
+(``inv_scale``), the noop_flag overflow contract, the optimizer math, and
+the fp32-master -> param-dtype recast into a single read-modify-write
+pass. ``input_output_aliases`` donate m/v/master so the update is in
+place, exactly the CUDA kernels' contract.
+
+Two views of a flat buffer (``docs/packed_optimizers.md``): the
+elementwise sweeps (Adam, SGD) take ``(n // LANES, LANES)``, whose
+``(8, 128)`` tile IS the 1-D buffer's 1024-element HBM tile, so the view
+is a bitcast and the aliasing reaches the buffers the step was handed;
+the kernels with per-row side values (LAMB, NovoGrad, the reductions,
+the ``amp_C`` flat ops) take ``(n // ROW, ROW)``, which XLA relayouts.
 
 Kernel inventory (CUDA counterparts in parens):
 
@@ -81,20 +87,23 @@ def _scalars(*vals) -> jax.Array:
     ).reshape(1, _NSCAL)
 
 
-# Sublane multiple every (rows, ROW) block keeps: bf16 tiles are 16 x 128
+# Lane width of one vreg: the minor dim of the elementwise sweeps' view.
+LANES = 128
+
+# Sublane multiple every block keeps: bf16 tiles are 16 x 128
 # (fp32's 8 x 128 divides it), and grads/params ride these kernels in bf16.
 _SUBLANES = 16
 
 
-def _block_rows(n_rows: int, chunk_size: int) -> int:
-    """Rows per grid step: ``chunk_size`` elements rounded up to a
-    ``_SUBLANES`` multiple — or the whole buffer when it is smaller
-    (Mosaic accepts a block dim that is a tile multiple or the array's
-    own). The grid is ``pl.cdiv(n_rows, block)``: a ragged last block
-    reads past the end and its out-of-range rows are dropped on write,
-    which is exact here because every output is row-aligned with the
-    input."""
-    want = _round_up(max(1, int(chunk_size) // ROW), _SUBLANES)
+def _block_rows(n_rows: int, chunk_size: int, width: int = ROW) -> int:
+    """Rows of ``width`` elements per grid step: ``chunk_size`` elements
+    rounded up to a ``_SUBLANES`` multiple — or the whole buffer when it
+    is smaller (Mosaic accepts a block dim that is a tile multiple or the
+    array's own). The grid is ``pl.cdiv(n_rows, block)``: a ragged last
+    block reads past the end and its out-of-range rows are dropped on
+    write, which is exact here because every output is row-aligned with
+    the input."""
+    want = _round_up(max(1, int(chunk_size) // width), _SUBLANES)
     return n_rows if n_rows <= want else want
 
 
@@ -103,8 +112,8 @@ def _sspec():
                         memory_space=pltpu.SMEM)
 
 
-def _tspec(b):
-    return pl.BlockSpec((b, ROW), lambda i: (i, 0),
+def _tspec(b, width=ROW):
+    return pl.BlockSpec((b, width), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
 
 
@@ -116,13 +125,17 @@ def _cspec(b):
     return pl.BlockSpec((b, 1), lambda i: (i, 0), memory_space=pltpu.VMEM)
 
 
-def _rows(flat: jax.Array) -> jax.Array:
+def _rows(flat: jax.Array, width: int = ROW) -> jax.Array:
+    """``(n,)`` -> ``(n // width, width)``. At ``width=LANES`` an
+    ``(8, 128)`` tile of the view is 1024 consecutive elements — the 1-D
+    buffer's own HBM tile — so this reshape, and the ``reshape(-1)`` of a
+    result, move no data; at ``ROW`` both are relayouts."""
     n = flat.shape[0]
     if n % ROW:
         raise ValueError(
             f"flat buffer length {n} is not a multiple of ROW ({ROW}); "
             "pack with PackSpec (or pad) first")
-    return flat.reshape(n // ROW, ROW)
+    return flat.reshape(n // width, width)
 
 
 def _pad_to_rows(flat: jax.Array) -> Tuple[jax.Array, int]:
@@ -219,8 +232,9 @@ def packed_adam_apply(
             new_p if write_master else None,
         )
 
-    R = flat_g.shape[0] // ROW
-    B = _block_rows(R, chunk_size)
+    R = flat_g.shape[0] // LANES
+    B = _block_rows(R, chunk_size, LANES)
+    tile = _tspec(B, LANES)
 
     def body(s_ref, g_ref, m_ref, v_ref, p_ref, *outs):
         keep = s_ref[0, 0] >= 0.5 if has_noop else None
@@ -252,24 +266,24 @@ def packed_adam_apply(
         if write_master:
             outs[k][:] = new_p
 
-    out_shape = [jax.ShapeDtypeStruct((R, ROW), param_dtype)]
-    out_specs = [_tspec(B)]
+    out_shape = [jax.ShapeDtypeStruct((R, LANES), param_dtype)]
+    out_specs = [tile]
     aliases = {}
     if write_mv:
-        out_shape += [jax.ShapeDtypeStruct((R, ROW), jnp.float32)] * 2
-        out_specs += [_tspec(B), _tspec(B)]
+        out_shape += [jax.ShapeDtypeStruct((R, LANES), jnp.float32)] * 2
+        out_specs += [tile, tile]
         aliases[2] = 1  # flat_m -> new_m (input idx: scalars=0, g=1, m=2...)
         aliases[3] = 2
     if write_master:
-        out_shape.append(jax.ShapeDtypeStruct((R, ROW), jnp.float32))
-        out_specs.append(_tspec(B))
+        out_shape.append(jax.ShapeDtypeStruct((R, LANES), jnp.float32))
+        out_specs.append(tile)
         aliases[4] = len(out_shape) - 1
 
     outs = pl.pallas_call(
         body,
         name="apex_tpu_packed_adam",
         grid=(pl.cdiv(R, B),),
-        in_specs=[_sspec(), _tspec(B), _tspec(B), _tspec(B), _tspec(B)],
+        in_specs=[_sspec(), tile, tile, tile, tile],
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -277,7 +291,8 @@ def packed_adam_apply(
     )(
         _scalars(noop_s.astype(jnp.float32) if has_noop else 0.0,
                  inv_scale, lr, bc1, bc2),
-        _rows(flat_g), _rows(flat_m), _rows(flat_v), _rows(flat_src),
+        _rows(flat_g, LANES), _rows(flat_m, LANES), _rows(flat_v, LANES),
+        _rows(flat_src, LANES),
     )
     outs = [o.reshape(-1) for o in outs]
     p_out = outs[0]
@@ -350,8 +365,9 @@ def packed_sgd_apply(
         return (new_p.astype(param_dtype), new_buf,
                 new_p if write_master else None)
 
-    R = flat_g.shape[0] // ROW
-    B = _block_rows(R, chunk_size)
+    R = flat_g.shape[0] // LANES
+    B = _block_rows(R, chunk_size, LANES)
+    tile = _tspec(B, LANES)
 
     def body(s_ref, g_ref, b_ref, p_ref, *outs):
         keep = s_ref[0, 0] >= 0.5 if has_noop else None
@@ -367,21 +383,21 @@ def packed_sgd_apply(
             outs[2][:] = new_p
 
     out_shape = [
-        jax.ShapeDtypeStruct((R, ROW), param_dtype),
-        jax.ShapeDtypeStruct((R, ROW), jnp.float32),
+        jax.ShapeDtypeStruct((R, LANES), param_dtype),
+        jax.ShapeDtypeStruct((R, LANES), jnp.float32),
     ]
-    out_specs = [_tspec(B), _tspec(B)]
+    out_specs = [tile, tile]
     aliases = {2: 1}  # flat_buf -> new_buf
     if write_master:
-        out_shape.append(jax.ShapeDtypeStruct((R, ROW), jnp.float32))
-        out_specs.append(_tspec(B))
+        out_shape.append(jax.ShapeDtypeStruct((R, LANES), jnp.float32))
+        out_specs.append(tile)
         aliases[3] = 2
 
     outs = pl.pallas_call(
         body,
         name="apex_tpu_packed_sgd",
         grid=(pl.cdiv(R, B),),
-        in_specs=[_sspec(), _tspec(B), _tspec(B), _tspec(B)],
+        in_specs=[_sspec(), tile, tile, tile],
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -389,7 +405,7 @@ def packed_sgd_apply(
     )(
         _scalars(noop_s.astype(jnp.float32) if has_noop else 0.0, inv_scale,
                  lr, jnp.asarray(first_run, jnp.float32)),
-        _rows(flat_g), _rows(flat_buf), _rows(flat_src),
+        _rows(flat_g, LANES), _rows(flat_buf, LANES), _rows(flat_src, LANES),
     )
     outs = [o.reshape(-1) for o in outs]
     return outs[0], outs[1], (outs[2] if write_master else None)

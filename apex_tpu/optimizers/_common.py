@@ -28,7 +28,6 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import optax
 
 Pytree = Any
 
@@ -102,8 +101,9 @@ class FusedOptimizer:
     def step(self, grads: Pytree, state, params: Pytree, **kw):  # pragma: no cover
         raise NotImplementedError
 
-    def as_gradient_transformation(self) -> optax.GradientTransformation:
+    def as_gradient_transformation(self) -> "optax.GradientTransformation":
         """Adapt to optax: update() returns (new_params - params) deltas."""
+        import optax  # on first use: a step needs no optax (0.6 s to import)
 
         def init_fn(params):
             return self.init(params)

@@ -15,9 +15,6 @@ from .distributed import (  # noqa: F401
 from .LARC import LARC, larc_adjust_gradients, larc_transform  # noqa: F401
 from .sync_batchnorm import sync_batch_norm  # noqa: F401
 
-try:
-    from .sync_batchnorm import SyncBatchNorm, convert_syncbn_model  # noqa: F401
-except ImportError:  # flax unavailable
-    pass
+from .sync_batchnorm import __getattr__  # noqa: F401  SyncBatchNorm, convert_syncbn_model (flax, on first use)
 
 from .multiproc import initialize_distributed  # noqa: F401

@@ -40,11 +40,4 @@ from .utils import (  # noqa: F401
     split_tensor_along_last_dim,
 )
 
-try:
-    from .layers import (  # noqa: F401
-        ColumnParallelLinear,
-        RowParallelLinear,
-        VocabParallelEmbedding,
-    )
-except ImportError:  # pragma: no cover - flax unavailable
-    pass
+from .layers import __getattr__  # noqa: F401  Column/RowParallelLinear, VocabParallelEmbedding (flax, on first use)
