@@ -916,6 +916,285 @@ def _flash_bwd(scale, causal, dropout_p, block_q, block_k, interpret,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# ---------------------------------------------------------------------------
+# the banded path: causal attention with a sliding window and/or grouped
+# K/V heads. The grid walks a list of the score tiles that touch the band
+# ``0 <= i - j < window`` (scalar-prefetched: tile t is q block ``qi[t]``
+# against k block ``ki[t]``), so a tile wholly outside the band costs no
+# grid step and no DMA, in the forward and in both backward kernels. Query
+# head ``h`` reads K/V head ``h // (n // n_kv)``; the dkv kernel walks each
+# K/V head's group of query heads as part of its tile list, so dk and dv
+# come out summed over the group.
+# ---------------------------------------------------------------------------
+
+
+def band_tiles(s: int, bq: int, bk: int, window: Optional[int]):
+    """``(iq, ik)`` of every ``[bq, bk]`` score tile of an ``[s, s]`` causal
+    attention that holds a pair with ``0 <= i - j < window`` (``window``
+    ``None``: every ``j <= i``), q-major."""
+    out = []
+    for iq in range(s // bq):
+        for ik in range(s // bk):
+            if ik * bk > iq * bq + bq - 1:
+                continue            # wholly above the diagonal
+            if window is not None and (ik + 1) * bk <= iq * bq - window + 1:
+                continue            # wholly older than the window
+            out.append((iq, ik))
+    return out
+
+
+def _tile_lists(tiles, group: int = 1, kmajor: bool = False):
+    """The int32 arrays a banded kernel prefetches: per grid step the q
+    block, the k block, the query head within its K/V group, and whether
+    the step opens / closes the accumulation of its row of tiles (q-major:
+    one q block; k-major: one k block over every query head of the group)."""
+    import numpy as np
+
+    if kmajor:
+        steps = sorted(((iq, ik, g) for iq, ik in tiles for g in range(group)),
+                       key=lambda t: (t[1], t[2], t[0]))
+        row = [t[1] for t in steps]
+    else:
+        steps = [(iq, ik, 0) for (iq, ik) in tiles]
+        row = [t[0] for t in steps]
+    n = len(steps)
+    first = [int(i == 0 or row[i] != row[i - 1]) for i in range(n)]
+    last = [int(i == n - 1 or row[i] != row[i + 1]) for i in range(n)]
+    cols = list(zip(*steps)) + [first, last]
+    return [jnp.asarray(np.asarray(c, np.int32)) for c in cols]
+
+
+def _band_mask(s, qi, ki, window):
+    s = jnp.where(ki > qi, _NEG_INF, s)
+    if window is not None:
+        s = jnp.where(qi - ki >= window, _NEG_INF, s)
+    return s
+
+
+def _band_scores(q_ref, k_ref, iq, ik, *, scale, window, block_q, block_k):
+    q = _scaled_q(q_ref, scale)
+    s = jax.lax.dot_general(
+        q, k_ref[0, 0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    qi, ki = _tile_indices(iq, ik, block_q, block_k)
+    return q, _band_mask(s, qi, ki, window)
+
+
+def _band_fwd_kernel(
+    qi_ref, ki_ref, g_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
+    o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale, window, block_q,
+    block_k,
+):
+    t = pl.program_id(2)
+
+    @pl.when(first_ref[t] == 1)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    _, s = _band_scores(q_ref, k_ref, qi_ref[t], ki_ref[t], scale=scale,
+                        window=window, block_q=block_q, block_k=block_k)
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # a row's window may not reach into the first tile of its band: such a
+    # row is all -1e30 here and must add exactly nothing
+    p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(last_ref[t] == 1)
+    def _finish():
+        # every row holds its own diagonal key, so l > 0
+        l = l_scr[:, :1]
+        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(l)
+
+
+def _band_dq_kernel(
+    qi_ref, ki_ref, g_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
+    lse_ref, delta_ref, dq_ref, acc_scr, *, scale, window, block_q, block_k,
+):
+    t = pl.program_id(2)
+
+    @pl.when(first_ref[t] == 1)
+    def _init():
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    _, s = _band_scores(q_ref, k_ref, qi_ref[t], ki_ref[t], scale=scale,
+                        window=window, block_q=block_q, block_k=block_k)
+    p = jnp.exp(s - lse_ref[0, 0][:, :1])
+    dp = jax.lax.dot_general(
+        do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds = p * (dp - delta_ref[0, 0][:, :1])
+    acc_scr[:] += jax.lax.dot_general(
+        ds.astype(k_ref.dtype), k_ref[0, 0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+
+    @pl.when(last_ref[t] == 1)
+    def _finish():
+        dq_ref[0, 0] = acc_scr[:].astype(dq_ref.dtype)
+
+
+def _band_dkv_kernel(
+    qi_ref, ki_ref, g_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
+    lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, scale, window,
+    block_q, block_k,
+):
+    t = pl.program_id(2)
+
+    @pl.when(first_ref[t] == 1)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    q, s = _band_scores(q_ref, k_ref, qi_ref[t], ki_ref[t], scale=scale,
+                        window=window, block_q=block_q, block_k=block_k)
+    p = jnp.exp(s - lse_ref[0, 0][:, :1])
+    do = do_ref[0, 0]
+    dv_scr[:] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dp = jax.lax.dot_general(
+        do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds = p * (dp - delta_ref[0, 0][:, :1])
+    # the chain rule's *scale rode in with the scaled q
+    dk_scr[:] += jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+    @pl.when(last_ref[t] == 1)
+    def _finish():
+        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _band_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _band_fwd(q, k, v, scale, window, block_q, block_k, interpret):
+    b, n, s, d = q.shape
+    group = n // k.shape[1]
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    lists = _tile_lists(band_tiles(s, bq, bk, window))
+    q_map = lambda ib, ih, t, qi, ki, g, f, l: (ib, ih, qi[t], 0)
+    k_map = lambda ib, ih, t, qi, ki, g, f, l: (ib, ih // group, ki[t], 0)
+    o, lse = pl.pallas_call(
+        functools.partial(_band_fwd_kernel, scale=scale, window=window,
+                          block_q=bq, block_k=bk),
+        name="apex_tpu_flash_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(b, n, int(lists[0].shape[0])),
+            in_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bk, d), k_map),
+                      pl.BlockSpec((1, 1, bk, d), k_map)],
+            out_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
+                       pl.BlockSpec((1, 1, bq, 1), q_map)],
+            scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
+                            pltpu.VMEM((bq, 128), jnp.float32),
+                            pltpu.VMEM((bq, d), jnp.float32)]),
+        out_shape=[_sds((b, n, s, d), q.dtype, q, k, v),
+                   _sds((b, n, s, 1), jnp.float32, q, k, v)],
+        compiler_params=_band_params(),
+        interpret=interpret,
+    )(*lists, q, k, v)
+    return o, lse
+
+
+def _band_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
+              interpret):
+    b, n, s, d = q.shape
+    n_kv = k.shape[1]
+    group = n // n_kv
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    tiles = band_tiles(s, bq, bk, window)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    kw = dict(scale=scale, window=window, block_q=bq, block_k=bk)
+
+    lists = _tile_lists(tiles)
+    q_map = lambda ib, ih, t, qi, ki, g, f, l: (ib, ih, qi[t], 0)
+    k_map = lambda ib, ih, t, qi, ki, g, f, l: (ib, ih // group, ki[t], 0)
+    dq = pl.pallas_call(
+        functools.partial(_band_dq_kernel, **kw),
+        name="apex_tpu_flash_bwd_dq",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(b, n, int(lists[0].shape[0])),
+            in_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bk, d), k_map),
+                      pl.BlockSpec((1, 1, bk, d), k_map),
+                      pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bq, 1), q_map),
+                      pl.BlockSpec((1, 1, bq, 1), q_map)],
+            out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
+        out_shape=_sds(q.shape, q.dtype, q, k, v, do),
+        compiler_params=_band_params(),
+        interpret=interpret,
+    )(*lists, q, k, v, do, lse, delta)
+
+    lists = _tile_lists(tiles, group, kmajor=True)
+    q_map = lambda ib, ih, t, qi, ki, g, f, l: (
+        ib, ih * group + g[t], qi[t], 0)
+    k_map = lambda ib, ih, t, qi, ki, g, f, l: (ib, ih, ki[t], 0)
+    dk, dv = pl.pallas_call(
+        functools.partial(_band_dkv_kernel, **kw),
+        name="apex_tpu_flash_bwd_dkv",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(b, n_kv, int(lists[0].shape[0])),
+            in_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bk, d), k_map),
+                      pl.BlockSpec((1, 1, bk, d), k_map),
+                      pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bq, 1), q_map),
+                      pl.BlockSpec((1, 1, bq, 1), q_map)],
+            out_specs=[pl.BlockSpec((1, 1, bk, d), k_map),
+                       pl.BlockSpec((1, 1, bk, d), k_map)],
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)]),
+        out_shape=[_sds(k.shape, k.dtype, q, k, v, do),
+                   _sds(v.shape, v.dtype, q, k, v, do)],
+        compiler_params=_band_params(),
+        interpret=interpret,
+    )(*lists, q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_band(q, k, v, scale, window, block_q, block_k, interpret):
+    return _band_fwd(q, k, v, scale, window, block_q, block_k, interpret)[0]
+
+
+def _flash_band_fwd(q, k, v, scale, window, block_q, block_k, interpret):
+    o, lse = _band_fwd(q, k, v, scale, window, block_q, block_k, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_band_bwd(scale, window, block_q, block_k, interpret, res, do):
+    q, k, v, o, lse = res
+    return _band_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
+                     interpret)
+
+
+_flash_band.defvjp(_flash_band_fwd, _flash_band_bwd)
+
+
 def _resolve_seed(dropout_p, dropout_seed):
     if not 0.0 <= dropout_p < 1.0:
         # out-of-range p would wrap the 32-bit keep threshold silently
@@ -955,6 +1234,7 @@ def flash_attention(
     v: jax.Array,  # [b, n, s_k, d]
     *,
     causal: bool = False,
+    window: Optional[int] = None,  # causal only: keys with 0 <= i - j < window
     kv_mask: Optional[jax.Array] = None,  # [b, s_k]; True/nonzero = attend
     bias: Optional[jax.Array] = None,  # [b|1, n|1, s_q|1, s_k] logit bias
     bias_grad: bool = True,
@@ -982,9 +1262,19 @@ def flash_attention(
     (reduced over broadcast dims). Pass ``bias_grad=False`` for a constant
     bias (ALiBi slopes, a folded mask): the backward then skips the O(s^2)
     dbias emission entirely and the bias cotangent is zeros.
+
+    ``window`` (a sliding window: keys with ``0 <= i - j < window``) and
+    grouped K/V heads (``k``, ``v`` of ``n_kv`` heads, ``n % n_kv == 0``;
+    query head ``h`` reads head ``h // (n // n_kv)``; ``dk``/``dv`` summed
+    over the group) take the banded kernels, which walk only the score
+    tiles that touch the band: causal self-attention without bias, mask or
+    dropout.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    if window is not None or k.shape[1] != q.shape[1]:
+        return _banded(q, k, v, causal, window, kv_mask, bias, scale,
+                       dropout_p, block_q, block_k, interpret)
     if kv_mask is not None:
         kv_mask = kv_mask.astype(jnp.int8)
     if bias is not None:
@@ -1025,6 +1315,34 @@ def flash_attention(
         float(dropout_p), int(block_q), int(block_k), bool(interpret),
         bool(bias_grad), tuple(int(x) for x in bwd_blocks),
     )
+
+
+def _banded(q, k, v, causal, window, kv_mask, bias, scale, dropout_p,
+            block_q, block_k, interpret):
+    """The checks of the banded path, and its call."""
+    n, n_kv = q.shape[1], k.shape[1]
+    if not causal or q.shape[2] != k.shape[2]:
+        raise ValueError(
+            "a window or grouped K/V heads need causal self-attention "
+            f"(causal={causal}, s_q={q.shape[2]}, s_k={k.shape[2]})")
+    if kv_mask is not None or bias is not None or dropout_p:
+        raise ValueError(
+            "the banded flash kernels (window / grouped K/V heads) take no "
+            "kv_mask, bias or dropout")
+    if n % n_kv or v.shape[1] != n_kv:
+        raise ValueError(
+            f"{n} query heads do not divide into {n_kv} K/V heads "
+            f"(v has {v.shape[1]})")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be at least 1, got {window}")
+    if window is not None and window >= q.shape[2]:
+        window = None           # every j <= i is inside it: plain causal
+    if not interpret and jax.default_backend() != "tpu":
+        interpret = True
+    return _flash_band(
+        q, k.astype(q.dtype), v.astype(q.dtype), float(scale),
+        None if window is None else int(window), int(block_q), int(block_k),
+        bool(interpret))
 
 
 def flash_attention_sbhd(
@@ -1094,7 +1412,8 @@ def flash_attention_varlen(
     return o[0].transpose(1, 0, 2)  # [total, n, d]
 
 
-def masked_scores(q, k, kv_mask, causal, scale, bias=None) -> jax.Array:
+def masked_scores(q, k, kv_mask, causal, scale, bias=None,
+                  window=None) -> jax.Array:
     """Dense fp32 ``[b, n, s_q, s_k]`` logits with the kernels' exact
     masking conventions (scale -> +bias -> causal/kv_mask as ``_NEG_INF``
     fills). Shared by :func:`mha_reference` and the context-parallel
@@ -1109,6 +1428,8 @@ def masked_scores(q, k, kv_mask, causal, scale, bias=None) -> jax.Array:
         qi = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
         s = jnp.where(ki > qi, _NEG_INF, s)
+        if window is not None:
+            s = jnp.where(qi - ki >= window, _NEG_INF, s)
     if kv_mask is not None:
         s = jnp.where(kv_mask[:, None, None, :] != 0, s, _NEG_INF)
     return s
@@ -1116,14 +1437,17 @@ def masked_scores(q, k, kv_mask, causal, scale, bias=None) -> jax.Array:
 
 def mha_reference(
     q, k, v, *, causal=False, kv_mask=None, bias=None, scale=None,
-    dropout_p=0.0, dropout_seed=None,
+    dropout_p=0.0, dropout_seed=None, window=None,
 ) -> jax.Array:
     """Materialised-score reference (for tests): same math, O(s^2) — incl.
     the kernels' exact hash-dropout mask and the zeros-for-fully-masked-rows
-    convention."""
+    convention. Grouped K/V heads are repeated to the query heads."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    s = masked_scores(q, k, kv_mask, causal, scale, bias)
+    if k.shape[1] != q.shape[1]:
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = masked_scores(q, k, kv_mask, causal, scale, bias, window)
     p = jax.nn.softmax(s, axis=-1)
     # zeros-for-fully-masked-rows (flash kernel convention): a row whose
     # keys are all masked outputs 0, not the uniform average softmax yields

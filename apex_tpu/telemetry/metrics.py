@@ -65,6 +65,11 @@ class MetricsState(NamedTuple):
     loss_scale: jax.Array       # f32 last observed loss scale (0 = none)
     overflow_skips: jax.Array   # i32 cumulative skipped (overflowed) steps
     scale_growths: jax.Array    # i32 cumulative loss-scale growth events
+    # the expert layers' counters (transformer/moe.py: gpt_loss(...,
+    # moe_stats=True)); all zero for a model without experts
+    moe_routed: jax.Array       # f32 window sum: assignments routed to the experts held here
+    moe_max_load: jax.Array     # f32 last step's largest expert load over the mean load
+    moe_dropped: jax.Array      # i32 cumulative assignments that found no row (dropless: 0)
 
 
 def init_metrics() -> MetricsState:
@@ -77,7 +82,8 @@ def init_metrics() -> MetricsState:
         total_steps=i(), window_steps=i(), loss_sum=f(), loss_last=f(),
         grad_norm_sum=f(), param_norm_sum=f(), tokens=f(),
         total_tokens=f(), loss_scale=f(), overflow_skips=i(),
-        scale_growths=i(),
+        scale_growths=i(), moe_routed=f(), moe_max_load=f(),
+        moe_dropped=i(),
     )
 
 
@@ -99,8 +105,12 @@ def accumulate(
     params: Optional[Pytree] = None,
     param_norm: Optional[jax.Array] = None,
     tokens=None,
+    moe_stats: Optional[dict] = None,
 ) -> MetricsState:
     """Fold one step's statistics into the window (pure, in-jit).
+
+    ``moe_stats`` is what ``gpt_loss(..., moe_stats=True)`` returns beside
+    the loss: ``routed``, ``max_over_mean_load``, ``dropped``.
 
     ``loss``/``tokens`` are free — they fuse into work the step already
     does. ``grads=``/``params=`` compute a global L2 norm, which costs one
@@ -118,6 +128,12 @@ def accumulate(
         param_norm = _global_l2(params)
     f32 = lambda v: jnp.asarray(v, jnp.float32)  # noqa: E731
     tok = f32(tokens) if tokens is not None else jnp.float32(0.0)
+    if moe_stats is not None:
+        m = m._replace(
+            moe_routed=m.moe_routed + f32(moe_stats["routed"]),
+            moe_max_load=f32(moe_stats["max_over_mean_load"]),
+            moe_dropped=m.moe_dropped
+            + jnp.asarray(moe_stats["dropped"], jnp.int32))
     return m._replace(
         total_steps=m.total_steps + 1,
         window_steps=m.window_steps + 1,
@@ -171,6 +187,9 @@ def summarize(m: MetricsState) -> dict:
         "loss_scale": m.loss_scale,
         "overflow_skips": m.overflow_skips,
         "scale_growths": m.scale_growths,
+        "moe_routed": m.moe_routed / n,
+        "moe_max_load": m.moe_max_load,
+        "moe_dropped": m.moe_dropped,
     }
 
 
@@ -178,7 +197,7 @@ def _reset_window(m: MetricsState) -> MetricsState:
     z = jnp.float32(0.0)
     return m._replace(
         window_steps=jnp.int32(0), loss_sum=z, grad_norm_sum=z,
-        param_norm_sum=z, tokens=z,
+        param_norm_sum=z, tokens=z, moe_routed=z,
     )
 
 
@@ -221,7 +240,8 @@ def drain(
 
     def _emit(total_steps, window_steps, loss_sum, loss_last,
               grad_norm_sum, param_norm_sum, tokens, total_tokens,
-              loss_scale, overflow_skips, scale_growths):
+              loss_scale, overflow_skips, scale_growths, moe_routed,
+              moe_max_load, moe_dropped):
         now = time.perf_counter()
         n = max(int(window_steps), 1)
         rec = {
@@ -238,6 +258,10 @@ def drain(
             "overflow_skips": int(overflow_skips),
             "scale_growths": int(scale_growths),
         }
+        if float(moe_routed) or int(moe_dropped):
+            rec.update(moe_routed=float(moe_routed) / n,
+                       moe_max_load=float(moe_max_load),
+                       moe_dropped=int(moe_dropped))
         # one wall-timestamp choke point for the whole record schema
         # (recorder.stamp_wall) — tools/lint_determinism.py enforces it
         stamp_wall(rec)

@@ -49,7 +49,11 @@ LAYER_SCOPES = (
     "apex_tpu.layer_stack",        # the layers' scan: slices of the stacked parameters, stacking of their gradients
     "apex_tpu.transformer_layer",  # one layer; directly under it, under neither child: norms and residual tails
     "apex_tpu.attention",          # qkv GEMM, layout changes, flash kernel, out projection
-    "apex_tpu.mlp",                # both GEMMs and the activation
+    "apex_tpu.mlp",                # both GEMMs and the activation; on an expert layer the whole expert MLP, the four below nested in it
+    "apex_tpu.moe_router",         # in mlp: float32 scores, top-k, weights
+    "apex_tpu.moe_dispatch",       # in mlp: sort, gather into expert order, the weighted gather back
+    "apex_tpu.moe_experts",        # in mlp: the grouped products over the experts held, and their activation
+    "apex_tpu.moe_shared",         # in mlp: the shared expert
     "apex_tpu.lm_head",            # final layer norm and the logits GEMM
     "apex_tpu.cross_entropy",      # the loss and its scan (with the head GEMM where gpt_loss chunk-fuses the two)
     "apex_tpu.amp_scaler",         # loss scaling, unscale, overflow probe, scale update

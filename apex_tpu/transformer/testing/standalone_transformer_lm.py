@@ -24,7 +24,8 @@ specs for every weight are exported for pjit/shard_map wiring.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,9 +42,12 @@ from ..tensor_parallel import (
 )
 from ..tensor_parallel import mappings
 from ...ops.layer_norm import layer_norm as fused_layer_norm
+from ...ops.layer_norm import rms_norm
 from ...ops.flash_attention import (
+    flash_attention,
     flash_attention_available,
     flash_attention_sbhd,
+    mha_reference,
 )
 from ...ops.fused_block import (
     BIAS_DROPOUT_RESIDUAL_FWD,
@@ -56,6 +60,14 @@ from ...ops.fused_block import (
 from ...telemetry import numerics as _numerics
 
 Pytree = Any
+
+
+class LayerKind(NamedTuple):
+    """What one layer of a mixed stack is (``GPTConfig.layer_kinds``)."""
+
+    window: Optional[int] = None    # attention sees keys 0 <= i - j < window
+    rotary: bool = False            # rotary positions on q and k
+    experts: bool = False           # expert MLP (else the dense one)
 
 
 @dataclasses.dataclass
@@ -143,6 +155,88 @@ class GPTConfig:
     fp8_amax_reduction_axes: Optional[Tuple[str, ...]] = None
     # BERT extras
     add_binary_head: bool = False
+    # --- the block by the model's own shape -------------------------------
+    # ``layer_kinds`` (one LayerKind a layer) makes the stack a list of
+    # layers of different kinds (window or full attention, rotary or no
+    # positions, dense or expert MLP) run one after another, where the
+    # default is one kind of layer scanned over stacked parameters. The
+    # fields below describe that block's shape and are read on that path
+    # only; ``init_gpt_params`` lays the parameters out to match.
+    layer_kinds: Optional[Tuple[LayerKind, ...]] = None
+    num_kv_heads: Optional[int] = None      # K/V heads; None: as many as query heads
+    head_dim: Optional[int] = None          # None: hidden_size / heads
+    norm: str = "layernorm"                 # | "rmsnorm" (gain only)
+    sandwich_norm: bool = False             # a norm after each branch too
+    qk_norm: bool = False                   # RMSNorm over each head of q and k
+    attention_gate: bool = False            # out = Wo (ctx * sigmoid(Wg x))
+    gated_mlp: bool = False                 # down(silu(gate x) * up x)
+    linear_bias: bool = True                # False: bias-free linears
+    learned_positions: bool = True          # False: no position table
+    rope_theta: float = 10000.0
+    embedding_scale: Optional[float] = None  # h = E[tokens] * scale
+    untied_head: bool = False               # a head matrix of its own
+    # experts (layers whose kind says so): the router is num_experts wide,
+    # this rank holds experts_held = (first, count) of them
+    num_experts: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    experts_per_token: int = 0
+    expert_ffn_size: int = 0
+    shared_expert_ffn_size: int = 0         # 0: no shared expert
+    router_score: str = "sigmoid"           # | "softmax"
+    route_norm: bool = True
+    route_scale: float = 1.0
+
+    def __post_init__(self):
+        shaped = {
+            "num_kv_heads": None, "head_dim": None, "norm": "layernorm",
+            "sandwich_norm": False, "qk_norm": False,
+            "attention_gate": False, "gated_mlp": False,
+            "linear_bias": True, "learned_positions": True,
+            "embedding_scale": None, "untied_head": False, "num_experts": 0}
+        if self.layer_kinds is None:
+            changed = [k for k, v in shaped.items() if getattr(self, k) != v]
+            if changed:
+                raise ValueError(
+                    f"{changed} describe the block that layer_kinds runs; "
+                    "the scanned stack of one kind of layer runs the "
+                    "LayerNorm + GeLU block only: set layer_kinds")
+            return
+        self.layer_kinds = tuple(LayerKind(*k) for k in self.layer_kinds)
+        if len(self.layer_kinds) != self.num_layers:
+            raise ValueError(
+                f"layer_kinds names {len(self.layer_kinds)} layers of "
+                f"num_layers {self.num_layers}")
+        for what, on in (
+                ("tensor parallelism (tensor_model_parallel_size > 1): the "
+                 "grouped K/V heads, the gate and the experts have no "
+                 "partition rule yet", self.tensor_model_parallel_size > 1),
+                ("sequence_parallel: it is the tensor-parallel block's",
+                 self.sequence_parallel),
+                ("context_parallel_axis: ring attention has no window and "
+                 "no grouped K/V heads", self.context_parallel_axis is not None),
+                ("fp8: the delayed-scaling state is laid out for the four "
+                 "GEMMs of the LayerNorm + GeLU block", self.fp8),
+                ("fused_block: its tail kernels are written for bias + "
+                 "LayerNorm + GeLU", self.fused_block),
+                ("add_binary_head: BERT's", self.add_binary_head)):
+            if on:
+                raise ValueError(f"layer_kinds does not support {what}")
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.num_attention_heads % self.kv_heads:
+            raise ValueError(
+                f"{self.num_attention_heads} query heads do not divide into "
+                f"{self.kv_heads} K/V heads")
+        if any(k.experts for k in self.layer_kinds):
+            first, count = self.experts_held or (0, 0)
+            if not (self.num_experts > 0 and count > 0 and first >= 0
+                    and first + count <= self.num_experts
+                    and 0 < self.experts_per_token <= self.num_experts
+                    and self.expert_ffn_size > 0 and self.gated_mlp):
+                raise ValueError(
+                    "expert layers need num_experts, experts_held = (first, "
+                    "count) inside it, experts_per_token, expert_ffn_size "
+                    "and gated_mlp (the experts are gated MLPs)")
 
     @property
     def ffn_size(self) -> int:
@@ -150,7 +244,11 @@ class GPTConfig:
 
     @property
     def kv_channels(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_attention_heads
 
 
 # --------------------------------------------------------------------------
@@ -165,6 +263,8 @@ def init_gpt_params(cfg: GPTConfig, key: jax.Array) -> Pytree:
     ``1/sqrt(2*num_layers)`` for output projections, zeros for biases, ones
     for LN weights.
     """
+    if cfg.layer_kinds is not None:
+        return _init_params_by_kind(cfg, key)
     h, L, v = cfg.hidden_size, cfg.num_layers, cfg.vocab_size
     ffn = cfg.ffn_size
     k = jax.random.split(key, 8)
@@ -205,6 +305,73 @@ def init_gpt_params(cfg: GPTConfig, key: jax.Array) -> Pytree:
             "head_w": n(k[3], (2, h)),
             "head_b": jnp.zeros((2,), dt),
         }
+    return params
+
+
+def _init_params_by_kind(cfg: GPTConfig, key: jax.Array) -> Pytree:
+    """The parameters of a ``layer_kinds`` stack: ``params["layers"]`` is a
+    list of one dict a layer (a layer's leaves depend on its kind, so they
+    do not stack). Linears are ``[out, in]``; the held experts' matrices
+    are ``[count, in, out]``, the layout the grouped product reads. Every
+    matrix normal(0, 0.02), unit gains, zero biases."""
+    h, v, dt = cfg.hidden_size, cfg.vocab_size, cfg.params_dtype
+    n, nkv, d = cfg.num_attention_heads, cfg.kv_heads, cfg.kv_channels
+    keys = iter(jax.random.split(key, 16 * cfg.num_layers + 8))
+
+    def w(*shape):
+        return (jax.random.normal(next(keys), shape) * 0.02).astype(dt)
+
+    def norm_gains(*names):
+        out = {f"{name}_w": jnp.ones((h,), dt) for name in names}
+        if cfg.norm == "layernorm":
+            out.update({f"{name}_b": jnp.zeros((h,), dt) for name in names})
+        return out
+
+    def linear(name, out_dim, in_dim):
+        out = {f"{name}_w": w(out_dim, in_dim)}
+        if cfg.linear_bias:
+            out[f"{name}_b"] = jnp.zeros((out_dim,), dt)
+        return out
+
+    def dense_mlp(ffn):
+        if cfg.gated_mlp:
+            return {**linear("gate", ffn, h), **linear("up", ffn, h),
+                    **linear("down", h, ffn)}
+        return {**linear("fc1", ffn, h), **linear("fc2", h, ffn)}
+
+    layers = []
+    for kind in cfg.layer_kinds:
+        lp = norm_gains("input_ln", "post_ln")
+        if cfg.sandwich_norm:
+            lp.update(norm_gains("post_attn_ln", "post_mlp_ln"))
+        lp.update(linear("q", n * d, h))
+        lp.update(linear("k", nkv * d, h))
+        lp.update(linear("v", nkv * d, h))
+        lp.update(linear("proj", h, n * d))
+        if cfg.attention_gate:
+            lp.update(linear("attn_gate", n * d, h))
+        if cfg.qk_norm:
+            lp["q_norm_w"] = jnp.ones((d,), dt)
+            lp["k_norm_w"] = jnp.ones((d,), dt)
+        if kind.experts:
+            count, f = cfg.experts_held[1], cfg.expert_ffn_size
+            lp["router_w"] = w(cfg.num_experts, h)
+            lp["experts_gate_w"] = w(count, h, f)
+            lp["experts_up_w"] = w(count, h, f)
+            lp["experts_down_w"] = w(count, f, h)
+            if cfg.shared_expert_ffn_size:
+                fs = cfg.shared_expert_ffn_size
+                lp["shared_gate_w"], lp["shared_up_w"] = w(fs, h), w(fs, h)
+                lp["shared_down_w"] = w(h, fs)
+        else:
+            lp.update(dense_mlp(cfg.ffn_size))
+        layers.append(lp)
+    params = {"embedding": {"word": w(v, h)}, "layers": layers,
+              **norm_gains("final_ln")}
+    if cfg.learned_positions:
+        params["embedding"]["position"] = w(cfg.max_position_embeddings, h)
+    if cfg.untied_head:
+        params["lm_head"] = w(v, h)
     return params
 
 
@@ -682,6 +849,133 @@ def parallel_mlp(
     return out
 
 
+def _norm(cfg: GPTConfig, lp, name: str, x32: jax.Array) -> jax.Array:
+    """The configured norm of a float32 ``[..., h]``, in float32."""
+    if cfg.norm == "rmsnorm":
+        return rms_norm(x32, lp[f"{name}_w"].astype(jnp.float32), 1,
+                        cfg.layernorm_epsilon)
+    return fused_layer_norm(
+        x32, lp[f"{name}_w"].astype(jnp.float32),
+        lp[f"{name}_b"].astype(jnp.float32), eps=cfg.layernorm_epsilon)
+
+
+def _linear(lp, name: str, x: jax.Array, spec: str, shape=None):
+    """``einsum(spec, x, W)`` with ``W = lp[name_w]`` stored ``[out, in]``
+    (seen as ``shape`` where the heads are split off), plus the bias where
+    the block has one."""
+    w = lp[f"{name}_w"].astype(x.dtype)
+    y = jnp.einsum(spec, x, w if shape is None else w.reshape(shape))
+    b = lp.get(f"{name}_b")
+    if b is None:
+        return y
+    b = b.astype(x.dtype)
+    if spec.endswith("->bnsd"):         # heads off a projection: [n, d]
+        b = b.reshape(shape[:2])[None, :, None, :]
+    return y + b
+
+
+def _rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions over the whole last dimension of ``[b, n, s, d]``
+    (rotate-half convention), computed in float32."""
+    s, d = x.shape[-2:]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None]   # [s, d/2]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * cos + rot * sin).astype(x.dtype)
+
+
+@jax.named_scope("apex_tpu.attention")
+def attention_by_kind(cfg: GPTConfig, kind: LayerKind, lp, x: jax.Array):
+    """Causal self-attention of a ``layer_kinds`` layer over ``x [s, b,
+    h]``: separate q / k / v projections with ``kv_heads`` K/V heads,
+    optionally RMSNorm over each head of q and k, rotary positions, a
+    window, and a sigmoid gate on the context before the output
+    projection. Heads come off the projections as ``[b, n, s, d]``, the
+    layout the flash kernels read."""
+    s, b, h = x.shape
+    n, nkv, d = cfg.num_attention_heads, cfg.kv_heads, cfg.kv_channels
+    q = _linear(lp, "q", x, "sbh,ndh->bnsd", (n, d, h))
+    k = _linear(lp, "k", x, "sbh,ndh->bnsd", (nkv, d, h))
+    v = _linear(lp, "v", x, "sbh,ndh->bnsd", (nkv, d, h))
+    if cfg.qk_norm:
+        q = rms_norm(q.astype(jnp.float32), lp["q_norm_w"].astype(jnp.float32),
+                     1, cfg.layernorm_epsilon).astype(x.dtype)
+        k = rms_norm(k.astype(jnp.float32), lp["k_norm_w"].astype(jnp.float32),
+                     1, cfg.layernorm_epsilon).astype(x.dtype)
+    if kind.rotary:
+        q, k = _rotary(q, cfg.rope_theta), _rotary(k, cfg.rope_theta)
+    scale = 1.0 / (d ** 0.5)
+    use_flash = cfg.use_flash_attention
+    if use_flash is None:
+        use_flash = flash_attention_available(s, s, d)
+    if use_flash:
+        from ...ops.flash_attention import require_kernel_tileable
+
+        require_kernel_tileable(s, d, "flash attention by layer kind")
+        ctx = flash_attention(q, k, v, causal=True, window=kind.window,
+                              scale=scale)
+    else:
+        ctx = mha_reference(q, k, v, causal=True, window=kind.window,
+                            scale=scale)
+    ctx = ctx.astype(x.dtype)
+    if cfg.attention_gate:
+        gate = _linear(lp, "attn_gate", x, "sbh,ndh->bnsd", (n, d, h))
+        ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(x.dtype)
+    return _linear(lp, "proj", ctx, "bnsd,hnd->sbh", (h, n, d))
+
+
+@jax.named_scope("apex_tpu.mlp")
+def mlp_by_kind(cfg: GPTConfig, kind: LayerKind, lp, x: jax.Array,
+                x32: jax.Array):
+    """The dense MLP (gated or fc1-GeLU-fc2) or, on an expert layer, this
+    rank's part of the expert layer (``transformer/moe.py``; the router
+    reads the float32 ``x32``). Returns ``(y, moe_stats or None)``."""
+    if kind.experts:
+        from .. import moe
+
+        s, b, h = x.shape
+        y, stats = moe.expert_mlp(
+            x.reshape(s * b, h), x32.reshape(s * b, h), lp,
+            num_experts=cfg.num_experts, held=cfg.experts_held,
+            per_token=cfg.experts_per_token, score=cfg.router_score,
+            route_norm=cfg.route_norm, route_scale=cfg.route_scale,
+            interpret=jax.default_backend() != "tpu")
+        return y.reshape(s, b, h), stats
+    if cfg.gated_mlp:
+        g = _linear(lp, "gate", x, "sbh,fh->sbf")
+        u = _linear(lp, "up", x, "sbh,fh->sbf")
+        return _linear(lp, "down", jax.nn.silu(g) * u, "sbf,hf->sbh"), None
+    inter = jax.nn.gelu(_linear(lp, "fc1", x, "sbh,fh->sbf"),
+                        approximate=True)
+    return _linear(lp, "fc2", inter, "sbf,hf->sbh"), None
+
+
+def layer_by_kind(cfg: GPTConfig, kind: LayerKind, lp, hidden: jax.Array):
+    """One pre-norm layer of a ``layer_kinds`` stack: ``h += [norm](attn(
+    norm(h)))``, ``h += [norm](mlp(norm(h)))``; the bracketed norms with
+    ``sandwich_norm``. Norms run in float32. No dropout on this path.
+    Returns ``(hidden, moe_stats or None)``."""
+    with jax.named_scope("apex_tpu.transformer_layer"):
+        dt = hidden.dtype
+        f32 = jnp.float32
+        attn = attention_by_kind(
+            cfg, kind, lp, _norm(cfg, lp, "input_ln", hidden.astype(f32))
+            .astype(dt))
+        if cfg.sandwich_norm:
+            attn = _norm(cfg, lp, "post_attn_ln", attn.astype(f32))
+        hidden = (hidden.astype(f32) + attn.astype(f32)).astype(dt)
+        pre = _norm(cfg, lp, "post_ln", hidden.astype(f32))
+        out, stats = mlp_by_kind(cfg, kind, lp, pre.astype(dt), pre)
+        if cfg.sandwich_norm:
+            out = _norm(cfg, lp, "post_mlp_ln", out.astype(f32))
+        hidden = (hidden.astype(f32) + out.astype(f32)).astype(dt)
+    return hidden, stats
+
+
 def transformer_layer(
     cfg: GPTConfig,
     lp: Dict[str, jax.Array],
@@ -830,6 +1124,46 @@ def _selective_elementwise_policy(prim, *args, **kwargs):
         *args, **kwargs)
 
 
+def _remat(cfg: GPTConfig, fn):
+    """``fn`` under the configured recompute granularity."""
+    if cfg.recompute_granularity == "full":
+        return jax.checkpoint(fn)
+    if cfg.recompute_granularity == "selective":
+        return jax.checkpoint(fn, policy=_selective_policy)
+    if cfg.recompute_granularity == "selective_elementwise":
+        return jax.checkpoint(fn, policy=_selective_elementwise_policy)
+    if cfg.recompute_granularity is not None:
+        raise ValueError(
+            f"unknown recompute_granularity "
+            f"{cfg.recompute_granularity!r}: use None, 'full', 'selective' "
+            f"or 'selective_elementwise'"
+        )
+    return fn
+
+
+def _block_by_kind(cfg: GPTConfig, layers, hidden, moe_stats: bool):
+    """The ``layer_kinds`` stack: each layer its own trace, under
+    ``apex_tpu.layer_stack`` like the scan. The expert layers' counters add
+    up over the layers (the load ratio takes the largest)."""
+    if len(layers) != len(cfg.layer_kinds):
+        raise ValueError(
+            f"{len(layers)} layers of parameters for layer_kinds of "
+            f"{len(cfg.layer_kinds)}")
+    total = None
+    with jax.named_scope("apex_tpu.layer_stack"):
+        for kind, lp in zip(cfg.layer_kinds, layers):
+            hidden, stats = _remat(
+                cfg, functools.partial(layer_by_kind, cfg, kind))(lp, hidden)
+            if stats is not None:
+                total = stats if total is None else {
+                    "routed": total["routed"] + stats["routed"],
+                    "max_over_mean_load": jnp.maximum(
+                        total["max_over_mean_load"],
+                        stats["max_over_mean_load"]),
+                    "dropped": total["dropped"] + stats["dropped"]}
+    return (hidden, total) if moe_stats else hidden
+
+
 def transformer_block(
     cfg: GPTConfig,
     layer_params: Dict[str, jax.Array],  # stacked [L, ...]
@@ -840,8 +1174,16 @@ def transformer_block(
     deterministic: bool = True,
     fp8_states=None,  # {name: Fp8DenseState [L, ...]}
     fp8_carriers=None,  # {name: [L]}
+    moe_stats: bool = False,
 ):
     """Scan the stacked layers (reference ``ParallelTransformer`` loop).
+
+    With ``cfg.layer_kinds`` the layers differ in kind and in their
+    parameters, which a scan over one stacked tree cannot run:
+    ``layer_params`` is then a list of one dict a layer, run one after
+    another (:func:`_block_by_kind`), each under the same recompute policy;
+    ``moe_stats=True`` returns ``(hidden, stats)`` with the expert layers'
+    counters.
 
     ``recompute_granularity="full"`` rematerialises each layer in backward —
     the reference's ``--recompute-granularity full`` activation
@@ -856,6 +1198,8 @@ def transformer_block(
     the scan's xs and the rolled states come back as ys: returns
     ``(hidden, new_fp8_states)``.
     """
+    if cfg.layer_kinds is not None:
+        return _block_by_kind(cfg, layer_params, hidden, moe_stats)
     L = layer_params["qkv_w"].shape[0]
     with_fp8 = fp8_states is not None
 
@@ -882,18 +1226,7 @@ def transformer_block(
             return (h, key), new_fp8_l
         return (h, key), None
 
-    if cfg.recompute_granularity == "full":
-        body = jax.checkpoint(body)
-    elif cfg.recompute_granularity == "selective":
-        body = jax.checkpoint(body, policy=_selective_policy)
-    elif cfg.recompute_granularity == "selective_elementwise":
-        body = jax.checkpoint(body, policy=_selective_elementwise_policy)
-    elif cfg.recompute_granularity is not None:
-        raise ValueError(
-            f"unknown recompute_granularity "
-            f"{cfg.recompute_granularity!r}: use None, 'full', 'selective' "
-            f"or 'selective_elementwise'"
-        )
+    body = _remat(cfg, body)
 
     unroll = int(cfg.layer_unroll)
     if unroll == -1:
@@ -933,7 +1266,7 @@ def gpt_embed(
     deterministic: bool = True,
 ) -> jax.Array:
     """Word + position embeddings → [s, b, h] (reference ``Embedding``)."""
-    if position_ids is None:
+    if position_ids is None and cfg.learned_positions:
         position_ids = jnp.broadcast_to(
             _local_position_ids(cfg, tokens.shape[1]), tokens.shape
         )
@@ -943,8 +1276,12 @@ def gpt_embed(
         )
     else:
         word = jnp.take(params["embedding"]["word"], tokens, axis=0)
-    pos = jnp.take(params["embedding"]["position"], position_ids, axis=0)
-    emb = (word + pos).astype(cfg.compute_dtype)
+    if cfg.embedding_scale is not None:
+        word = word.astype(jnp.float32) * cfg.embedding_scale
+    if cfg.learned_positions:
+        word = word + jnp.take(
+            params["embedding"]["position"], position_ids, axis=0)
+    emb = word.astype(cfg.compute_dtype)
     emb = jnp.transpose(emb, (1, 0, 2))  # [b,s,h] -> [s,b,h]
     if axis_name is not None and cfg.sequence_parallel:
         # enter the sequence-parallel region: each TP rank keeps its s/tp
@@ -995,11 +1332,14 @@ def gpt_hidden(
     deterministic: bool = True,
     fp8_states=None,
     fp8_carriers=None,
+    moe_stats: bool = False,
 ):
     """GPT trunk → pre-head hidden states [s, b, h] (embeddings, layer
     stack, final LN, SP gather) — everything of ``gpt_forward`` except the
     LM-head projection. With ``fp8_states`` the projection GEMMs run the
-    e4m3/e5m2 recipe and ``(hidden, new_fp8_states)`` is returned."""
+    e4m3/e5m2 recipe and ``(hidden, new_fp8_states)`` is returned; with
+    ``moe_stats`` (a ``layer_kinds`` stack with expert layers) ``(hidden,
+    stats)``."""
     if bool(cfg.fp8) != (fp8_states is not None):
         raise ValueError(
             "GPTConfig.fp8 and the fp8_states argument must agree: the "
@@ -1026,13 +1366,16 @@ def gpt_hidden(
     hidden = gpt_embed(
         cfg, params, tokens, None, axis_name, k_embed, deterministic
     )
-    new_fp8 = None
+    new_fp8 = stats = None
     hidden = transformer_block(
         cfg, params["layers"], hidden, None, axis_name, k_block,
         deterministic, fp8_states=fp8_states, fp8_carriers=fp8_carriers,
+        moe_stats=moe_stats,
     )
     if fp8_states is not None:
         hidden, new_fp8 = hidden
+    elif moe_stats:
+        hidden, stats = hidden
     hidden = _final_layer_norm(cfg, params, hidden)
     if axis_name is not None and cfg.sequence_parallel:
         # leave the SP region before the LM head: all-gather the sequence
@@ -1043,6 +1386,8 @@ def gpt_hidden(
         )
     if fp8_states is not None:
         return hidden, new_fp8
+    if moe_stats:
+        return hidden, stats
     return hidden
 
 
@@ -1078,6 +1423,9 @@ def gpt_forward(
 def _final_layer_norm(cfg, params, hidden):
     """The LN before the output head; on the device timeline it counts to
     the head (``apex_tpu.lm_head``), not to a layer."""
+    if cfg.norm == "rmsnorm":
+        return _norm(cfg, params, "final_ln", hidden.astype(jnp.float32)
+                     ).astype(cfg.compute_dtype)
     return fused_layer_norm(
         hidden.astype(jnp.float32),
         params["final_ln_w"].astype(jnp.float32),
@@ -1086,8 +1434,15 @@ def _final_layer_norm(cfg, params, hidden):
     ).astype(cfg.compute_dtype)
 
 
+def _head_weight(cfg, params):
+    """The output head's ``[vocab, hidden]`` matrix: the embedding table,
+    or the head's own where ``cfg.untied_head``."""
+    return params["lm_head"] if cfg.untied_head else params["embedding"]["word"]
+
+
 def _lm_head(cfg, params, hidden, axis_name):
-    """Tied-embedding output head: a column-parallel GEMM over the
+    """Output head (tied to the embedding unless ``cfg.untied_head``): a
+    column-parallel GEMM over the
     vocab-sharded table (reference ``parallel_lm_logits``) — the
     copy-to-region makes backward all-reduce the partial d(hidden)."""
     if axis_name is not None:
@@ -1096,7 +1451,7 @@ def _lm_head(cfg, params, hidden, axis_name):
         )
     return jnp.einsum(
         "sbh,vh->sbv", hidden,
-        params["embedding"]["word"].astype(cfg.compute_dtype),
+        _head_weight(cfg, params).astype(cfg.compute_dtype),
         preferred_element_type=jnp.float32,
     )
 
@@ -1112,8 +1467,14 @@ def gpt_loss(
     deterministic: bool = True,
     fp8_states=None,
     fp8_carriers=None,
+    moe_stats: bool = False,
 ):
     """Masked mean LM loss (reference GPT ``loss_func``).
+
+    ``moe_stats=True`` (single device, a ``layer_kinds`` stack with expert
+    layers) returns ``(loss, stats)``: assignments routed to the experts
+    held here, the largest expert's load over the mean, assignments that
+    found no row (``telemetry.accumulate(..., moe_stats=stats)``).
 
     Single-device path: the head GEMM and the CE are chunk-fused
     (``contrib.xentropy.lm_head_cross_entropy``) so the ``[b*s, vocab]``
@@ -1142,9 +1503,12 @@ def gpt_loss(
         hidden = gpt_hidden(
             cfg, params, tokens, axis_name, dropout_key, deterministic,
             fp8_states=fp8_states, fp8_carriers=fp8_carriers,
+            moe_stats=moe_stats,
         )
         if fp8_states is not None:
             hidden, new_fp8 = hidden
+        elif moe_stats:
+            hidden, stats = hidden
         with jax.named_scope("apex_tpu.cross_entropy"):
             s, b, h = hidden.shape
             n = s * b
@@ -1159,7 +1523,7 @@ def gpt_loss(
                     break
             losses = lm_head_cross_entropy(
                 hidden.reshape(n, h),
-                params["embedding"]["word"],
+                _head_weight(cfg, params),
                 jnp.transpose(labels, (1, 0)).reshape(n),  # [s, b] rows
                 chunk_size=chunk,
                 save_logits_dtype=(
@@ -1185,6 +1549,8 @@ def gpt_loss(
             loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
     if fp8_states is not None:
         return loss, new_fp8
+    if moe_stats:
+        return loss, stats
     return loss
 
 

@@ -503,3 +503,93 @@ def test_explicit_bwd_blocks_match_default(causal):
     for a, b, name in zip(g_def, g_exp, "qkv"):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-5, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# the banded path: a sliding window and grouped K/V heads
+# ---------------------------------------------------------------------------
+from apex_tpu.ops.flash_attention import band_tiles  # noqa: E402
+
+BANDED = {
+    # b, n, n_kv, s, d, window, block_q, block_k
+    "window_and_groups": (2, 4, 2, 64, 16, 24, 16, 16),
+    "one_kv_head": (1, 4, 1, 64, 16, 40, 16, 8),
+    "window_with_all_heads": (1, 4, 4, 64, 16, 24, 32, 16),
+    "window_as_long_as_the_sequence": (1, 4, 2, 64, 16, 64, 16, 16),
+    "window_longer_than_the_sequence": (1, 2, 1, 32, 16, 100, 16, 16),
+    "groups_without_a_window": (1, 4, 2, 64, 16, None, 16, 16),
+    "sequence_no_multiple_of_the_window": (1, 8, 2, 48, 16, 20, 16, 16),
+    "window_of_one": (1, 2, 2, 32, 16, 1, 16, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BANDED))
+def test_banded_forward_and_backward_match_the_reference(case):
+    b, n, n_kv, s, d, window, bq, bk = BANDED[case]
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (b, n, s, d))
+    k = jax.random.normal(ks[1], (b, n_kv, s, d))
+    v = jax.random.normal(ks[2], (b, n_kv, s, d))
+    w = jax.random.normal(ks[3], (b, n, s, d))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=bq, block_k=bk, interpret=True)
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, causal=True, window=window)
+
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(ref(*a) * w), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape       # dk, dv summed over the group
+        np.testing.assert_allclose(g, r, atol=5e-5)
+    if window is not None and window >= s:
+        np.testing.assert_allclose(
+            flash(q, k, v), mha_reference(q, k, v, causal=True), atol=2e-5)
+
+
+def _all_eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _all_eqns(inner)
+
+
+def test_tiles_outside_the_band_cost_no_grid_step():
+    """The banded kernels' grid is the list of tiles that touch the band:
+    at the cell's shape a sliding layer walks 21 of the 36 causal tiles
+    (64 in the square), and every listed tile holds a pair of the band."""
+    s, blk, window = 8192, 1024, 2048
+    causal, banded = band_tiles(s, blk, blk, None), band_tiles(
+        s, blk, blk, window)
+    assert len(causal) == 36 and len(banded) == 21
+    for iq, ik in banded:
+        i = np.arange(iq * blk, (iq + 1) * blk)[:, None]
+        j = np.arange(ik * blk, (ik + 1) * blk)[None, :]
+        assert np.any((j <= i) & (i - j < window))
+    # the grid of a compiled call is the list's length: per head, forward
+    jaxpr = jax.make_jaxpr(lambda q, k: flash_attention(
+        q, k, k, causal=True, window=24, block_q=16, block_k=16,
+        interpret=True))(jnp.zeros((1, 2, 64, 16)), jnp.zeros((1, 1, 64, 16)))
+    grids = [e.params["grid_mapping"].grid
+             for e in _all_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    assert grids and grids[0] == (1, 2, len(band_tiles(64, 16, 16, 24)))
+
+
+def test_the_banded_path_refuses_what_it_does_not_compute():
+    q = jnp.zeros((1, 4, 32, 16))
+    kv = jnp.zeros((1, 2, 32, 16))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, kv, kv, causal=False)
+    with pytest.raises(ValueError, match="kv_mask, bias or dropout"):
+        flash_attention(q, kv, kv, causal=True,
+                        kv_mask=jnp.ones((1, 32), bool))
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(q, jnp.zeros((1, 3, 32, 16)),
+                        jnp.zeros((1, 3, 32, 16)), causal=True)
+    with pytest.raises(ValueError, match="at least 1"):
+        flash_attention(q, q, q, causal=True, window=0)

@@ -70,3 +70,37 @@ def test_cast_lists_build_on_first_use():
     assert (jnp, "matmul") in low and (jnp, "exp") in fp32
     with pytest.raises(AttributeError):
         jo.NO_SUCH_LIST
+
+
+_DENSE_PATH = _TRAIN_PATH.replace(
+    'print(",".join(m for m in ("flax", "optax") if m in sys.modules))',
+    'print(",".join(m for m in ("apex_tpu.transformer.moe", '
+    '"apex_tpu.ops.grouped_matmul") if m in sys.modules))')
+
+
+def test_the_expert_layer_loads_on_first_use():
+    """The dense cells' imports load neither the expert layer nor its
+    grouped matmul; a stack with an expert layer loads both when traced."""
+    env = {"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
+           "PYTHONPATH": ":".join(sys.path)}
+    out = subprocess.run([sys.executable, "-c", _DENSE_PATH],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "", f"imported: {out.stdout.strip()}"
+    first_use = _DENSE_PATH.replace("print(", """
+import jax, jax.numpy as jnp
+from apex_tpu.transformer.testing import LayerKind, init_gpt_params
+cfg = GPTConfig(num_layers=1, hidden_size=32, num_attention_heads=2,
+                vocab_size=64, hidden_dropout=0.0, attention_dropout=0.0,
+                layer_kinds=(LayerKind(None, False, True),), gated_mlp=True,
+                num_experts=4, experts_held=(0, 2), experts_per_token=2,
+                expert_ffn_size=16)
+tokens = jnp.zeros((1, 8), jnp.int32)
+jax.eval_shape(lambda p: gpt_loss(cfg, p, tokens, tokens),
+               init_gpt_params(cfg, jax.random.PRNGKey(0)))
+print(""", 1)
+    out = subprocess.run([sys.executable, "-c", first_use],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == (
+        "apex_tpu.transformer.moe,apex_tpu.ops.grouped_matmul")
