@@ -70,6 +70,8 @@ class MetricsState(NamedTuple):
     moe_routed: jax.Array       # f32 window sum: assignments routed to the experts held here
     moe_max_load: jax.Array     # f32 last step's largest expert load over the mean load
     moe_dropped: jax.Array      # i32 cumulative assignments that found no row (dropless: 0)
+    moe_rows_walked: jax.Array  # f32 window sum: buffer rows the expert layers' sweeps walked
+    moe_buffer_rows: jax.Array  # f32 last step's buffer rows (the worst case the routing allows)
 
 
 def init_metrics() -> MetricsState:
@@ -83,7 +85,7 @@ def init_metrics() -> MetricsState:
         grad_norm_sum=f(), param_norm_sum=f(), tokens=f(),
         total_tokens=f(), loss_scale=f(), overflow_skips=i(),
         scale_growths=i(), moe_routed=f(), moe_max_load=f(),
-        moe_dropped=i(),
+        moe_dropped=i(), moe_rows_walked=f(), moe_buffer_rows=f(),
     )
 
 
@@ -110,7 +112,8 @@ def accumulate(
     """Fold one step's statistics into the window (pure, in-jit).
 
     ``moe_stats`` is what ``gpt_loss(..., moe_stats=True)`` returns beside
-    the loss: ``routed``, ``max_over_mean_load``, ``dropped``.
+    the loss: ``routed``, ``max_over_mean_load``, ``dropped``,
+    ``rows_walked``, ``buffer_rows``.
 
     ``loss``/``tokens`` are free — they fuse into work the step already
     does. ``grads=``/``params=`` compute a global L2 norm, which costs one
@@ -133,7 +136,9 @@ def accumulate(
             moe_routed=m.moe_routed + f32(moe_stats["routed"]),
             moe_max_load=f32(moe_stats["max_over_mean_load"]),
             moe_dropped=m.moe_dropped
-            + jnp.asarray(moe_stats["dropped"], jnp.int32))
+            + jnp.asarray(moe_stats["dropped"], jnp.int32),
+            moe_rows_walked=m.moe_rows_walked + f32(moe_stats["rows_walked"]),
+            moe_buffer_rows=f32(moe_stats["buffer_rows"]))
     return m._replace(
         total_steps=m.total_steps + 1,
         window_steps=m.window_steps + 1,
@@ -190,6 +195,9 @@ def summarize(m: MetricsState) -> dict:
         "moe_routed": m.moe_routed / n,
         "moe_max_load": m.moe_max_load,
         "moe_dropped": m.moe_dropped,
+        "moe_rows_walked": m.moe_rows_walked / n,
+        "moe_walked_share": m.moe_rows_walked
+        / (n * jnp.maximum(m.moe_buffer_rows, 1.0)),
     }
 
 
@@ -197,7 +205,7 @@ def _reset_window(m: MetricsState) -> MetricsState:
     z = jnp.float32(0.0)
     return m._replace(
         window_steps=jnp.int32(0), loss_sum=z, grad_norm_sum=z,
-        param_norm_sum=z, tokens=z, moe_routed=z,
+        param_norm_sum=z, tokens=z, moe_routed=z, moe_rows_walked=z,
     )
 
 
@@ -241,7 +249,7 @@ def drain(
     def _emit(total_steps, window_steps, loss_sum, loss_last,
               grad_norm_sum, param_norm_sum, tokens, total_tokens,
               loss_scale, overflow_skips, scale_growths, moe_routed,
-              moe_max_load, moe_dropped):
+              moe_max_load, moe_dropped, moe_rows_walked, moe_buffer_rows):
         now = time.perf_counter()
         n = max(int(window_steps), 1)
         rec = {
@@ -261,7 +269,10 @@ def drain(
         if float(moe_routed) or int(moe_dropped):
             rec.update(moe_routed=float(moe_routed) / n,
                        moe_max_load=float(moe_max_load),
-                       moe_dropped=int(moe_dropped))
+                       moe_dropped=int(moe_dropped),
+                       moe_rows_walked=float(moe_rows_walked) / n,
+                       moe_walked_share=float(moe_rows_walked)
+                       / (n * max(float(moe_buffer_rows), 1.0)))
         # one wall-timestamp choke point for the whole record schema
         # (recorder.stamp_wall) — tools/lint_determinism.py enforces it
         stamp_wall(rec)

@@ -51,8 +51,8 @@ LAYER_SCOPES = (
     "apex_tpu.attention",          # qkv GEMM, layout changes, flash kernel, out projection
     "apex_tpu.mlp",                # both GEMMs and the activation; on an expert layer the whole expert MLP, the four below nested in it
     "apex_tpu.moe_router",         # in mlp: float32 scores, top-k, weights
-    "apex_tpu.moe_dispatch",       # in mlp: sort, gather into expert order, the weighted gather back
-    "apex_tpu.moe_experts",        # in mlp: the grouped products over the experts held, and their activation
+    "apex_tpu.moe_dispatch",       # in mlp: sort, gather into expert order, each row in use added back into its token
+    "apex_tpu.moe_experts",        # in mlp: the grouped products over the experts held, and their activation over the row tiles in use
     "apex_tpu.moe_shared",         # in mlp: the shared expert
     "apex_tpu.lm_head",            # final layer norm and the logits GEMM
     "apex_tpu.cross_entropy",      # the loss and its scan (with the head GEMM where gpt_loss chunk-fuses the two)

@@ -1155,12 +1155,12 @@ def _block_by_kind(cfg: GPTConfig, layers, hidden, moe_stats: bool):
             hidden, stats = _remat(
                 cfg, functools.partial(layer_by_kind, cfg, kind))(lp, hidden)
             if stats is not None:
+                # every counter adds up; the load ratio takes the largest
                 total = stats if total is None else {
-                    "routed": total["routed"] + stats["routed"],
-                    "max_over_mean_load": jnp.maximum(
-                        total["max_over_mean_load"],
-                        stats["max_over_mean_load"]),
-                    "dropped": total["dropped"] + stats["dropped"]}
+                    name: (jnp.maximum
+                           if name == "max_over_mean_load" else jnp.add)(
+                               total[name], stats[name])
+                    for name in stats}
     return (hidden, total) if moe_stats else hidden
 
 

@@ -75,12 +75,13 @@ def test_cast_lists_build_on_first_use():
 _DENSE_PATH = _TRAIN_PATH.replace(
     'print(",".join(m for m in ("flax", "optax") if m in sys.modules))',
     'print(",".join(m for m in ("apex_tpu.transformer.moe", '
-    '"apex_tpu.ops.grouped_matmul") if m in sys.modules))')
+    '"apex_tpu.ops.grouped_matmul", "apex_tpu.ops.moe_rows") '
+    'if m in sys.modules))')
 
 
 def test_the_expert_layer_loads_on_first_use():
     """The dense cells' imports load neither the expert layer nor its
-    grouped matmul; a stack with an expert layer loads both when traced."""
+    kernels; a stack with an expert layer loads all three when traced."""
     env = {"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
            "PYTHONPATH": ":".join(sys.path)}
     out = subprocess.run([sys.executable, "-c", _DENSE_PATH],
@@ -103,4 +104,5 @@ print(""", 1)
                          capture_output=True, text=True, env=env, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.strip() == (
-        "apex_tpu.transformer.moe,apex_tpu.ops.grouped_matmul")
+        "apex_tpu.transformer.moe,apex_tpu.ops.grouped_matmul,"
+        "apex_tpu.ops.moe_rows")

@@ -7,10 +7,14 @@ against the ``afmoe`` family's plain reference.
 - the shares add up: the routed parts of all shares plus the shared expert
   once equal the uncut reference's layer;
 - dropless: nothing is dropped under any routing, the counters say so;
+- the row kernels (``ops/moe_rows.py``) equal the XLA formulation in value
+  and in every gradient, under any routing, whatever stands past the routed
+  rows, and say how much of the buffer they walked;
 - combinations the new fields do not support raise at construction;
 - the new scopes stand in the compiled step's text.
 """
 import dataclasses
+import functools
 import os
 import sys
 
@@ -22,6 +26,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from apex_tpu import telemetry  # noqa: E402
+from apex_tpu.ops import moe_rows  # noqa: E402
+from apex_tpu.ops.grouped_matmul import grouped_matmul, row_tile  # noqa: E402
 from apex_tpu.transformer import moe  # noqa: E402
 from apex_tpu.transformer.testing import GPTConfig, LayerKind, gpt_loss  # noqa: E402
 from apex_tpu.transformer.testing.standalone_transformer_lm import (  # noqa: E402
@@ -168,6 +174,275 @@ def test_the_counters_reach_the_device_resident_telemetry(tiny):
         expert_layers * tokens.size * d["per_token"])
     assert float(out["moe_max_load"]) >= 1.0
     assert int(out["moe_dropped"]) == 0
+
+
+SKEWS = ["uniform", "all_on_the_held", "none_on_the_held", "one_expert"]
+# 96 tokens x 4 choices on 4 held experts of 16: a buffer of three row tiles
+# of 128, of which none, some or all are in use
+ROUTED = dict(tokens=96, k=4, experts=16, count=4, hidden=128, ffn=128)
+
+
+def _routing(skew, seed=0):
+    """``(selected, weights)`` drawn so that the held experts ``[0, count)``
+    get a share, every assignment, none, or one expert's worth."""
+    t, k, e, count = (ROUTED[n] for n in ("tokens", "k", "experts", "count"))
+    scores = jax.random.uniform(jax.random.PRNGKey(seed), (t, e))
+    held = jnp.arange(e) < count
+    scores = scores + {"uniform": 0.0 * held, "all_on_the_held": 2.0 * held,
+                       "none_on_the_held": -2.0 * held,
+                       "one_expert": -2.0 * held + 4.0 * (jnp.arange(e) == 1)
+                       }[skew]
+    _, selected = jax.lax.top_k(scores, k)
+    weights = jax.random.uniform(jax.random.PRNGKey(seed + 1), (t, k)) + 0.5
+    return selected.astype(jnp.int32), weights
+
+
+def _plan(skew):
+    selected, weights = _routing(skew)
+    rows = moe.buffer_rows(ROUTED["tokens"], ROUTED["k"], ROUTED["count"])
+    return moe.plan(selected, weights, (0, ROUTED["count"]), rows), weights
+
+
+@jax.custom_vjp
+def _poison(rows, past):
+    """NaN on the rows past the sum, on the way forward and on the way
+    back: what a buffer nobody wrote may hold."""
+    return jnp.where(past, jnp.nan, rows)
+
+
+_poison.defvjp(lambda rows, past: (_poison(rows, past), past),
+               lambda past, d: (jnp.where(past, jnp.nan, d), None))
+
+
+def _moved(x, weights, mats, p, *, kernels, poison=False):
+    """dispatch -> two products -> activation -> product -> combine, as
+    ``expert_mlp`` strings them; ``kernels=False`` is the XLA formulation
+    (what runs off the TPU without the interpreter)."""
+    past = ~moe._in_use(p)[:, None]
+    dirty = (lambda rows: _poison(rows, past)) if poison else (lambda r: r)
+    gmm = lambda a, b: dirty(grouped_matmul(  # noqa: E731
+        a, b, p.group_sizes, interpret=True))
+    for_gate, for_up = moe.dispatch(x, p, 2, kernels)
+    gate, up = gmm(dirty(for_gate), mats[0]), gmm(dirty(for_up), mats[1])
+    if kernels:
+        act = moe_rows.gated_act(gate, up, moe._tiles(p), True)
+    else:
+        act = jax.nn.silu(gate) * up
+    return moe.combine(gmm(dirty(act), mats[2]), weights, p, kernels)
+
+
+def _operands(dtype):
+    t, h, f, count = (ROUTED[n] for n in ("tokens", "hidden", "ffn", "count"))
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(ks[0], (t, h)).astype(dtype)
+    mats = tuple((jax.random.normal(k, shape) / 8).astype(dtype)
+                 for k, shape in zip(ks[1:4], ((count, h, f), (count, h, f),
+                                               (count, f, h))))
+    cot = jax.random.normal(ks[4], (t, h))
+    return x, mats, cot
+
+
+def _value_and_grads(p, weights, dtype, **kw):
+    x, mats, cot = _operands(dtype)
+
+    @jax.jit
+    def run(x, weights, mats):
+        def loss(x, weights, mats):
+            y = jax.checkpoint(functools.partial(_moved, p=p, **kw))(
+                x, weights, mats)
+            return jnp.sum(y.astype(jnp.float32) * cot), y
+        (_, y), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                           has_aux=True)(x, weights, mats)
+        return y, grads
+
+    with jax.default_matmul_precision("highest"):
+        return jax.tree_util.tree_map(
+            lambda a: np.asarray(a, np.float32), run(x, weights, mats))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("skew", SKEWS)
+def test_the_row_kernels_equal_the_xla_formulation(skew, dtype):
+    """Value, ``d x``, ``d weights`` and the gradients that pass through
+    ``d rows`` (the three matrices'), jitted under full recomputation: zero
+    rows in use and a full buffer are both legal. float32 to the order of
+    the sums, bfloat16 to a unit in the last place of the largest element
+    (the activation rounds once where XLA's rounds by operation)."""
+    p, weights = _plan(skew)
+    got = _value_and_grads(p, weights, jnp.dtype(dtype), kernels=True)
+    want = _value_and_grads(p, weights, jnp.dtype(dtype), kernels=False)
+    in_use = {"all_on_the_held": p.token_of_row.shape[0],
+              "none_on_the_held": 0, "one_expert": ROUTED["tokens"]}
+    assert skew not in in_use or int(jnp.sum(p.group_sizes)) == in_use[skew]
+    rounding = 2e-6 if dtype == "float32" else 2.0 ** -6
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.all(np.isfinite(g))
+        np.testing.assert_allclose(
+            g, w, atol=rounding * max(float(np.max(np.abs(w))), 1e-3))
+
+
+@pytest.mark.parametrize("skew", ["uniform", "one_expert",
+                                  "none_on_the_held"])
+def test_rows_past_the_sum_may_hold_anything_in_every_buffer(skew):
+    """NaN past the routed rows in every buffer between ``dispatch`` and
+    ``combine``, and in every gradient handed back through them: the loss
+    side and all gradients are finite and equal the clean run's (the last
+    tile in use holds such rows too: the kernels work on whole tiles)."""
+    p, weights = _plan(skew)
+    used = int(jnp.sum(p.group_sizes))
+    assert used % row_tile(p.token_of_row.shape[0]) or skew != "uniform"
+    clean = _value_and_grads(p, weights, jnp.float32, kernels=True)
+    dirty = _value_and_grads(p, weights, jnp.float32, kernels=True,
+                             poison=True)
+    for d, c in zip(jax.tree_util.tree_leaves(dirty),
+                    jax.tree_util.tree_leaves(clean)):
+        assert np.all(np.isfinite(d))
+        np.testing.assert_array_equal(d, c)
+
+
+def _plan_by_argsort(selected, held, rows):
+    """PR 28's ``plan``, kept as the oracle of the order."""
+    first, count = held
+    tokens, k = selected.shape
+    n = tokens * k
+    local = selected - first
+    is_held = (local >= 0) & (local < count)
+    key = jnp.where(is_held, local, count).reshape(n)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    row_of = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))
+    order = jnp.concatenate([order, jnp.zeros((max(rows - n, 0),),
+                                              jnp.int32)])[:rows]
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None], axis=0)
+    return order // k, order % k, row_of.reshape(tokens, k), is_held, sizes
+
+
+@pytest.mark.parametrize("count", [4, 2])
+@pytest.mark.parametrize("skew", SKEWS)
+def test_the_plan_s_rows_in_use_are_the_argsort_s(skew, count):
+    """One sort now carries the weights: the rows in use come out element
+    for element as the ``argsort`` gave them, each with its assignment's
+    weight (``count`` 2 < k: a buffer shorter than the assignments)."""
+    selected, weights = _routing(skew)
+    held = (1, count)
+    rows = moe.buffer_rows(ROUTED["tokens"], ROUTED["k"], count)
+    p = moe.plan(selected, weights, held, rows)
+    tok, choice, row_of, is_held, sizes = _plan_by_argsort(
+        selected, held, rows)
+    used = int(jnp.sum(sizes))
+    np.testing.assert_array_equal(p.group_sizes, sizes)
+    np.testing.assert_array_equal(p.held, is_held)
+    np.testing.assert_array_equal(p.token_of_row[:used], tok[:used])
+    np.testing.assert_array_equal(p.choice_of_row[:used], choice[:used])
+    np.testing.assert_array_equal(jnp.where(is_held, p.row_of, -1),
+                                  jnp.where(is_held, row_of, -1))
+    np.testing.assert_array_equal(
+        p.weight_of_row[:used], weights[tok[:used], choice[:used]])
+    np.testing.assert_array_equal(jnp.sort(p.order),
+                                  jnp.arange(selected.size))
+    # and a sort by the order takes a row's number back to its assignment
+    back = moe._by_assignment(jnp.arange(rows, dtype=jnp.float32), p)
+    np.testing.assert_array_equal(jnp.where(is_held, back, -1),
+                                  jnp.where(is_held, row_of, -1))
+
+
+@pytest.mark.parametrize("skew, share", [("uniform", 4 / 32),
+                                         ("all_on_the_held", 1.0)])
+def test_the_share_of_the_buffer_walked_reaches_the_telemetry(skew, share):
+    """``rows_walked`` is the sweeps' common bound, whole row tiles: about
+    ``count / num_experts`` of the buffer at an even load, all of it when
+    every choice falls on the experts held (1.0 at an even load would mean
+    the bound is not applied)."""
+    tokens, k, experts, count, h, f = 800, 4, 32, 4, 64, 32
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    x = jax.random.normal(ks[0], (tokens, h))
+    lp = {"router_w": jax.random.normal(ks[1], (experts, h)) * 0.1,
+          "experts_gate_w": jax.random.normal(ks[2], (count, h, f)) * 0.1,
+          "experts_up_w": jax.random.normal(ks[3], (count, h, f)) * 0.1,
+          "experts_down_w": jax.random.normal(ks[4], (count, f, h)) * 0.1}
+    if skew == "all_on_the_held":
+        lp["expert_bias"] = 10.0 * (jnp.arange(experts) < count)
+
+    @jax.jit
+    def step(metrics):
+        _, stats = moe.expert_mlp(x, x, lp, num_experts=experts,
+                                  held=(0, count), per_token=k,
+                                  interpret=True)
+        return telemetry.accumulate(metrics, moe_stats=stats), stats
+
+    metrics, stats = step(step(telemetry.init_metrics())[0])
+    rows = moe.buffer_rows(tokens, k, count)
+    tile = row_tile(rows)
+    assert rows // tile == 25 and float(stats["buffer_rows"]) == rows
+    walked = -(-int(stats["routed"]) // tile) * tile
+    assert float(stats["rows_walked"]) == walked
+    out = telemetry.summarize(metrics)
+    assert float(out["moe_rows_walked"]) == walked       # the window's mean
+    np.testing.assert_allclose(out["moe_walked_share"], walked / rows)
+    assert abs(float(out["moe_walked_share"]) - share) <= 1.5 * tile / rows
+    records = []
+    jax.jit(lambda m: telemetry.drain(m, records.append))(metrics)
+    jax.effects_barrier()
+    np.testing.assert_allclose(records[0]["moe_walked_share"], walked / rows)
+
+
+# ---------------------------------------------------------------------------
+# the chip compiler's word (compile only: a described v5e, no chip)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("kernel", ["gather", "gather_scaled_dotted", "add",
+                                    "add_two", "act"])
+def test_v5e_compiles_the_row_kernels_at_the_cell_s_shapes(one_chip, kernel):
+    """``trinity-mini.train-1chip``: 131,072 rows of 2048 (1024 between the
+    products) for 16,384 tokens, 16 groups, bfloat16."""
+    rows, tokens, h, f, groups = 131072, 16384, 2048, 1024, 16
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    buf, tok, sizes = S((rows, h), bf), S((rows,), i32), S((groups,), i32)
+    fn, shapes, names = {
+        "gather": (lambda x, t, n: moe_rows.gather_rows(
+            x, t, n, out_dtype=bf), (S((tokens, h), bf), tok, S((), i32)),
+            (moe_rows.BY_ROW, moe_rows.GATHER)),
+        "gather_scaled_dotted": (lambda x, t, n, w, o: moe_rows.gather_rows(
+            x, t, n, out_dtype=bf, scale=w, dot_with=o),
+            (S((tokens, h), bf), tok, S((), i32), S((rows,), f32), buf),
+            (moe_rows.BY_ROW, moe_rows.GATHER)),
+        "add": (lambda a, t, s, w: moe_rows.add_rows(
+            (a,), t, s, tokens, scale=w), (buf, tok, sizes, S((rows,), f32)),
+            (moe_rows.ADD,)),
+        "add_two": (lambda a, b, t, s: moe_rows.add_rows(
+            (a, b), t, s, tokens), (buf, buf, tok, sizes), (moe_rows.ADD,)),
+        "act": (lambda g, u, n: jax.value_and_grad(
+            lambda g, u: jnp.sum(moe_rows.gated_act(g, u, n, False)),
+            argnums=(0, 1))(g, u),
+            (S((rows, f), bf), S((rows, f), bf), S((), i32)),
+            (moe_rows.ACT_FWD, moe_rows.ACT_BWD)),
+    }[kernel]
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(fn).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    text = compiled.as_text()
+    for name in names:
+        assert name in text and "tpu_custom_call" in text, name
+    # no temporary the size of a [rows, hidden] buffer (512 MiB): the token
+    # side's float32 rows ([16384, 1, 2048]: 128 MiB) and a weight a row
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * rows * h
 
 
 def test_the_new_scopes_stand_in_the_compiled_step_s_text(tiny):
