@@ -16,7 +16,7 @@ Entry points:
   (the ROADMAP sharded-packed precondition);
 - :func:`comm_volume` — static per-program
   ``{collective: {count, bytes, axes}}`` report (the serving psum pins
-  and compare_bench comm gates are stated in it);
+  are stated in it);
 - :func:`check_shard_specs` — standalone PartitionSpec-vs-mesh
   verification (the mesh-rebase pre-trace gate);
 - :func:`kernel_inventory` — every ``pallas_call`` a program traces

@@ -144,8 +144,8 @@ def comm_volume(fn, *args) -> Dict[str, Dict]:
     ``{collective: {"count": int, "bytes": int, "axes": [str, ...]}}``
     over every collective primitive in the program. Equations inside
     scan/while bodies are counted once — this is the *program's* shape,
-    the quantity the serving psum pins and compare_bench gates are
-    stated in, not a per-iteration runtime volume.
+    the quantity the serving psum pins are stated in, not a
+    per-iteration runtime volume.
     """
     import jax
 

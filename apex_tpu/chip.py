@@ -3,7 +3,7 @@ it ran on, that it refuses to run anywhere else, and where compiled code
 is kept.
 
 A CPU run says what a program counts and nothing about time, so a
-measurement path (``chip_smoke.py``, ``bench.py``) that finds no TPU
+measurement path (``chip_smoke.py``, ``benchmark/run.py``) that finds no TPU
 stops before it compiles anything — there is no switch that lets it
 pass off-chip. Library code keeps its CPU/interpret paths (tier-1 needs
 them); this module is for entry points.

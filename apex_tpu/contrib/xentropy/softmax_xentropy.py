@@ -59,27 +59,6 @@ class SoftmaxCrossEntropyLoss:
         )
 
 
-def _maybe_scan(body, carry, xs, unroll):
-    """``lax.scan`` or a Python-unrolled equivalent (stacked ys).
-
-    Unrolling replaces the scan while-loop's dynamic-slice xs reads and
-    dynamic-update-slice ys writes with plain slices/concatenates — the
-    candidate fix for the GPT bench's ``bitcast_dynamic-update-slice``
-    data-movement bucket (see ``docs/dus_bucket.md``). Numerics are
-    identical; only the loop lowering changes.
-    """
-    if not unroll:
-        return jax.lax.scan(body, carry, xs)
-    nc = jax.tree_util.tree_leaves(xs)[0].shape[0]
-    ys = []
-    for i in range(nc):
-        xi = jax.tree_util.tree_map(lambda a: a[i], xs)
-        carry, y = body(carry, xi)
-        ys.append(y)
-    stacked = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *ys)
-    return carry, stacked
-
-
 @jax.named_scope("apex_tpu.cross_entropy")
 def lm_head_cross_entropy(
     hidden: jax.Array,  # [N, h] pre-head activations (any float dtype)
@@ -88,7 +67,6 @@ def lm_head_cross_entropy(
     *,
     chunk_size: int = 2048,
     save_logits_dtype=None,
-    unroll: bool = False,
 ) -> jax.Array:
     """Chunk-fused LM-head GEMM + cross entropy: per-row losses WITHOUT
     materialising the full ``[N, V]`` logits tensor.
@@ -116,14 +94,6 @@ def lm_head_cross_entropy(
     one fewer GEMM pass + one fewer reduce pass per chunk; measured ~5
     ms/step on the GPT-2 345M v5e bench. Logit precision: bf16 keeps
     |logit| <= ~40 to ~0.3% relative, well inside half-softmax parity.
-
-    ``unroll=True`` unrolls the chunk loop (Python loop + concatenate
-    instead of a scan's dynamic-update-slice stacking). For THIS remat
-    variant it was measured ~6 ms/step slower on v5e (several fp32
-    ``[chunk, V]`` logit blocks go live concurrently); for the
-    saved-logits variant the ``[N, V]`` buffer is materialised either
-    way, so unrolling costs no extra memory and is the A/B knob for the
-    scan-lowering data-movement bucket (``docs/dus_bucket.md``).
     """
     n, h = hidden.shape
     if n % chunk_size:
@@ -131,7 +101,7 @@ def lm_head_cross_entropy(
     if save_logits_dtype is not None:
         return _lm_head_ce_saved(
             hidden, head_weight, labels, chunk_size,
-            jnp.dtype(save_logits_dtype), unroll,
+            jnp.dtype(save_logits_dtype),
         )
     hc = hidden.reshape(n // chunk_size, chunk_size, h)
     lc = labels.reshape(n // chunk_size, chunk_size)
@@ -150,25 +120,22 @@ def lm_head_cross_entropy(
     def body(carry, xs):
         return carry, chunk_loss(head_weight, xs)
 
-    # NB: measured on v5e (345M bench): unroll=True here is ~6 ms/step
-    # SLOWER — unrolling lets several [chunk, V] fp32 logit blocks go live
-    # concurrently and the memory pressure costs more than the rolled
-    # scan's slice overhead. Keep the rolled scan by default.
-    _, losses = _maybe_scan(body, None, (hc, lc), unroll)
+    # a rolled scan: unrolled, several [chunk, V] fp32 logit blocks go live
+    # at once, which read ~6 ms/step slower on the v5e (345M, round 5)
+    _, losses = jax.lax.scan(body, None, (hc, lc))
     return losses.reshape(n)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _lm_head_ce_saved(hidden, head_weight, labels, chunk_size, logits_dtype,
-                      unroll=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _lm_head_ce_saved(hidden, head_weight, labels, chunk_size, logits_dtype):
     losses, _ = _lm_head_ce_saved_fwd(
-        hidden, head_weight, labels, chunk_size, logits_dtype, unroll
+        hidden, head_weight, labels, chunk_size, logits_dtype
     )
     return losses
 
 
 def _lm_head_ce_saved_fwd(hidden, head_weight, labels, chunk_size,
-                          logits_dtype, unroll=False):
+                          logits_dtype):
     n, h = hidden.shape
     nc = n // chunk_size
     hc = hidden.reshape(nc, chunk_size, h)
@@ -191,11 +158,11 @@ def _lm_head_ce_saved_fwd(hidden, head_weight, labels, chunk_size,
         gold = jnp.take_along_axis(lf, lrow[:, None], axis=-1)[:, 0]
         return carry, (lse - gold, logits, lse)
 
-    _, (losses, saved_logits, lse) = _maybe_scan(body, None, (hc, lc), unroll)
+    _, (losses, saved_logits, lse) = jax.lax.scan(body, None, (hc, lc))
     return losses.reshape(n), (hidden, head_weight, labels, saved_logits, lse)
 
 
-def _lm_head_ce_saved_bwd(chunk_size, logits_dtype, unroll, res, g):
+def _lm_head_ce_saved_bwd(chunk_size, logits_dtype, res, g):
     hidden, head_weight, labels, saved_logits, lse = res
     n, h = hidden.shape
     nc = n // chunk_size
@@ -226,7 +193,7 @@ def _lm_head_ce_saved_bwd(chunk_size, logits_dtype, unroll, res, g):
         return dw_acc, dh.astype(hidden.dtype)
 
     dw0 = jnp.zeros(head_weight.shape, jnp.float32)
-    dw, dhc = _maybe_scan(body, dw0, (hc, lc, gc, saved_logits, lse), unroll)
+    dw, dhc = jax.lax.scan(body, dw0, (hc, lc, gc, saved_logits, lse))
     return (
         dhc.reshape(n, h).astype(hidden.dtype),
         dw.astype(head_weight.dtype),

@@ -21,8 +21,8 @@ response half — the run must *survive* what they detect:
   blocking points with all-thread stack dumps instead of silent pod
   deadlocks;
 - :mod:`~apex_tpu.resilience.retry` — the jittered-backoff
-  :class:`RetryPolicy` (promoted from bench.py) used by checkpoint IO
-  and the bench legs;
+  :class:`RetryPolicy` used by checkpoint IO and the serving
+  transport;
 - :mod:`~apex_tpu.resilience.chaos` — fault injection (NaN gradients,
   failed/truncated checkpoint writes, fake preemption, stalled
   callbacks, SIGKILLed fake hosts) driving the tests and

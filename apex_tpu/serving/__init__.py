@@ -49,8 +49,8 @@ training stack's own machinery:
   zero-loss migration under :class:`FleetSupervisor`.
 
 ``tools/serving_check.py --self`` is the CI smoke; ``docs/serving.md``
-the design document; ``bench.py``'s ``serving_throughput`` /
-``prefill_decode_split`` / ``serving_overload`` legs the measurements.
+the design document. No benchmark cell serves yet: nothing here is
+measured on the chip (``PERF.md`` §7 row 1).
 """
 from .engine import (  # noqa: F401
     NO_TOKEN,

@@ -1722,7 +1722,7 @@ class ServingEngine:
             "kv_bytes_per_shard": self.spec_local.cache_bytes(),
             "psum_per_program": self.program_psum_counts(),
             # full static comm report ({program: {collective: {count,
-            # bytes, axes}}}) — what compare_bench's comm gates read
+            # bytes, axes}}}) — what the comm-volume tests pin
             "comm_volume": self.program_comm_volume(),
             # latency attribution (telemetry.spans): per-term TTFT/e2e
             # percentiles, the sum-vs-measured identity's max relative

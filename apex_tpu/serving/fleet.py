@@ -60,11 +60,9 @@ TTFT, **requests_lost** — the zero-loss failover contract) plus a
 per-replica breakdown.
 
 CPU-faked replicas (in-process engines) keep all of it tier-1
-testable: ``tests/test_serving_fleet.py``, the ``fleet_kill_migrate``
-/ ``fleet_drain_join`` legs of ``tools/serving_check.py --self``, and
-bench.py's ``serving_fleet`` leg (Zipfian trace at ~0.8x fleet
-capacity, one of three replicas killed mid-run, requests-lost must
-be 0).
+testable: ``tests/test_serving_fleet.py`` and the ``fleet_kill_migrate``
+/ ``fleet_drain_join`` legs of ``tools/serving_check.py --self`` (one
+replica killed mid-run, requests-lost must be 0).
 """
 from __future__ import annotations
 

@@ -10,8 +10,8 @@ XLA fuses into the step, and the only host interaction is
 :func:`drain` — an **async** ``jax.debug.callback`` under ``lax.cond``
 that fires every ``every_n`` steps and never blocks the device.
 Instrumentation therefore adds ZERO extra host syncs to the hot path
-(the ``telemetry_overhead`` leg in ``bench.py`` pins instrumented vs
-bare step time).
+(``analysis.audit_step``'s ``ungated_callback`` rule holds a step to it;
+what it costs in step time is not measured on the chip).
 
 Usage::
 

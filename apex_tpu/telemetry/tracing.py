@@ -24,7 +24,6 @@ the TPU analogue is a ``jax.profiler`` xplane trace. This module owns
   TPU.
 
 The xplane is read with ``jax.profiler.ProfileData`` (ships with jax).
-``tools/op_breakdown.py`` re-exports all of this for script use.
 """
 from __future__ import annotations
 

@@ -138,11 +138,6 @@ class GPTConfig:
     # logit at bf16 (see contrib.xentropy.lm_head_cross_entropy's
     # save_logits_dtype docstring, where the behavior is parity-tested).
     ce_save_logits: bool = False
-    # Unroll the chunked-CE loop: with ce_save_logits the [b*s, vocab]
-    # buffer is materialised either way, so unrolling trades the scan's
-    # dynamic-update-slice stacking (the bench's bitcast_DUS data-movement
-    # bucket, docs/dus_bucket.md) for concatenates at no memory cost.
-    ce_unroll: bool = False
     # fp8 (e4m3 fwd + e5m2 grads, TE-style delayed scaling) on the four
     # projection GEMMs per layer (qkv / proj / fc1 / fc2). Thread
     # ``init_gpt_fp8_states(cfg)`` through ``gpt_loss(...,
@@ -150,8 +145,6 @@ class GPTConfig:
     # ``fp8_amax_reduction_axes`` (the reference amax-reduction group
     # over (data, tensor), ``apex/transformer/parallel_state.py:280``).
     fp8: bool = False
-    fp8_margin: float = 0.0
-    fp8_amax_history_len: int = 16
     fp8_amax_reduction_axes: Optional[Tuple[str, ...]] = None
     # BERT extras
     add_binary_head: bool = False
@@ -411,6 +404,8 @@ def _dropout(x, rate, key, deterministic):
 
 
 FP8_GEMM_NAMES = ("qkv", "proj", "fc1", "fc2")
+#: Steps of amax history the delayed scaling keeps for each GEMM.
+FP8_AMAX_HISTORY_LEN = 16
 
 
 def init_gpt_fp8_states(cfg: GPTConfig):
@@ -421,7 +416,7 @@ def init_gpt_fp8_states(cfg: GPTConfig):
     ``fp8_carriers`` cotangent (fold with :func:`record_gpt_grad_amaxes`)."""
     from apex_tpu.fused_dense import init_fp8_dense_state
 
-    one = init_fp8_dense_state(cfg.fp8_amax_history_len, with_grad_meta=True)
+    one = init_fp8_dense_state(FP8_AMAX_HISTORY_LEN, with_grad_meta=True)
     stack = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x, (cfg.num_layers,) + x.shape).copy(),
         one,
@@ -449,9 +444,7 @@ def record_gpt_grad_amaxes(cfg: GPTConfig, fp8_states, carrier_grads):
         amax = carrier_grads[name]
         if cfg.fp8_amax_reduction_axes is not None:
             amax = jax.lax.pmax(amax, cfg.fp8_amax_reduction_axes)
-        out[name] = jax.vmap(
-            lambda s, a: record_grad_amax(s, a, margin=cfg.fp8_margin)
-        )(fp8_states[name], amax)
+        out[name] = jax.vmap(record_grad_amax)(fp8_states[name], amax)
     return out
 
 
@@ -462,7 +455,7 @@ def _fp8_dense(cfg, fp8, name, x, w, b):
 
     state, carrier = fp8[name]
     y, new_state = fp8_fused_dense_qgrad(
-        x, w, None, state, carrier, margin=cfg.fp8_margin,
+        x, w, None, state, carrier,
         amax_reduction_axes=cfg.fp8_amax_reduction_axes,
     )
     y = y.astype(x.dtype)
@@ -505,7 +498,6 @@ def parallel_attention(
             sequence_parallel_enabled=cfg.sequence_parallel,
             fp8_state=st, fp8_grad_carrier=car,
             fp8_amax_reduction_axes=cfg.fp8_amax_reduction_axes,
-            fp8_margin=cfg.fp8_margin,
         )
     elif fp8 is not None:
         qkv, new_fp8["qkv"] = _fp8_dense(
@@ -736,7 +728,6 @@ def _attn_out_proj(cfg, lp, ctx, axis_name, fp8=None, new_fp8=None,
             sequence_parallel_enabled=cfg.sequence_parallel,
             fp8_state=st, fp8_grad_carrier=car,
             fp8_amax_reduction_axes=cfg.fp8_amax_reduction_axes,
-            fp8_margin=cfg.fp8_margin,
         )
         return out, new_fp8
     if fp8 is not None:
@@ -798,7 +789,6 @@ def parallel_mlp(
             sequence_parallel_enabled=cfg.sequence_parallel,
             fp8_state=st1, fp8_grad_carrier=car1,
             fp8_amax_reduction_axes=cfg.fp8_amax_reduction_axes,
-            fp8_margin=cfg.fp8_margin,
         )
         inter = act(inter)
         st2, car2 = fp8["fc2"]
@@ -810,7 +800,6 @@ def parallel_mlp(
             sequence_parallel_enabled=cfg.sequence_parallel,
             fp8_state=st2, fp8_grad_carrier=car2,
             fp8_amax_reduction_axes=cfg.fp8_amax_reduction_axes,
-            fp8_margin=cfg.fp8_margin,
         )
         return out, new_fp8
     if fp8 is not None:
@@ -1529,7 +1518,6 @@ def gpt_loss(
                 save_logits_dtype=(
                     cfg.compute_dtype if cfg.ce_save_logits else None
                 ),
-                unroll=cfg.ce_unroll,
             ).reshape(s, b)
             losses = jnp.transpose(losses, (1, 0))  # [b, s]
     with jax.named_scope("apex_tpu.cross_entropy"):

@@ -10,10 +10,10 @@ vocab 50304; random weights from a seed):
 
 - **trainer** — ``amp.initialize(O2)`` -> ``amp.scaled_value_and_grad`` ->
   ``FusedAdam(packed=True).step(found_inf=)`` -> ``scaler.update_scale`` on
-  the step ``bench.py`` times (flash attention, fused block tails with
-  ``selective_elementwise`` recompute, chunk-fused LM-head CE), batch 8 x
-  seq 1024: the loss falls over a few chained steps, and a step with an
-  injected overflow leaves params untouched and halves the loss scale;
+  the step of ``gpt2-345m.train-1chip`` (flash attention, fused block
+  tails with ``selective_elementwise`` recompute, chunk-fused LM-head CE),
+  batch 8 x seq 1024: the loss falls over a few chained steps, and a step
+  with an injected overflow leaves params untouched and halves the scale;
 - **flat scaler** — ``LossScaler.unscale_flat`` / ``found_inf_flat`` on a
   345M-element flat gradient buffer, clean and with an inf planted, against
   their ``use_kernel=False`` path (the bucketed lifecycle's sweeps, which
@@ -108,7 +108,7 @@ class Size:
             compute_dtype=jnp.bfloat16, **kw)
 
     def train_config(self):
-        """The step ``bench.py`` times."""
+        """The step of ``gpt2-345m.train-1chip``."""
         return self.gpt_config(
             recompute_granularity="selective_elementwise",
             layer_unroll=-1, fused_block=True,
@@ -266,7 +266,7 @@ def _peak_bytes() -> Optional[int]:
 # ---------------------------------------------------------------------------
 def train_program(size: Size):
     """``(jitted step, (params, opt_state, sstate))`` — the amp O2 flow
-    around the step ``bench.py`` times. The step's last argument scales
+    around ``train_config``'s step. The step's last argument scales
     the loss: 1.0, or inf for the injected-overflow step, so one
     compiled program serves both."""
     from apex_tpu import amp

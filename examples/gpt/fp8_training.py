@@ -83,7 +83,7 @@ def main():
 
     # donate params + optimizer state (masters/moments updated in place);
     # the fp8 state tree stays undonated — donating its small nested
-    # buffers trips a TPU backend INVALID_ARGUMENT (see bench.py), and
+    # buffers trips a TPU backend INVALID_ARGUMENT (seen on the v5e), and
     # at KB size copying it is free
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, fp8_states):

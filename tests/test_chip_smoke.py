@@ -1,12 +1,11 @@
 """``chip_smoke.py`` and the rules measurement entry points share.
 
-- off-chip the smoke (and ``bench.py``) exit non-zero before compiling
-  anything, naming the backend; alone in a directory the smoke fails too;
+- off-chip the smoke exits non-zero before compiling anything, naming
+  the backend; alone in a directory it fails too;
 - every leg passes at toy width on the CPU mesh with interpreted kernels;
 - the kernel-presence assertion goes red when a gate turns a kernel off,
   and the dense-reference check goes red on a wrong token;
-- the compile-cache rule (``apex_tpu.chip.use_compile_cache``);
-- ``bench.peaks_for`` raises on an unknown device.
+- the compile-cache rule (``apex_tpu.chip.use_compile_cache``).
 """
 import os
 import shutil
@@ -59,28 +58,12 @@ def test_smoke_alone_in_a_directory_fails(tmp_path):
     assert '"ok"' not in proc.stdout
 
 
-def test_bench_off_chip_exits_nonzero_before_compiling():
-    proc = _run_off_chip(REPO / "bench.py", REPO)
-    assert proc.returncode != 0
-    assert "bench.py: needs a TPU" in proc.stderr
-    assert proc.stdout.strip() == ""
-
-
 def test_require_tpu_and_device_record():
     with pytest.raises(SystemExit, match="needs a TPU"):
         chip.require_tpu("a test")
     assert chip.device_record() == {
         "platform": "cpu", "kind": jax.devices()[0].device_kind,
         "count": len(jax.devices())}
-
-
-def test_peaks_raise_on_an_unknown_device():
-    import bench
-
-    assert bench.peaks_for("TPU v5 lite") == (197.0, 819.0)
-    for kind in ("cpu", "TPU v9 imaginary"):
-        with pytest.raises(ValueError, match="no peak"):
-            bench.peaks_for(kind)
 
 
 # -- the compile-cache rule --------------------------------------------------

@@ -279,7 +279,7 @@ def test_zero2_step_volume():
 
 
 # ---------------------------------------------------------------------------
-# HLO-parse helpers shared with tools/op_breakdown.py
+# HLO-parse helpers behind the volume accounting
 # ---------------------------------------------------------------------------
 
 def test_shape_bytes_parser():

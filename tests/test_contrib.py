@@ -369,10 +369,12 @@ def test_lm_head_cross_entropy_matches_unfused():
         lm_head_cross_entropy(hid, w, labels, chunk_size=24)
 
 
-@pytest.mark.parametrize("save_dtype", [None, jnp.bfloat16])
-def test_lm_head_cross_entropy_unroll_parity(save_dtype):
-    """unroll=True (concatenate lowering, the docs/dus_bucket.md A/B
-    knob) is numerically identical to the rolled scan, fwd and bwd."""
+@pytest.mark.parametrize("save_dtype,tol", [(jnp.float32, 1e-6),
+                                            (jnp.bfloat16, 2e-2)])
+def test_lm_head_cross_entropy_saved_logits_matches_remat(save_dtype, tol):
+    """``save_logits_dtype`` (backward from the saved chunk logits, no
+    GEMM replay) against the rematerialising path, loss and gradients:
+    equal at float32, within the logits' rounding at bfloat16."""
     from apex_tpu.contrib.xentropy import lm_head_cross_entropy
 
     n, h, v = 64, 16, 96
@@ -380,19 +382,18 @@ def test_lm_head_cross_entropy_unroll_parity(save_dtype):
     w = jax.random.normal(jax.random.PRNGKey(1), (v, h)) * 0.3
     labels = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, v)
 
-    def loss(hid, w, unroll):
+    def loss(hid, w, save):
         return jnp.mean(lm_head_cross_entropy(
-            hid, w, labels, chunk_size=16, save_logits_dtype=save_dtype,
-            unroll=unroll))
+            hid, w, labels, chunk_size=16, save_logits_dtype=save))
 
     l0, g0 = jax.value_and_grad(
-        lambda a, b: loss(a, b, False), argnums=(0, 1))(hid, w)
+        lambda a, b: loss(a, b, None), argnums=(0, 1))(hid, w)
     l1, g1 = jax.value_and_grad(
-        lambda a, b: loss(a, b, True), argnums=(0, 1))(hid, w)
-    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
+        lambda a, b: loss(a, b, save_dtype), argnums=(0, 1))(hid, w)
+    np.testing.assert_allclose(float(l1), float(l0), rtol=tol)
     for a, b in zip(g1, g0):
         np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+            np.asarray(a), np.asarray(b), rtol=tol, atol=tol * 0.1)
 
 
 # ---------------------------------------------------------------------------

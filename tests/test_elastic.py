@@ -9,7 +9,7 @@ FusedAdam + GradBuckets state across world sizes) — plus the
 satellites: fsync durability of the base manager's rename commit,
 multi-writer-safe stale-tmp sweeping (seeded-violation red tests),
 restore fallback over a partially-committed multi-host step, the
-attributable :class:`HangWatchdog` context, and the bench/CLI wiring.
+attributable :class:`HangWatchdog` context, and the CLI wiring.
 
 The full chaos trace (kills mid-part-write and mid-barrier, a
 heartbeat wedge, a topology reshape — final loss records byte-exact)
@@ -595,7 +595,7 @@ class TestWatchdogContext:
 
 
 # ---------------------------------------------------------------------------
-# CLI + bench wiring
+# CLI wiring
 # ---------------------------------------------------------------------------
 class TestSupervisorCLI:
     def test_parse_chaos_and_reshape(self):
@@ -615,43 +615,7 @@ class TestSupervisorCLI:
         assert os.path.exists(es.HOST_PROGRAM)
 
 
-class TestBenchWiring:
-    def test_compare_bench_extracts_elastic_legs(self):
-        from tools import compare_bench
-
-        names = [m[0] for m in compare_bench.METRICS]
-        assert "elastic_mttr_s" in names
-        assert "elastic_save_overhead_pct" in names
-        assert "elastic_mttr_s" in compare_bench.ABS_TOLERANCE
-        legs = compare_bench.extract_legs(
-            {"elastic_mttr": {"mttr_s": 3.2,
-                              "save_overhead_pct": 12.5}})
-        assert legs["elastic_mttr_s"] == -3.2  # lower-is-better
-        assert legs["elastic_save_overhead_pct"] == -12.5
-
-    def test_mttr_regression_gated_absolutely(self):
-        from tools import compare_bench
-
-        base = {"elastic_mttr": {"mttr_s": 3.0}}
-        ok = {"elastic_mttr": {"mttr_s": 6.0}}  # within 5s abs tol
-        cmp = compare_bench.compare(base, ok, threshold=0.05)
-        assert not [r for r in cmp["regressions"]
-                    if r["leg"] == "elastic_mttr_s"]
-        bad = {"elastic_mttr": {"mttr_s": 20.0}}
-        cmp = compare_bench.compare(base, bad, threshold=0.05)
-        assert [r for r in cmp["regressions"]
-                if r["leg"] == "elastic_mttr_s"]
-
-    def test_cpu_smoke_artifact_committed(self):
-        path = REPO / "bench_artifacts" / "elastic_mttr_cpu_smoke.json"
-        with open(path) as f:
-            smoke = json.load(f)
-        leg = smoke["elastic_mttr"]
-        assert leg["records_match"] is True
-        assert leg["restarts"] >= 1
-        assert leg["mttr_s"] > 0
-        assert "save_overhead_pct" in leg
-
+class TestCheckWiring:
     def test_resilience_check_gained_elastic_legs(self):
         from tools import resilience_check
 

@@ -3,6 +3,9 @@
 Mirrors reference L0 suites: ``test_mlp.py`` (MLP vs nn.Sequential),
 fused_dense test, ``run_fp16util``, ``test_rnn.py``.
 """
+import pathlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -338,7 +341,6 @@ def test_megatron_arguments_reference_l0_lines_and_deprecations():
 def test_megatron_arguments_cover_reference_flag_set():
     """Every --flag the reference's arguments.py registers is accepted
     here (mechanical diff, so the surface cannot silently regress)."""
-    import re
 
     from apex_tpu.transformer.testing import arguments as A
 
@@ -353,3 +355,52 @@ def test_megatron_arguments_cover_reference_flag_set():
     our_flags = set(re.findall(r"add_argument\(\s*['\"](--[\w-]+)", our_src))
     missing = sorted(ref_flags - our_flags)
     assert not missing, missing
+
+
+# ---------------------------------------------------------------------------
+# the documents name files that exist
+# ---------------------------------------------------------------------------
+_REPO = pathlib.Path(__file__).resolve().parent.parent
+_DOCUMENTS = ["README.md", ".claude/skills/verify/SKILL.md",
+              *sorted(f"docs/{p.name}" for p in (_REPO / "docs").glob("*.md"))]
+#: Directories of the checkout a document may name a path under.
+_ROOTS = ("tools", "apex_tpu", "docs", "benchmark", "tests", "examples",
+          "bench_artifacts")
+
+
+def _named_paths(text: str):
+    """Back-ticked tokens that look like a path of this repo: under one
+    of ``_ROOTS`` or under a package directory of ``apex_tpu/`` (written
+    ``serving/engine.py``), or a bare ``name.py`` / ``NAME.json``. A
+    ``::test`` or ``:line`` suffix is dropped; placeholders and globs
+    (``<family>``, ``*``, ``{a,b}``, ``…``) are no names."""
+    packages = {p.name for p in (_REPO / "apex_tpu").iterdir() if p.is_dir()}
+    for token in re.findall(r"`([^`\s]+)`", text):
+        token = re.sub(r"(::.*|:\d+(-\d+)?)$", "", token).rstrip(".,;)")
+        if re.search(r"[<>*{}\[\]…$]", token) or token.startswith(("/", "-")):
+            continue
+        head, _, rest = token.partition("/")
+        if rest and head in _ROOTS:
+            # ``benchmark/scope_reduce.inside``: a name inside a module
+            yield token, [_REPO / token,
+                          _REPO / re.sub(r"\.\w+$", ".py", token)]
+        elif rest and head in packages and re.search(
+                r"\.(py|cpp|json|txt)$|/$", rest):
+            yield token, [_REPO / "apex_tpu" / token, _REPO / token]
+        elif not rest and re.fullmatch(r"\w+\.py|[A-Z][A-Z_0-9a-z]*\.jsonl?",
+                                       token):
+            yield token, [_REPO / token, *_REPO.glob(f"*/**/{token}")]
+
+
+@pytest.mark.parametrize("document", _DOCUMENTS)
+def test_a_document_names_files_that_exist(document):
+    """Every path a document names resolves in the checkout, and none
+    names a switch of the benchmark script that is gone (``BENCH_*``): the
+    guard a deletion needs, so that a sentence does not outlive its
+    subject."""
+    text = (_REPO / document).read_text()
+    missing = sorted({token for token, places in _named_paths(text)
+                      if not any(p.exists() for p in places)})
+    assert not missing, f"{document} names what is not there: {missing}"
+    switches = sorted(set(re.findall(r"\bBENCH_[A-Z][A-Z_0-9]*", text)))
+    assert not switches, f"{document} names benchmark switches: {switches}"

@@ -13,9 +13,12 @@ against the ``afmoe`` family's plain reference.
 - combinations the new fields do not support raise at construction;
 - the new scopes stand in the compiled step's text.
 """
+import ast
 import dataclasses
 import functools
 import os
+import pathlib
+import re
 import sys
 
 import jax
@@ -497,21 +500,21 @@ def test_a_shape_that_does_not_hold_together_raises(change, reason):
         GPTConfig(**{**BY_KIND, **change})
 
 
+EVERY_FIELD = {**BY_KIND, "layer_kinds": (LayerKind(8, True, False),
+                                          LayerKind(None, False, False)),
+               "norm": "layernorm", "gated_mlp": False, "linear_bias": True,
+               "learned_positions": True, "num_experts": 0,
+               "experts_held": None, "experts_per_token": 0,
+               "expert_ffn_size": 0, "sandwich_norm": True, "qk_norm": True,
+               "attention_gate": True, "embedding_scale": 2.0}
+
+
 def test_every_new_field_has_a_path_of_its_own():
     """Bias, LayerNorm, fc1-GeLU-fc2, learned positions and a tied head on
-    the ``layer_kinds`` path: the fields are independent of one another."""
-    cfg = GPTConfig(**{**BY_KIND, "layer_kinds": (LayerKind(8, True, False),
-                                                  LayerKind(None, False, False)),
-                       "norm": "layernorm", "gated_mlp": False,
-                       "linear_bias": True, "learned_positions": True,
-                       "num_experts": 0, "experts_held": None,
-                       "experts_per_token": 0, "expert_ffn_size": 0,
-                       "sandwich_norm": True, "qk_norm": True,
-                       "attention_gate": True, "embedding_scale": 2.0})
+    the ``layer_kinds`` path, all at once: the fields are independent of one
+    another (each one's own parameter: ``OWN_PARAMETER`` below)."""
+    cfg = GPTConfig(**EVERY_FIELD)
     params = init_gpt_params(cfg, jax.random.PRNGKey(0))
-    assert "position" in params["embedding"] and "lm_head" not in params
-    assert {"q_b", "fc1_w", "fc2_b", "input_ln_b", "attn_gate_w",
-            "q_norm_w", "post_mlp_ln_w"} <= set(params["layers"][0])
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
     loss, grads = jax.value_and_grad(
         lambda p: gpt_loss(cfg, p, tokens, jnp.roll(tokens, -1, 1)))(params)
@@ -523,3 +526,109 @@ def test_every_new_field_has_a_path_of_its_own():
     expert = init_gpt_params(GPTConfig(**BY_KIND), jax.random.PRNGKey(0))
     assert expert["layers"][1]["experts_gate_w"].shape == (2, 32, 16)
     assert expert["layers"][1]["router_w"].shape == (4, 32)
+
+
+# ---------------------------------------------------------------------------
+# no option without a caller
+# ---------------------------------------------------------------------------
+REPO = pathlib.Path(__file__).resolve().parent.parent
+
+#: Fields that no caller outside ``tests/`` sets away from their default,
+#: each with what keeps it: ROADMAP D5's list in executable form. A field
+#: leaves this list when a cell, an example or a tool sets it, or when it
+#: goes.
+TESTS_ONLY = {
+    "ce_save_logits": "test_standalone_models holds its parity; ROADMAP "
+                      "Q1.6 owes it an A/B on the chip",
+    "add_binary_head": "BERT's next-sentence head (test_standalone_models); "
+                       "the bert cell does not build it (PERF.md §4)",
+}
+
+#: Under ``EVERY_FIELD``, the parameter that shows a field took a path of
+#: its own: where it stands in the tree, and whether it is there.
+OWN_PARAMETER = {
+    "linear_bias": (("layers", 0, "q_b"), True),
+    "gated_mlp": (("layers", 0, "fc1_w"), True),
+    "norm": (("layers", 0, "input_ln_b"), True),
+    "attention_gate": (("layers", 0, "attn_gate_w"), True),
+    "qk_norm": (("layers", 0, "q_norm_w"), True),
+    "sandwich_norm": (("layers", 0, "post_mlp_ln_w"), True),
+    "learned_positions": (("embedding", "position"), True),
+    "untied_head": (("lm_head",), False),
+}
+
+#: What builds a ``GPTConfig`` or a ``LayerKind`` outside ``tests/``.
+_BUILDERS = {"GPTConfig", "LayerKind", "program_config", "gpt_config",
+             "replace"}
+_FIELDS = ([(GPTConfig, f.name, f.default)
+            for f in dataclasses.fields(GPTConfig)]
+           + [(LayerKind, n, LayerKind._field_defaults[n])
+              for n in LayerKind._fields])
+
+
+@functools.lru_cache(maxsize=None)
+def _set_by_callers():
+    """``{field: {file, ...}}``: keyword arguments that a builder call in
+    ``benchmark/families``, ``chip_smoke.py``, ``__graft_entry__.py``,
+    ``examples/``, ``tools/`` or ``apex_tpu/`` gives a value that is not
+    (as far as a literal shows) the default."""
+    defaults = {name: default for _, name, default in _FIELDS}
+    files = [*REPO.glob("benchmark/families/*.py"), REPO / "chip_smoke.py",
+             REPO / "__graft_entry__.py", *REPO.glob("examples/**/*.py"),
+             *REPO.glob("tools/*.py"), *REPO.glob("apex_tpu/**/*.py")]
+    found = {}
+    for path in files:
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(node, ast.Call):
+                continue
+            name = getattr(node.func, "attr", getattr(node.func, "id", None))
+            if name not in _BUILDERS:
+                continue
+            for kw in node.keywords:
+                if kw.arg not in defaults:
+                    continue
+                try:
+                    if ast.literal_eval(kw.value) == defaults[kw.arg]:
+                        continue
+                except ValueError:
+                    pass                        # an expression: a choice
+                found.setdefault(kw.arg, set()).add(
+                    str(path.relative_to(REPO)))
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _package_text():
+    return "\n".join(p.read_text() for p in REPO.glob("apex_tpu/**/*.py"))
+
+
+@functools.lru_cache(maxsize=None)
+def _every_field_params():
+    return init_gpt_params(GPTConfig(**EVERY_FIELD), jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("owner, field", [
+    pytest.param(owner, name, id=f"{owner.__name__}.{name}")
+    for owner, name, _ in _FIELDS])
+def test_no_option_without_a_caller(owner, field):
+    """Every field of the model's configuration is read by the package
+    (an attribute access: its declaration is none) and is set away from its
+    default by a caller outside ``tests/``, or stands in ``TESTS_ONLY`` with
+    its reason. Where ``EVERY_FIELD`` turns a field, its own parameter is
+    there."""
+    assert re.search(r"\.%s\b" % field, _package_text()), (
+        f"{owner.__name__}.{field} is declared and never read")
+    callers = _set_by_callers().get(field, set())
+    if owner is GPTConfig and field in TESTS_ONLY:
+        assert not callers, (
+            f"{field} has a caller now ({sorted(callers)}): take it out of "
+            "TESTS_ONLY")
+    else:
+        assert callers, (
+            f"no caller outside tests/ sets {owner.__name__}.{field}: give "
+            "it one, put it in TESTS_ONLY with the reason, or delete it")
+    if field in OWN_PARAMETER:
+        (*where, key), present = OWN_PARAMETER[field]
+        tree = functools.reduce(lambda t, k: t[k], where,
+                                _every_field_params())
+        assert (key in tree) is present

@@ -1,8 +1,8 @@
-"""Unit tests for the xplane op-breakdown helpers (tools/op_breakdown.py).
+"""Unit tests for the xplane op-breakdown helpers (``apex_tpu.telemetry.tracing``).
 
 The profiling capture itself needs a real TPU; the parsing/classification
-logic is pure and pinned here so a refactor cannot silently misbucket the
-published bench breakdown. The golden xplane fixtures at the bottom build
+logic is pure and pinned here so a refactor cannot silently misbucket a
+step's breakdown. The golden xplane fixtures at the bottom build
 REAL xplane protobufs (with tensorflow's protobuf classes where they are
 installed; the parser itself reads them with ``jax.profiler.ProfileData``)
 and pin the corrected category attribution end-to-end (round-5 VERDICT: generic ``%fusion.N`` ops were all booked as
@@ -16,66 +16,67 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from tools.op_breakdown import _category, _short_op_name  # noqa: E402
 from apex_tpu.telemetry.tracing import (  # noqa: E402
     breakdown_table,
+    categorize_op,
     parse_xspace_op_times,
+    short_op_name,
 )
 
 
 def test_short_op_name_strips_hlo_decoration():
-    assert _short_op_name(
+    assert short_op_name(
         "%convolution_tanh_fusion.3 = bf16[4096,4096]{1,0} fusion(...)"
     ) == "convolution_tanh_fusion"
-    assert _short_op_name("%while.7 = (s32[], f32[8]) while(...)") == "while"
-    assert _short_op_name(
+    assert short_op_name("%while.7 = (s32[], f32[8]) while(...)") == "while"
+    assert short_op_name(
         "%apex_tpu_flash_fwd.65 = (bf16[8,16,1024,64]) custom-call(...)"
     ) == "apex_tpu_flash_fwd"
     # no ' = ' (bare name) and no trailing index both survive
-    assert _short_op_name("%copy-done") == "copy-done"
-    assert _short_op_name("fusion") == "fusion"
+    assert short_op_name("%copy-done") == "copy-done"
+    assert short_op_name("fusion") == "fusion"
 
 
 def test_category_buckets():
-    assert _category("apex_tpu_flash_fwd") == "attention-kernel"
-    assert _category("apex_tpu.flash_attention") == "attention-kernel"
-    assert _category("convolution_add_fusion") == "matmul/conv"
-    assert _category("all-reduce-start") == "collective"
-    assert _category("collective-permute") == "collective"
-    assert _category("bitcast_dynamic-update-slice_fusion") == "data-movement"
-    assert _category("copy") == "data-movement"
-    assert _category("exponential_reduce_fusion") == "reduce"
-    assert _category("select_add_fusion") == "fusion(elementwise)"
-    assert _category("iota") == "other"
+    assert categorize_op("apex_tpu_flash_fwd") == "attention-kernel"
+    assert categorize_op("apex_tpu.flash_attention") == "attention-kernel"
+    assert categorize_op("convolution_add_fusion") == "matmul/conv"
+    assert categorize_op("all-reduce-start") == "collective"
+    assert categorize_op("collective-permute") == "collective"
+    assert categorize_op("bitcast_dynamic-update-slice_fusion") == "data-movement"
+    assert categorize_op("copy") == "data-movement"
+    assert categorize_op("exponential_reduce_fusion") == "reduce"
+    assert categorize_op("select_add_fusion") == "fusion(elementwise)"
+    assert categorize_op("iota") == "other"
 
 
 def test_category_hlo_category_stat_is_authoritative():
     """The profiler's per-op category (from the fused computation's root
     op) overrides the generic name — the round-5 fix."""
-    assert _category("fusion", "convolution fusion") == "matmul/conv"
-    assert _category("fusion", "loop fusion") == "fusion(elementwise)"
-    assert _category("fusion", "output fusion") == "fusion(elementwise)"
-    assert _category("fusion", "all-reduce fusion") == "collective"
-    assert _category("fusion", "reduce fusion") == "reduce"
+    assert categorize_op("fusion", "convolution fusion") == "matmul/conv"
+    assert categorize_op("fusion", "loop fusion") == "fusion(elementwise)"
+    assert categorize_op("fusion", "output fusion") == "fusion(elementwise)"
+    assert categorize_op("fusion", "all-reduce fusion") == "collective"
+    assert categorize_op("fusion", "reduce fusion") == "reduce"
     # a named fusion with a contradicting stat: the stat wins
-    assert _category("select_add_fusion", "convolution fusion") \
+    assert categorize_op("select_add_fusion", "convolution fusion") \
         == "matmul/conv"
 
 
 def test_category_generic_fusion_without_signal_is_unattributed():
     """A bare %fusion.N with no hlo_category and no callee signal must
     NOT be claimed as elementwise — that is the exact round-5 bug."""
-    assert _category("fusion") == "fusion(unattributed)"
-    assert _category("loop_fusion") == "fusion(unattributed)"
-    assert _category("fused_computation") == "fusion(unattributed)"
+    assert categorize_op("fusion") == "fusion(unattributed)"
+    assert categorize_op("loop_fusion") == "fusion(unattributed)"
+    assert categorize_op("fused_computation") == "fusion(unattributed)"
 
 
 def test_category_generic_fusion_salvaged_from_callee():
     raw = ("%fusion.3 = bf16[4,4]{1,0} fusion(%p0, %p1), kind=kOutput, "
            "calls=%convolution_fusion.3")
-    assert _category("fusion", None, raw) == "matmul/conv"
+    assert categorize_op("fusion", None, raw) == "matmul/conv"
     raw2 = "%fusion.9 = f32[8] fusion(%p0), kind=kLoop, calls=%fused_computation.9"
-    assert _category("fusion", None, raw2) == "fusion(unattributed)"
+    assert categorize_op("fusion", None, raw2) == "fusion(unattributed)"
 
 
 # ---------------------------------------------------------------------------

@@ -681,31 +681,3 @@ class TestResilienceCheckCLI:
         monkeypatch.setitem(resilience_check.CHECKS, "seeded_boom", boom)
         assert resilience_check.main(
             ["--self", "--check", "seeded_boom"]) == 2
-
-
-# ---------------------------------------------------------------------------
-# bench wiring (satellite: resilience_overhead leg in compare_bench)
-# ---------------------------------------------------------------------------
-class TestBenchWiring:
-    def test_compare_bench_extracts_resilience_overhead(self):
-        from tools import compare_bench
-
-        names = [m[0] for m in compare_bench.METRICS]
-        assert "resilience_overhead_pct" in names
-        assert "resilience_overhead_pct" in compare_bench.ABS_TOLERANCE
-        legs = compare_bench.extract_legs(
-            {"resilience_overhead": {"overhead_pct": 0.4}})
-        assert legs["resilience_overhead_pct"] == -0.4  # lower-is-better
-
-    def test_overhead_within_tolerance_not_regression(self):
-        from tools import compare_bench
-
-        base = {"resilience_overhead": {"overhead_pct": 0.1}}
-        new = {"resilience_overhead": {"overhead_pct": 0.8}}
-        cmp = compare_bench.compare(base, new, threshold=0.05)
-        assert not [r for r in cmp["regressions"]
-                    if r["leg"] == "resilience_overhead_pct"]
-        worse = {"resilience_overhead": {"overhead_pct": 1.5}}
-        cmp = compare_bench.compare(base, worse, threshold=0.05)
-        assert [r for r in cmp["regressions"]
-                if r["leg"] == "resilience_overhead_pct"]

@@ -15,17 +15,14 @@ Coverage map (the ISSUE-6 acceptance surface):
 - assert_step_clean on the jitted decode step (KV cache donated, no
   ungated callbacks) with the in-jit telemetry drain ARMED;
 - satellites: amp.cast_params_for_inference, telemetry.percentiles,
-  tools/serving_check.py exit codes, compare_bench serving legs;
+  tools/serving_check.py exit codes;
 - tensor parallelism (ISSUE-16): TP=2/4 token identity vs TP=1 on the
   8-virtual-device mesh (tools/serving_check tp_identity), the 3-psum-
   per-program jaxpr pin with no pool-shaped all-gather, head-sharded
   PagedKVSpec geometry, sharding-preserving inference cast, the
   top_k<=filter-width submit guard, TP-tagged telemetry + DP x TP fleet
-  summary, topology-preserving recover/rebuild/swap, and the committed
-  equal-chip DP-vs-TP bench artifact.
+  summary, topology-preserving recover/rebuild/swap.
 """
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -623,36 +620,6 @@ def test_serving_check_detects_broken_engine(monkeypatch):
     assert sc.main(["--self", "--check", "token_identity"]) == 1
 
 
-def test_compare_bench_surfaces_serving_legs():
-    """The serving legs ride compare_bench with regression exit codes:
-    a throughput drop or a latency increase past threshold regresses."""
-    from tools.compare_bench import compare, extract_legs
-
-    base = {"serving_throughput": {
-        "tokens_per_sec": 100.0, "p50_ms": 50.0, "p99_ms": 80.0,
-        "occupancy": 0.9}}
-    legs = extract_legs(base)
-    assert legs["serving_tokens_per_sec"] == 100.0
-    assert legs["serving_p50_ms"] == -50.0  # lower-is-better inverted
-    slower = {"serving_throughput": {
-        "tokens_per_sec": 100.0, "p50_ms": 50.0, "p99_ms": 120.0,
-        "occupancy": 0.9}}
-    rep = compare(base, slower, threshold=0.05)
-    assert [r["leg"] for r in rep["regressions"]] == ["serving_p99_ms"]
-    assert rep["regressions"][0]["base"] == 80.0
-    assert rep["regressions"][0]["new"] == 120.0
-    faster = {"serving_throughput": {
-        "tokens_per_sec": 120.0, "p50_ms": 40.0, "p99_ms": 80.0,
-        "occupancy": 0.95}}
-    rep = compare(base, faster, threshold=0.05)
-    assert {r["leg"] for r in rep["improvements"]} >= {
-        "serving_tokens_per_sec", "serving_p50_ms"}
-    # committed CPU smoke artifact parses and carries both legs
-    art = json.load(open("bench_artifacts/serving_cpu_smoke.json"))
-    assert art["serving_throughput"]["tokens_per_sec"] > 0
-    assert art["prefill_decode_split"]["prefill_slot_steps"] > 0
-
-
 def test_scheduler_rejects_oversized_requests(tiny_model):
     cfg, params = tiny_model
     eng = ServingEngine(cfg, params, n_slots=1, num_pages=8,
@@ -868,26 +835,6 @@ def test_cast_params_for_inference_preserves_sharding(tiny_model):
     again = cast_params_for_inference(out, jnp.bfloat16)
     assert again["w_col"] is out["w_col"]
     assert again["b_rep"] is out["b_rep"]
-
-
-def test_serving_tp_bench_artifact_and_compare_legs():
-    """Satellite 4: the committed equal-chip DP-vs-TP smoke artifact
-    parses and carries the contract numbers (psum budget, halved
-    per-chip pool, zero leaks), and compare_bench extracts + orients
-    the two gated serving_tp legs."""
-    from tools.compare_bench import extract_legs
-
-    art = json.load(open("bench_artifacts/serving_tp_cpu_smoke.json"))
-    tp = art["serving_tp"]
-    assert tp["tp"] == 2 and tp["chips"] == 2
-    assert tp["tokens_per_sec"] > 0 and tp["dp_tokens_per_sec"] > 0
-    assert all(v == 3 for v in tp["psum_per_program"].values())
-    assert tp["kv_bytes_per_chip_ratio"] == 0.5
-    assert tp["page_leaks"] == 0
-    legs = extract_legs(art)
-    assert legs["serving_tp_tokens_per_sec"] == tp["tokens_per_sec"]
-    # lower-is-better legs are sign-inverted at extraction
-    assert legs["serving_tp_p99_ms"] == -tp["p99_ms"]
 
 
 def test_tp_recover_and_swap_keep_topology(tiny_model):

@@ -14,6 +14,7 @@ from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing import (
     DistributedTestBase,
     GPTConfig,
+    LayerKind,
     bert_model_provider,
     gpt_loss,
     gpt_model_provider,
@@ -283,3 +284,87 @@ def test_selective_policy_saves_named_pallas_outputs():
     # the flash fwd kernel outputs must be saved, not rematted
     pallas_saved = [d for _, d in res if "output of pallas_call" in str(d)]
     assert pallas_saved, [str(d) for _, d in res]
+
+
+# ---------------------------------------------------------------------------
+# the scanned block and the block by kind agree where they overlap
+# ---------------------------------------------------------------------------
+_OVERLAP = dict(num_layers=3, hidden_size=32, num_attention_heads=4,
+                vocab_size=96, max_position_embeddings=16,
+                hidden_dropout=0.0, attention_dropout=0.0)
+
+
+def _by_kind_layout(cfg, scanned):
+    """The scanned block's stacked parameters in the ``layer_kinds``
+    layout: every leaf unstacked into its layer's dict, and ``qkv_w [L, 3h,
+    h]`` (rows laid out per head as ``[q | k | v]``) split into ``q_w``,
+    ``k_w``, ``v_w`` (their biases likewise)."""
+    n, d = cfg.num_attention_heads, cfg.kv_channels
+    stacked = dict(scanned["layers"])
+    w = stacked.pop("qkv_w").reshape(cfg.num_layers, n, 3, d, -1)
+    b = stacked.pop("qkv_b").reshape(cfg.num_layers, n, 3, d)
+    for i, name in enumerate("qkv"):
+        stacked[f"{name}_w"] = w[:, :, i].reshape(
+            cfg.num_layers, n * d, -1)
+        stacked[f"{name}_b"] = b[:, :, i].reshape(cfg.num_layers, n * d)
+    return {**scanned, "layers": [
+        {k: v[l] for k, v in stacked.items()}
+        for l in range(cfg.num_layers)]}
+
+
+def _scanned_layout(cfg, by_kind):
+    """``_by_kind_layout`` the other way round (for the gradients)."""
+    n, d = cfg.num_attention_heads, cfg.kv_channels
+    stacked = {k: jnp.stack([lp[k] for lp in by_kind["layers"]])
+               for k in by_kind["layers"][0]}
+    w = [stacked.pop(f"{name}_w").reshape(cfg.num_layers, n, d, -1)
+         for name in "qkv"]
+    b = [stacked.pop(f"{name}_b").reshape(cfg.num_layers, n, d)
+         for name in "qkv"]
+    stacked["qkv_w"] = jnp.stack(w, axis=2).reshape(
+        cfg.num_layers, 3 * n * d, -1)
+    stacked["qkv_b"] = jnp.stack(b, axis=2).reshape(
+        cfg.num_layers, 3 * n * d)
+    return {**by_kind, "layers": stacked}
+
+
+@pytest.mark.parametrize("unroll", [1, -1])
+@pytest.mark.parametrize("recompute", [None, "full", "selective"])
+def test_scanned_block_equals_block_by_kind_where_they_overlap(
+        recompute, unroll):
+    """The net under ROADMAP D1 (one model definition): plain
+    full-attention layers with LayerNorm, biases, a GeLU MLP, learned
+    positions and a tied head through ``layer_by_kind`` give the scanned
+    ``transformer_layer``'s loss and gradients on the same weights, in
+    float32 without dropout, under every recompute policy both take."""
+    scanned_cfg = GPTConfig(**_OVERLAP, recompute_granularity=recompute,
+                            layer_unroll=unroll)
+    kind_cfg = GPTConfig(
+        **_OVERLAP, recompute_granularity=recompute, layer_unroll=unroll,
+        layer_kinds=(LayerKind(None, False, False),) * 3, norm="layernorm",
+        linear_bias=True, gated_mlp=False, learned_positions=True,
+        untied_head=False)
+    params = init_gpt_params(scanned_cfg, jax.random.PRNGKey(3))
+    # biases and gains away from their 0 / 1 start, so that each is felt
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(4), len(leaves))
+    params = jax.tree_util.tree_unflatten(tree, [
+        x + 0.05 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 16), 0, 96)
+    labels = jnp.roll(tokens, -1, axis=1)
+
+    loss_s, grads_s = jax.value_and_grad(
+        lambda p: gpt_loss(scanned_cfg, p, tokens, labels))(params)
+    loss_k, grads_k = jax.value_and_grad(
+        lambda p: gpt_loss(kind_cfg, p, tokens, labels))(
+            _by_kind_layout(scanned_cfg, params))
+    grads_k = _scanned_layout(scanned_cfg, grads_k)
+
+    np.testing.assert_allclose(float(loss_k), float(loss_s), rtol=1e-6)
+    assert (jax.tree_util.tree_structure(grads_k)
+            == jax.tree_util.tree_structure(grads_s))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads_k),
+                            jax.tree_util.tree_leaves(grads_s)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6,
+            err_msg=jax.tree_util.keystr(path))

@@ -583,29 +583,3 @@ def test_self_audit_cli_fail_on_warning(monkeypatch, capsys):
     assert static_audit.main(
         ["--self", "--target", "warny", "--json", "--fail-on",
          "warning"]) == 1
-
-
-# ---------------------------------------------------------------------------
-# compare_bench integration: audit status rides the perf gate
-# ---------------------------------------------------------------------------
-def test_compare_bench_reports_audit_status():
-    from tools.compare_bench import compare
-
-    base = {"value": 30000.0,
-            "audit": {"ok": True, "error": 0, "warning": 0, "codes": []}}
-    new = {"value": 30000.0,
-           "audit": {"ok": False, "error": 2, "warning": 1,
-                     "codes": ["undonated_state", "ungated_callback"]}}
-    rep = compare(base, new)
-    assert rep["audit"]["base"]["ok"] is True
-    assert rep["audit"]["new"]["ok"] is False
-    legs = [r["leg"] for r in rep["regressions"]]
-    assert "static_audit" in legs
-
-
-def test_compare_bench_audit_absent_is_not_a_regression():
-    from tools.compare_bench import compare
-
-    rep = compare({"value": 30000.0}, {"value": 30000.0})
-    assert rep["audit"] == {"base": None, "new": None}
-    assert rep["regressions"] == []

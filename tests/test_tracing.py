@@ -17,8 +17,7 @@ Covers the satellites around the tracing tentpole:
   span per offered request, and TTFT attribution terms that sum to the
   measured TTFT within 1%;
 - CI wiring — tools/trace_report.py CHECKS run tier-1 and its CLI exit
-  codes hold; compare_bench gates ``trace_overhead_pct`` and the
-  attribution-summary schema; the committed CPU-smoke artifact parses.
+  codes hold.
 """
 import json
 import sys
@@ -40,7 +39,6 @@ from apex_tpu.telemetry import (  # noqa: E402
 from apex_tpu.telemetry.spans import ATTR_TERMS  # noqa: E402
 
 import trace_report  # noqa: E402  (tools/)
-from tools import compare_bench  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
@@ -300,77 +298,3 @@ class TestTraceReportCLI:
             for s in spans:
                 f.write(json.dumps(s) + "\n")
         assert trace_report.main([str(p)]) == 0
-
-
-# ---------------------------------------------------------------------------
-# satellite 6b: compare_bench gates (trace_overhead + attribution schema)
-# ---------------------------------------------------------------------------
-def _valid_attr_block():
-    pct = {"p50": 1.0, "p90": 2.0, "p99": 3.0}
-    return {
-        "terms": list(compare_bench.ATTR_TERMS),
-        "ttft_ms": {t: dict(pct) for t in compare_bench.ATTR_TERMS},
-        "e2e_ms": {t: dict(pct) for t in compare_bench.ATTR_TERMS},
-        "n_attributed": 4,
-        "ttft_sum_rel_err_max": 0.0,
-    }
-
-
-class TestBenchWiring:
-    def test_trace_overhead_leg_extracted(self):
-        names = [m[0] for m in compare_bench.METRICS]
-        assert "trace_overhead_pct" in names
-        assert "trace_overhead_pct" in compare_bench.ABS_TOLERANCE
-        legs = compare_bench.extract_legs(
-            {"trace_overhead": {"overhead_pct": 0.4}})
-        assert legs["trace_overhead_pct"] == -0.4  # lower-is-better
-
-    def test_overhead_within_abs_tolerance_not_regression(self):
-        base = {"trace_overhead": {"overhead_pct": 0.1}}
-        new = {"trace_overhead": {"overhead_pct": 0.8}}
-        cmp = compare_bench.compare(base, new, threshold=0.05)
-        assert not any(r["leg"] == "trace_overhead_pct"
-                       for r in cmp["regressions"])
-        new = {"trace_overhead": {"overhead_pct": 2.0}}
-        cmp = compare_bench.compare(base, new, threshold=0.05)
-        assert any(r["leg"] == "trace_overhead_pct"
-                   for r in cmp["regressions"])
-
-    def test_attribution_schema_valid_block_passes(self):
-        bench = {"serving_throughput": {"attribution": _valid_attr_block()},
-                 "serving_fleet": {"attribution": _valid_attr_block()}}
-        assert compare_bench.attribution_problems(bench) == []
-
-    def test_attribution_schema_absent_block_is_fine(self):
-        assert compare_bench.attribution_problems(
-            {"serving_throughput": None}) == []
-        assert compare_bench.attribution_problems({}) == []
-
-    def test_attribution_schema_flags_drift(self):
-        bad = _valid_attr_block()
-        del bad["ttft_ms"]["decode"]  # missing term
-        probs = compare_bench.attribution_problems(
-            {"serving_fleet": {"attribution": bad}})
-        assert any("ttft_ms" in p for p in probs)
-        broken_sum = _valid_attr_block()
-        broken_sum["ttft_sum_rel_err_max"] = 0.5  # identity broken
-        probs = compare_bench.attribution_problems(
-            {"serving_fleet": {"attribution": broken_sum}})
-        assert any("rel_err" in p for p in probs)
-
-    def test_compare_flags_malformed_attribution_as_regression(self):
-        bad = _valid_attr_block()
-        bad["terms"] = ["queue_wait"]
-        new = {"serving_fleet": {"attribution": bad}}
-        cmp = compare_bench.compare({}, new, threshold=0.05)
-        assert any(r["leg"] == "attribution_schema"
-                   for r in cmp["regressions"])
-
-    def test_committed_cpu_smoke_artifact_parses(self):
-        art = json.loads(
-            (REPO / "bench_artifacts" /
-             "trace_overhead_cpu_smoke.json").read_text())
-        leg = art["trace_overhead"]
-        assert leg["within_1pct"] is True
-        assert leg["steps"] > 0 and leg["n_requests"] > 0
-        assert compare_bench.attribution_problems(art) == []

@@ -128,11 +128,11 @@ def health_from_records(records: Iterable[dict]) -> dict:
         elif ev == "step" and isinstance(r.get("t_dispatch"), (int, float)):
             step_stamps[r.get("leg") or "?"].append(float(r["t_dispatch"]))
 
-    # per-leg percentiles over gaps between the bench per-step
+    # per-leg percentiles over gaps between a trainer's per-step
     # t_dispatch stamps, via the shared telemetry.percentiles reducer
     # (no hand-rolled percentile math here or in the serving leg).
     # These are DISPATCH intervals — the stamps are taken host-side
-    # with no sync (bench.py), so on an async backend they measure how
+    # with no sync, so on an async backend they measure how
     # fast the host issues steps, not how long the device takes; true
     # step time is the leg summary's step_ms.
     from apex_tpu.telemetry import percentiles

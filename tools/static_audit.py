@@ -64,8 +64,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # (donation, gating, aliasing, alignment) are size-independent.
 # ---------------------------------------------------------------------------
 def build_gpt_step():
-    """The headline bench leg's shape: bf16 GPT, packed FusedAdam with
-    masters, params+state donated, loss carried (bench.py:bench_gpt)."""
+    """The shape of ``gpt2-345m.train-1chip``'s step: bf16 GPT, packed
+    FusedAdam with masters, params+state donated, loss carried."""
     import jax
     import jax.numpy as jnp
 
@@ -378,8 +378,8 @@ def run_self_audit(targets=None, rules=None):
 
 
 def summarize(result: dict) -> dict:
-    """The one-line summary bench.py/compare_bench.py embed: counts per
-    severity plus the distinct finding codes (stable, sorted)."""
+    """The one-line summary the CLI prints: counts per severity plus
+    the distinct finding codes (stable, sorted)."""
     counts = {"error": 0, "warning": 0, "info": 0}
     codes = set()
     for t in result["targets"].values():
