@@ -50,6 +50,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -861,6 +862,21 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
+# The two outputs of a forward kernel that its backward kernels read, named
+# where the custom VJPs' forward rules hand them on (``o`` [b, n, s, d] in the
+# compute dtype, ``lse`` float32 [b, n, s]): a ``jax.checkpoint`` policy of
+# ``save_only_these_names(*FLASH_RESIDUAL_NAMES)`` keeps them, and the
+# replay in backward then holds no forward kernel (``recompute_granularity=
+# "full"``, standalone_transformer_lm._remat). Under any other policy, and
+# under none, a name is an identity that compiles to nothing.
+FLASH_RESIDUAL_NAMES = ("apex_tpu_flash_o", "apex_tpu_flash_lse")
+
+
+def _name_residuals(o, lse):
+    name_o, name_lse = FLASH_RESIDUAL_NAMES
+    return checkpoint_name(o, name_o), checkpoint_name(lse, name_lse)
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14)
 )
@@ -875,10 +891,10 @@ def _flash(q, k, v, bias, kv_mask, segs, seed, scale, causal, dropout_p,
 def _flash_fwd(q, k, v, bias, kv_mask, segs, seed, scale, causal, dropout_p,
                block_q, block_k, interpret, bias_grad=True, bwd_blocks=None):
     seg_q, seg_k = segs if segs is not None else (None, None)
-    o, lse = _fwd(
+    o, lse = _name_residuals(*_fwd(
         q, k, v, bias, kv_mask, seg_q, seg_k, seed, scale, causal, dropout_p,
         block_q, block_k, interpret,
-    )
+    ))
     return o, (q, k, v, bias, kv_mask, segs, seed, o, lse)
 
 
@@ -1114,7 +1130,9 @@ def _band_fwd(q, k, v, scale, window, block_q, block_k, interpret):
         compiler_params=_band_params(),
         interpret=interpret,
     )(*lists, q, k, v)
-    return o, lse
+    # the kernel writes lse as [b, n, s, 1], one valid lane of 128 in HBM:
+    # what is handed on is the lane-dense [b, n, s], as _fwd's
+    return o, lse[..., 0]
 
 
 def _band_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
@@ -1126,6 +1144,7 @@ def _band_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
     tiles = band_tiles(s, bq, bk, window)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)
+    lse = lse[..., None]        # row statistics as lane-dim-1 blocks
     kw = dict(scale=scale, window=window, block_q=bq, block_k=bk)
 
     lists = _tile_lists(tiles)
@@ -1182,7 +1201,8 @@ def _flash_band(q, k, v, scale, window, block_q, block_k, interpret):
 
 
 def _flash_band_fwd(q, k, v, scale, window, block_q, block_k, interpret):
-    o, lse = _band_fwd(q, k, v, scale, window, block_q, block_k, interpret)
+    o, lse = _name_residuals(
+        *_band_fwd(q, k, v, scale, window, block_q, block_k, interpret))
     return o, (q, k, v, o, lse)
 
 

@@ -44,6 +44,7 @@ from ..tensor_parallel import mappings
 from ...ops.layer_norm import layer_norm as fused_layer_norm
 from ...ops.layer_norm import rms_norm
 from ...ops.flash_attention import (
+    FLASH_RESIDUAL_NAMES,
     flash_attention,
     flash_attention_available,
     flash_attention_sbhd,
@@ -91,7 +92,10 @@ class GPTConfig:
     apply_query_key_layer_scaling: bool = True
     attn_mask_type: AttnMaskType = AttnMaskType.causal
     # None | "full" | "selective" | "selective_elementwise" — see
-    # transformer_block. "selective_elementwise" additionally pins the
+    # transformer_block. "full" keeps a layer's input and, where the layer
+    # runs the flash kernel, that kernel's output and row statistics (the
+    # replay in backward holds no forward kernel); everything else is
+    # recomputed. "selective_elementwise" additionally pins the
     # fused-block tail kernel outputs as saveable, so backward replays
     # only the cheap unfused elementwise remainder (pairs with
     # fused_block=True; docs/fused_block.md has the decision table).
@@ -1069,7 +1073,10 @@ def _hash_dropout_seed(key, p: float):
 # flash bwd kernel re-derives score tiles from its saved (o, lse), so
 # replaying the fwd kernel in backward is pure waste (~17 MB/layer saved
 # buys back one full fwd flash pass per layer at the 345M bench shape);
-# the O(s) norm outputs skip the LN replay. Deliberately NOT a blanket
+# the O(s) norm outputs skip the LN replay. These policies keep the
+# pallas_call's raw outputs; 'full' keeps the same two flash outputs by
+# name instead (FLASH_RESIDUAL_NAMES: lse lane-dense, [b, n, s], never the
+# kernel's [b, n, s, 1]) and no GEMM output. Deliberately NOT a blanket
 # pallas_call match: the non-flash path's fused-softmax kernel emits the
 # [b, n, s, s] probability tensor — the exact activation selective
 # recompute exists to avoid storing.
@@ -1113,10 +1120,19 @@ def _selective_elementwise_policy(prim, *args, **kwargs):
         *args, **kwargs)
 
 
+# 'full': the flash forward's two named outputs and nothing else; a layer
+# without the flash kernel holds no such name and keeps its input alone.
+# ONE policy object for every layer: jax caches a remat's partial
+# evaluation by the policy's identity, and a fresh closure a layer had
+# every layer's kernels traced and lowered anew (2.5 s of cell 4's set-up).
+_FULL_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *FLASH_RESIDUAL_NAMES)
+
+
 def _remat(cfg: GPTConfig, fn):
     """``fn`` under the configured recompute granularity."""
     if cfg.recompute_granularity == "full":
-        return jax.checkpoint(fn)
+        return jax.checkpoint(fn, policy=_FULL_POLICY)
     if cfg.recompute_granularity == "selective":
         return jax.checkpoint(fn, policy=_selective_policy)
     if cfg.recompute_granularity == "selective_elementwise":
@@ -1176,7 +1192,12 @@ def transformer_block(
 
     ``recompute_granularity="full"`` rematerialises each layer in backward —
     the reference's ``--recompute-granularity full`` activation
-    checkpointing (``tensor_parallel/random.py:237``); ``"selective"``
+    checkpointing (``tensor_parallel/random.py:237``) — but for the flash
+    forward kernel: beside the layer's input its output ``o`` and row
+    statistics ``lse`` (float32 ``[b, n, s]``) are kept, the two the
+    backward kernels read, so the replay runs projections, norms and MLP
+    and no attention kernel (without the flash kernel, the input alone);
+    ``"selective"``
     keeps matmul outputs and replays only the cheap elementwise/softmax work
     (the reference's ``--recompute-granularity selective``);
     ``"selective_elementwise"`` additionally keeps the fused-block tail

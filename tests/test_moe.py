@@ -14,12 +14,15 @@ against the ``afmoe`` family's plain reference.
 - the new scopes stand in the compiled step's text.
 """
 import ast
+import collections
 import dataclasses
 import functools
+import math
 import os
 import pathlib
 import re
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -28,11 +31,15 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from jax._src.ad_checkpoint import saved_residuals  # noqa: E402
+
 from apex_tpu import telemetry  # noqa: E402
+from apex_tpu.analysis import kernel_inventory, walk  # noqa: E402
 from apex_tpu.ops import moe_rows  # noqa: E402
 from apex_tpu.ops.grouped_matmul import grouped_matmul, row_tile  # noqa: E402
 from apex_tpu.transformer import moe  # noqa: E402
 from apex_tpu.transformer.testing import GPTConfig, LayerKind, gpt_loss  # noqa: E402
+from apex_tpu.transformer.testing import standalone_transformer_lm as lm  # noqa: E402
 from apex_tpu.transformer.testing.standalone_transformer_lm import (  # noqa: E402
     init_gpt_params,
 )
@@ -41,6 +48,8 @@ from benchmark import run as harness  # noqa: E402
 from benchmark import traffic, weights  # noqa: E402
 
 CELL = "trinity-mini.train-1chip"
+FLASH_FWD, FLASH_BWD = "apex_tpu_flash_fwd", ("apex_tpu_flash_bwd_dq",
+                                              "apex_tpu_flash_bwd_dkv")
 
 
 @pytest.fixture(scope="module")
@@ -448,6 +457,54 @@ def test_v5e_compiles_the_row_kernels_at_the_cell_s_shapes(one_chip, kernel):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * rows * h
 
 
+def test_v5e_keeps_o_and_a_lane_dense_lse_of_a_layer_at_the_cell_s_shape(
+        one_chip):
+    """``trinity-mini.train-1chip``: one sliding layer's attention (window
+    2048, 32 query heads on 4 K/V heads of 128, 2 x 8192 tokens, bfloat16)
+    under the recipe's ``"full"``: the compiled forward and backward hold
+    one forward kernel, and what the forward hands to the backward beside
+    parameters and input is ``o`` and a ``[b, n, s]`` ``lse``, 0.14 GB: not
+    the kernel's ``f32[2,32,8192,1]`` (one valid lane of 128: 268 MB)."""
+    cell = mf.Cell(mf.load_manifest(), CELL)
+    cfg = cell.family.program_config(cell.config,
+                                     recompute_granularity="full")
+    kind = cfg.layer_kinds[1]
+    assert kind.window == 2048 and cfg.kv_heads == 4
+    bf = jnp.bfloat16
+    lp = {name: jax.ShapeDtypeStruct(x.shape, bf, sharding=one_chip)
+          for name, x in jax.eval_shape(
+              lambda: init_gpt_params(cfg, jax.random.PRNGKey(0))
+          )["layers"][1].items()
+          if name.split("_")[0] in ("q", "k", "v", "proj", "attn")}
+    x = jax.ShapeDtypeStruct((8192, 2, cfg.hidden_size), bf,
+                             sharding=one_chip)
+    layer = lm._remat(cfg, functools.partial(lm.attention_by_kind, cfg, kind))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            step = jax.jit(jax.grad(
+                lambda lp, x: layer(lp, x).astype(jnp.float32).sum(),
+                argnums=(0, 1))).lower(lp, x).compile()
+            forward = jax.jit(
+                lambda lp, x: jax.vjp(layer, lp, x)).lower(lp, x).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    calls = collections.Counter(re.findall(
+        r"^\s*%?(apex_tpu_flash_\w+?)[.\d]* = ", step.as_text(), re.M))
+    assert calls == {FLASH_FWD: 1, FLASH_BWD[0]: 1, FLASH_BWD[1]: 1}
+    handed = [(o.shape, o.dtype) for o in jax.tree_util.tree_leaves(
+        forward.out_info)]
+    assert ((2, 32, 8192, 128), bf) in handed
+    assert ((2, 32, 8192), jnp.float32) in handed
+    assert not [shape for shape, _ in handed if shape == (2, 32, 8192, 1)]
+    given = sum(math.prod(a.shape) * a.dtype.itemsize
+                for a in [*lp.values(), x])
+    kept = sum(math.prod(shape) * dt.itemsize
+               for shape, dt in handed) - given - x.size * 2   # the output
+    assert 0.134e9 < kept < 0.14e9, kept
+
+
 def test_the_new_scopes_stand_in_the_compiled_step_s_text(tiny):
     config, family, d, params, tokens, labels = tiny
     cfg = _f32(family, config, recompute_granularity="full")
@@ -526,6 +583,134 @@ def test_every_new_field_has_a_path_of_its_own():
     expert = init_gpt_params(GPTConfig(**BY_KIND), jax.random.PRNGKey(0))
     assert expert["layers"][1]["experts_gate_w"].shape == (2, 32, 16)
     assert expert["layers"][1]["router_w"].shape == (4, 32)
+
+
+# ---------------------------------------------------------------------------
+# recompute_granularity="full" keeps what the flash forward kernel wrote
+# ---------------------------------------------------------------------------
+#: One sliding and one full layer on grouped K/V heads (``BY_KIND``), and
+#: the scanned block; with the bodies each traces forward (the scan: one).
+FULL_BLOCKS = {
+    "by_kind": (BY_KIND, 2),
+    "scanned": (dict(num_layers=2, hidden_size=32, num_attention_heads=4,
+                     vocab_size=64, max_position_embeddings=16,
+                     hidden_dropout=0.0, attention_dropout=0.0), 1),
+}
+
+
+def _full_case(block, **kw):
+    """``(cfg, params, loss)`` of a small model on the flash kernels."""
+    cfg = GPTConfig(**{**FULL_BLOCKS[block][0], "use_flash_attention": True,
+                       **kw})
+    params = init_gpt_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
+    return cfg, params, lambda p: gpt_loss(cfg, p, tokens,
+                                           jnp.roll(tokens, -1, 1))
+
+
+def _flash_calls(block, recompute):
+    _, params, loss = _full_case(block, recompute_granularity=recompute)
+    return collections.Counter(
+        k.name for k in kernel_inventory(jax.value_and_grad(loss), params))
+
+
+def _loss_and_grads(block, recompute):
+    _, params, loss = _full_case(block, recompute_granularity=recompute)
+    return jax.jit(jax.value_and_grad(loss))(params)
+
+
+@pytest.mark.parametrize("against", ["none", "whole_replay"])
+@pytest.mark.parametrize("block", list(FULL_BLOCKS))
+def test_full_recompute_gives_the_same_loss_and_gradients_bit_for_bit(
+        block, against):
+    """The kept ``o`` and ``lse`` are the values a replay would write: equal
+    to the replay of the whole layer (no name kept) and to no recomputation.
+    The scan's body alone compiles to other fusions once it is replayed at
+    all, with or without the names: there the last bit against ``None``."""
+    full = _loss_and_grads(block, "full")
+    if against == "none":
+        other = _loss_and_grads(block, None)
+    else:
+        with mock.patch.object(lm, "_FULL_POLICY", None):
+            other = _loss_and_grads(block, "full")
+    exact = (block, against) != ("scanned", "none")
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(full),
+                            jax.tree_util.tree_leaves(other)):
+        if exact:
+            np.testing.assert_array_equal(a, b, jax.tree_util.keystr(path))
+        else:
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-7,
+                                       err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("block", list(FULL_BLOCKS))
+def test_full_recompute_runs_the_flash_forward_once_a_layer(block):
+    """``value_and_grad`` holds one forward kernel a layer body, as without
+    recomputation (a replay would make it two), and the backward kernels
+    it held before."""
+    bodies = FULL_BLOCKS[block][1]
+    full, none = _flash_calls(block, "full"), _flash_calls(block, None)
+    assert full[FLASH_FWD] == none[FLASH_FWD] == bodies
+    # (one key block, the scanned model here: dq rides in the dkv kernel)
+    assert [full[name] for name in FLASH_BWD] == [
+        none[name] for name in FLASH_BWD]
+    assert full[FLASH_BWD[1]] == bodies
+    # the other policies keep the kernel's raw outputs and do not replay it
+    # either: the names are identities to them
+    assert _flash_calls(block, "selective")[FLASH_FWD] == bodies
+    with mock.patch.object(lm, "_FULL_POLICY", None):
+        assert _flash_calls(block, "full")[FLASH_FWD] == 2 * bodies
+
+
+def test_every_layer_under_full_shares_one_policy_object():
+    """jax caches a remat's partial evaluation by the policy's identity: a
+    fresh ``save_only_these_names`` closure a layer had every layer's
+    kernels traced and lowered anew (60 kernel bodies in cell 4's step for
+    the parent's 41, 2.5 s of ``setup_s`` on the chip's host, PR 32)."""
+    _, params, loss = _full_case("by_kind", recompute_granularity="full")
+    policies = [eqn.params["policy"]
+                for eqn, _ in walk(jax.make_jaxpr(loss)(params).jaxpr)
+                if eqn.primitive.name == "remat2"
+                and eqn.params["policy"] is not None]   # (the loss's: None)
+    assert len(policies) == 2 and policies[0] is policies[1]
+
+
+def _saved_by_a_layer(block, **kw):
+    """What one layer under ``"full"`` hands to its backward pass, beyond
+    its parameters: ``[(shape, dtype, where from)]``."""
+    cfg, params, _ = _full_case(block, recompute_granularity="full", **kw)
+    hidden = jnp.ones((16, 2, cfg.hidden_size), cfg.compute_dtype)
+    if block == "by_kind":
+        lp, kind = params["layers"][0], cfg.layer_kinds[0]
+        layer = lambda lp, h: lm.layer_by_kind(cfg, kind, lp, h)[0]  # noqa: E731
+    else:
+        lp = jax.tree_util.tree_map(lambda x: x[0], params["layers"])
+        layer = lambda lp, h: lm.transformer_layer(  # noqa: E731
+            cfg, lp, h, None, None, None, True, 1)
+    fn = lm._remat(cfg, lambda lp, h: layer(lp, h).sum())
+    return [(a.shape, a.dtype, why) for a, why in saved_residuals(
+        fn, lp, hidden) if "the argument lp" not in why
+        and "a constant" not in why]
+
+
+@pytest.mark.parametrize("block", list(FULL_BLOCKS))
+def test_a_layer_under_full_keeps_its_input_o_and_a_rank_3_lse(block):
+    """And no GEMM output: ``o`` as ``[b, n, s, d]``, ``lse`` float32
+    ``[b, n, s]`` (never the banded kernel's ``[b, n, s, 1]``)."""
+    cfg = _full_case(block)[0]
+    b, n, s, d = 2, cfg.num_attention_heads, 16, cfg.kv_channels
+    saved = _saved_by_a_layer(block)
+    assert sorted((shape, str(dt)) for shape, dt, _ in saved) == sorted([
+        ((s, b, cfg.hidden_size), "float32"), ((b, n, s, d), "float32"),
+        ((b, n, s), "float32")])
+    assert any("'apex_tpu_flash_lse'" in why for _, _, why in saved)
+
+
+@pytest.mark.parametrize("block", list(FULL_BLOCKS))
+def test_without_the_flash_kernel_full_keeps_the_input_alone(block):
+    saved = _saved_by_a_layer(block, use_flash_attention=False)
+    assert [(shape, "argument h" in why) for shape, _, why in saved] == [
+        ((16, 2, 32), True)]
 
 
 # ---------------------------------------------------------------------------
