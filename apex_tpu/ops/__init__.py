@@ -31,6 +31,8 @@ from .packed_optimizer import (  # noqa: F401
 )
 from .flash_attention import (  # noqa: F401
     flash_attention,
+    flash_attention_bshd,
+    flash_attention_qkv,
     flash_attention_sbhd,
     flash_attention_available,
 )

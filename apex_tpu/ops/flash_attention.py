@@ -16,11 +16,19 @@ the saved logsumexp (the flash-attention-2 scheme): one kernel accumulates
 dq over key blocks, a second accumulates dk/dv over query blocks, with
 ``delta = rowsum(dO * O)`` precomputed in XLA.
 
-Layouts: ``[b, n, s, d]`` (canonical) via :func:`flash_attention`, the
-Megatron ``[s, b, n, d]`` wrapper :func:`flash_attention_sbhd`, and the
-packed-varlen layout ``[total, n, d]`` + ``cu_seqlens`` via
-:func:`flash_attention_varlen` (the reference fmha's primary mode,
-``contrib/fmha/fmha.py:33-92``) — implemented with per-token segment ids so
+Layouts: one kernel body a pass (forward, ``bwd_dq``, ``bwd_dkv``) reads
+q, k, v as two-dimensional ``[rows, lanes]`` arrays cut into ``[block, hp x
+d]`` blocks, and which rows and lanes a block is follows from the entry
+point (``_Layout``): head-major ``[b, n, s, d]`` via :func:`flash_attention`
+(one head a block; the reference's API), batch-major ``[b, s, n, d]`` via
+:func:`flash_attention_bshd` (what a projection GEMM over ``[b, s, hidden]``
+writes and an output projection reads: ``hp`` heads side by side in 128
+lanes, two at ``d = 64``; nothing is transposed, split or copied round the
+kernels), Megatron's ``[s, b, n, d]`` via :func:`flash_attention_sbhd`
+(batch-major after a swap of the first two axes), and the packed-varlen
+layout ``[total, n, d]`` + ``cu_seqlens`` via :func:`flash_attention_varlen`
+(the reference fmha's primary mode, ``contrib/fmha/fmha.py:33-92``;
+batch-major with one batch row) — implemented with per-token segment ids so
 tokens only attend within their own sequence.
 
 Supports: causal masking (block-skipped: tiles strictly above the diagonal
@@ -46,7 +54,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -213,14 +221,165 @@ def _mask_scores(s, qi, ki, *, causal, have_mask, mask_ref, have_segs,
 
 
 # ---------------------------------------------------------------------------
-# forward
+# the dense kernels' layouts
 # ---------------------------------------------------------------------------
 
 
-def _scaled_q(q_ref, scale):
-    """The softmax scale folded into the [bq, d] q block (16x cheaper than
+def heads_per_block(n: int, d: int) -> int:
+    """Heads that one lane block of the batch-major ``[b, s, n x d]`` layout
+    holds side by side: one where ``d`` is a multiple of 128, ``128 // d``
+    (two at 64, four at 32) where ``n`` divides into such groups, and 0
+    where that layout cannot be cut into blocks the chip addresses (a
+    block's lane width is a multiple of 128): ``d`` of neither kind, or
+    one head of 64 on a tensor-parallel rank."""
+    if d % 128 == 0:
+        return 1
+    if d in (32, 64) and n % (128 // d) == 0:
+        return 128 // d
+    return 0
+
+
+class _Layout(NamedTuple):
+    """How q, k, v, o and their gradients are cut into blocks. Every such
+    operand is seen as a two-dimensional ``[rows, lanes]`` array (a reshape
+    that moves nothing) and a kernel gets ``[block, hp x d]`` blocks of it,
+    ``hp`` heads side by side in the lanes. By ``kind``:
+
+    - ``"bnsd"``, head-major ``[b, n, s, d]`` as ``[b x n x s, d]``, one
+      head a block;
+    - ``"bshd"``, batch-major ``[b, s, n, d]`` as ``[b x s, n x d]``, the
+      array a projection GEMM over ``[b, s, hidden]`` writes: a block is
+      ``hp = heads_per_block(n, d)`` heads of one batch row;
+    - ``"qkv"``, one array ``[b, s, 3, n, d]`` as ``[b x s, 3 x n x d]``,
+      what ONE fused projection writes when the rows of its weight run
+      ``[(q, k, v), head, d]``: q, k and v are three views of it (a block
+      of ``hp`` heads of k lies ``n x d`` lanes after that of q), the
+      context and ``do`` are batch-major.
+
+    The grids walk ``(batch, block of heads, ., .)``."""
+    b: int
+    n: int
+    d: int
+    hp: int
+    kind: str
+
+    @classmethod
+    def of(cls, q, kind):
+        if kind == "bnsd":
+            b, n, _, d = q.shape
+            return cls(b, n, d, 1, kind)
+        b, n, d = q.shape[0], q.shape[-2], q.shape[-1]
+        hp = heads_per_block(n, d)
+        if not hp:
+            raise ValueError(
+                f"{n} heads of {d} do not cut into 128-lane blocks: "
+                "take the head-major layout")
+        return cls(b, n, d, hp, kind)
+
+    def seq(self, x):
+        return x.shape[2] if self.kind == "bnsd" else x.shape[1]
+
+    def flat(self, x):
+        if self.kind == "bnsd":
+            return x.reshape(-1, self.d)
+        return x.reshape(x.shape[0] * x.shape[1], -1)
+
+    def qkv(self, q, k, v):
+        """The three flat operands, and where each one's blocks start in
+        the lanes (in blocks; None: at the array's own first lane)."""
+        if self.kind == "qkv":
+            x = self.flat(q)
+            return (x, x, x), (0, 1, 2)
+        return (self.flat(q), self.flat(k), self.flat(v)), (None,) * 3
+
+    def spec(self, rows, n_blocks, pick, part=None):
+        """BlockSpec of a ``[rows, hp x d]`` block; ``pick(i2, i3)`` is the
+        block's place along the sequence (of ``n_blocks``) from the grid's
+        last two indices, ``part`` the view (q, k or v: 0, 1, 2) of a
+        ``"qkv"`` array."""
+        n, head_major = self.n, self.kind == "bnsd"
+        first = 0 if part is None else part * (n // self.hp)
+
+        def index(ib, ip, i2, i3):
+            i = pick(i2, i3)
+            if head_major:
+                return (ib * n + ip) * n_blocks + i, 0
+            return ib * n_blocks + i, first + ip
+
+        return pl.BlockSpec((rows, self.hp * self.d), index)
+
+    def row_spec(self, rows, pick):
+        """Row statistics ``[b, n, s, 1]`` (lse, delta): ``[hp, rows, 1]``
+        blocks, a head's column at ``ref[j]``."""
+        return pl.BlockSpec(
+            (None, self.hp, rows, 1),
+            lambda ib, ip, i2, i3: (ib, ip, pick(i2, i3), 0))
+
+    def row_stat(self, x):
+        """``[b, n, s]`` from a per-head row reduction of ``o``'s layout."""
+        return x if self.kind == "bnsd" else jnp.swapaxes(x, 1, 2)
+
+    def context(self, s_q):
+        """Shape of the context (and of ``do``)."""
+        if self.kind == "bnsd":
+            return self.b, self.n, s_q, self.d
+        return self.b, s_q, self.n, self.d
+
+    def flat_shape(self, s):
+        """Of a flat operand that is not the ``"qkv"`` array."""
+        if self.kind == "bnsd":
+            return self.b * self.n * s, self.d
+        return self.b * s, self.n * self.d
+
+
+def _first(i2, i3):
+    return i2
+
+
+def _second(i2, i3):
+    return i3
+
+
+class _Tile(NamedTuple):
+    """What the three dense kernel bodies share (all static)."""
+    scale: float
+    causal: bool
+    block_q: int
+    block_k: int
+    n_heads: int
+    d: int
+    hp: int
+    bias_heads: int     # heads in a bias block: hp, or 1 (broadcast over heads)
+    have_bias: bool
+    have_mask: bool
+    have_segs: bool
+    dropout_p: float
+
+
+def _lanes_of(t: _Tile, j: int):
+    """``[1, hp x d]`` bool, the lanes of a block's head ``j``; None where
+    a block is one head."""
+    if t.hp == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, t.hp * t.d), 1)
+    return (lane >= j * t.d) & (lane < (j + 1) * t.d)
+
+
+def _only(x, lanes):
+    """``x [rows, hp x d]`` with every other head's lanes zeroed: a product
+    that contracts over the lanes then sees one head, and one that writes
+    them leaves the others' at zero. Selected in float32, where the
+    scaling of q is done too."""
+    if lanes is None:
+        return x
+    return jnp.where(lanes, x.astype(jnp.float32), 0.0).astype(x.dtype)
+
+
+def _scaled_q(q, scale, lanes=None):
+    """The softmax scale folded into the [bq, .] q block (16x cheaper than
     scaling the [bq, bk] score tile; fp32 mul before the cast keeps the
-    rounding to one step). Shared by fwd/dq/dkv so the score computation
+    rounding to one step), with the other heads' lanes of a shared block
+    zeroed in the same pass. Shared by fwd/dq/dkv so the score computation
     cannot desynchronise between kernels.
 
     Numerics: for bf16 inputs the scaled q rounds back to bf16 BEFORE the
@@ -230,16 +389,91 @@ def _scaled_q(q_ref, scale):
     sits well inside the bf16 attention test tolerances; flagging it here
     because it shifts lse by ~1e-3 vs a score-tile-scaled revision, which
     matters only if a test ever pins lse against an external oracle."""
-    return (q_ref[0, 0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    q32 = q.astype(jnp.float32) * scale
+    if lanes is not None:
+        q32 = jnp.where(lanes, q32, 0.0)
+    return q32.astype(q.dtype)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+_TN = ((0,), (0,))      # a.T @ b
+
+
+def _head_scores(t: _Tile, j, iq, ik, q_ref, k_ref, bias_ref, mask_ref,
+                 segq_ref, segk_ref):
+    """Head ``j`` of the block: its lanes, its scaled q (the other heads'
+    lanes zero, so the 128-lane contraction is this head's), the masked
+    float32 ``[bq, bk]`` scores and the tile's global indices — one
+    implementation for the three kernels, so the score and mask semantics
+    cannot desynchronise. Dots run in the INPUT dtype with fp32
+    accumulation: bf16 inputs hit the MXU's native rate."""
+    lanes = _lanes_of(t, j)
+    q = _scaled_q(q_ref[...], t.scale, lanes)
+    s = _dot(q, k_ref[...], _NT)
+    if t.have_bias:
+        s = s + bias_ref[0, j if t.bias_heads > 1 else 0].astype(jnp.float32)
+    qi, ki = _tile_indices(iq, ik, t.block_q, t.block_k)
+    s = _mask_scores(
+        s, qi, ki, causal=t.causal, have_mask=t.have_mask, mask_ref=mask_ref,
+        have_segs=t.have_segs, segq_ref=segq_ref, segk_ref=segk_ref)
+    return lanes, q, s, qi, ki
+
+
+def _probs(t: _Tile, s, m):
+    """exp(s - m) with the fully-masked-row guard: a masked tile (or a
+    bias row folded to -1e30) must contribute exactly zero (such rows have
+    lse = -inf); on the pure-causal/unmasked hot path the -1e30 entries
+    underflow exp to exact 0 already, so the extra [bq, bk] pass is
+    skipped."""
+    p = jnp.exp(s - m)
+    if t.have_mask or t.have_segs or t.have_bias:
+        p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
+    return p
+
+
+def _keep_scaled(t: _Tile, seed_ref, ib, ip, j, qi, ki):
+    """The head's dropout keep mask times 1 / (1 - p), or None."""
+    if t.dropout_p == 0.0:
+        return None
+    bh = ib * t.n_heads + ip * t.hp + j
+    keep = _keep_mask(seed_ref[0], bh, qi, ki, t.dropout_p)
+    return keep * (1.0 / (1.0 - t.dropout_p))
+
+
+def _for_heads(t: _Tile, body):
+    """``body(j)`` for every head ``j`` of a block: a loop the compiler
+    keeps as one (a kernel's code is that of one head, whatever ``hp``)."""
+    if t.hp == 1:
+        body(0)
+    else:
+        jax.lax.fori_loop(0, t.hp, lambda j, c: (body(j), c)[1], 0)
+
+
+def _write_lanes(ref, lanes, val):
+    """``val`` on the head's lanes of the block in ``ref``; the other
+    heads' lanes stay as they are (unwritten ones until their head's
+    turn: every lane belongs to one head of the loop)."""
+    if lanes is not None:
+        val = jnp.where(lanes, val, ref[...].astype(val.dtype))
+    ref[...] = val.astype(ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, bias_ref, mask_ref, segq_ref, segk_ref, seed_ref,
-    o_ref, lse_ref, *scratch,
-    scale, causal, block_q, block_k, n_k, n_heads, have_bias, have_mask,
-    have_segs, dropout_p,
+    o_ref, lse_ref, *scratch, t, n_k,
 ):
-    ib, ih = pl.program_id(0), pl.program_id(1)
+    ib, ip = pl.program_id(0), pl.program_id(1)
     iq, ik = pl.program_id(2), pl.program_id(3)
     # single-k-block fast path: every (iq) sees its whole key range in one
     # tile, so the online-softmax recurrence (scratch buffers, running
@@ -255,94 +489,64 @@ def _fwd_kernel(
             l_scr[:] = jnp.zeros_like(l_scr)
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def score_tile():
-        """Shared prologue: scaled q @ k.T + bias + masking — one
-        implementation for both paths so the score/mask semantics cannot
-        desynchronise (probs()/dropped() below are likewise shared)."""
-        # dots run in the INPUT dtype with fp32 accumulation — bf16
-        # inputs hit the MXU's native rate; upcasting first would force
-        # the slow fp32 matmul path. The softmax scale rides in with q.
-        q = _scaled_q(q_ref, scale)
-        k = k_ref[0, 0]  # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, bk]
-        if have_bias:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        qi, ki = _tile_indices(iq, ik, block_q, block_k)
-        s = _mask_scores(
-            s, qi, ki, causal=causal, have_mask=have_mask, mask_ref=mask_ref,
-            have_segs=have_segs, segq_ref=segq_ref, segk_ref=segk_ref,
-        )
-        return s, qi, ki
+    def head(j):
+        return _head_scores(t, j, iq, ik, q_ref, k_ref, bias_ref, mask_ref,
+                            segq_ref, segk_ref)
 
-    def probs(s, m):
-        """exp(s - m) with the fully-masked-row guard: a masked tile (or
-        a bias row folded to -1e30) must contribute exactly zero; on the
-        pure-causal/unmasked hot path the -1e30 entries underflow exp to
-        exact 0 already, so the extra [bq, bk] pass is skipped."""
-        p = jnp.exp(s - m)
-        if have_mask or have_segs or have_bias:
-            p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
-        return p
-
-    def dropped(p, qi, ki):
+    def pv(p, j, qi, ki):
         # softmax normalizer uses the UNDROPPED probabilities; dropout
         # hits only the value accumulation (standard attention-dropout
-        # semantics: out = dropout(softmax(s)) @ v)
-        if dropout_p == 0.0:
-            return p
-        bh = ib * n_heads + ih
-        keep = _keep_mask(seed_ref[0], bh, qi, ki, dropout_p)
-        return p * keep * (1.0 / (1.0 - dropout_p))
+        # semantics: out = dropout(softmax(s)) @ v). The product fills
+        # every head's lanes of the block; the caller keeps this head's.
+        keep = _keep_scaled(t, seed_ref, ib, ip, j, qi, ki)
+        if keep is not None:
+            p = p * keep
+        return _dot(p.astype(v_ref.dtype), v_ref[...], _NN)
 
-    def pv(p_acc):
-        return jax.lax.dot_general(
-            p_acc.astype(v_ref.dtype), v_ref[0, 0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    def write_out(acc, m, l):
+    def finish(j, lanes, acc, m, l):
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l == 0.0, _NEG_INF, m + jnp.log(safe_l))
+        lse_ref[j] = jnp.where(l == 0.0, _NEG_INF, m + jnp.log(safe_l))
+        _write_lanes(o_ref, lanes, acc / safe_l)
 
     if single:
         # with n_k == 1 the (causal) tile skip never fires: ik == 0
         # always intersects the diagonal band of every q block
-        s, qi, ki = score_tile()
-        m = jnp.max(s, axis=1, keepdims=True)
-        p = probs(s, m)
-        l = jnp.sum(p, axis=1, keepdims=True)
-        write_out(pv(dropped(p, qi, ki)), m, l)
+        def direct(j):
+            lanes, _, s, qi, ki = head(j)
+            m = jnp.max(s, axis=1, keepdims=True)
+            p = _probs(t, s, m)
+            l = jnp.sum(p, axis=1, keepdims=True)
+            finish(j, lanes, pv(p, j, qi, ki), m, l)
+
+        _for_heads(t, direct)
         return
 
-    def compute():
-        s, qi, ki = score_tile()
-        m_prev = m_scr[:, :1]  # [bq, 1]
+    def online(j):
+        lanes, _, s, qi, ki = head(j)
+        m_prev = m_scr[j][:, :1]  # [bq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = probs(s, m_new)
+        p = _probs(t, s, m_new)
         alpha = jnp.exp(m_prev - m_new)
-        if have_mask or have_segs or have_bias:
+        if t.have_mask or t.have_segs or t.have_bias:
             alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0, alpha)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + pv(dropped(p, qi, ki))
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        l_new = alpha * l_scr[j][:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        _write_lanes(acc_scr, lanes, acc_scr[...] * alpha + pv(p, j, qi, ki))
+        m_scr[j] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[j] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-    if causal:
+    if t.causal:
         # skip tiles strictly above the diagonal
-        @pl.when(ik * block_k <= iq * block_q + (block_q - 1))
+        @pl.when(ik * t.block_k <= iq * t.block_q + (t.block_q - 1))
         def _():
-            compute()
+            _for_heads(t, online)
     else:
-        compute()
+        _for_heads(t, online)
 
     @pl.when(ik == n_k - 1)
     def _finish():
-        write_out(acc_scr[:], m_scr[:, :1], l_scr[:, :1])
+        _for_heads(t, lambda j: finish(
+            j, _lanes_of(t, j), acc_scr[...], m_scr[j][:, :1],
+            l_scr[j][:, :1]))
 
 
 def _seg_args(segments, s):
@@ -359,52 +563,35 @@ def _seg_args(segments, s):
     return arr, have
 
 
-def _bias_args(bias, bq, bk, kmajor):
-    """(array, spec, have) for the optional additive-bias input
-    ``[b|1, n|1, s_q|1, s_k]``; broadcast batch/head/row dims pin their
-    block index to 0 (a row-broadcast bias — e.g. an additive key-padding
-    mask — streams [1, bk] tiles and broadcasts in-kernel). ``kmajor``
-    selects the (ik, iq) grid order of the dkv backward kernel."""
-    have = bias is not None
-    if not have:
-        arr = jnp.zeros((1, 1, 8, 128), jnp.float32)
-        return arr, pl.BlockSpec(
-            (1, 1, 8, 128), lambda ib, ih, i2, i3: (0, 0, 0, 0)
-        ), False
+def _bias_input(bias, bq, bk, hp, pick_q, pick_k):
+    """(array, spec) for the optional additive-bias input ``[b|1, n|1,
+    s_q|1, s_k]``; broadcast batch/head/row dims pin their block index to 0
+    (a row-broadcast bias — e.g. an additive key-padding mask — streams
+    [1, bk] tiles and broadcasts in-kernel). A block holds the bias of the
+    ``hp`` heads of a q block (one where it is broadcast over heads)."""
+    if bias is None:
+        return jnp.zeros((1, 1, 8, 128), jnp.float32), pl.BlockSpec(
+            (1, 1, 8, 128), lambda ib, ip, i2, i3: (0, 0, 0, 0))
     bb, bn, brow = bias.shape[0], bias.shape[1], bias.shape[2]
-    row_block = bq if brow > 1 else 1
-    if kmajor:
-        im = lambda ib, ih, ik, iq: (
-            ib if bb > 1 else 0, ih if bn > 1 else 0,
-            iq if brow > 1 else 0, ik)
-    else:
-        im = lambda ib, ih, iq, ik: (
-            ib if bb > 1 else 0, ih if bn > 1 else 0,
-            iq if brow > 1 else 0, ik)
-    return bias, pl.BlockSpec((1, 1, row_block, bk), im), True
+
+    def index(ib, ip, i2, i3):
+        return (ib if bb > 1 else 0, ip if bn > 1 else 0,
+                pick_q(i2, i3) if brow > 1 else 0, pick_k(i2, i3))
+
+    return bias, pl.BlockSpec(
+        (1, hp if bn > 1 else 1, bq if brow > 1 else 1, bk), index)
 
 
-def _fwd(
-    q, k, v, bias, kv_mask, seg_q, seg_k, seed, scale, causal, dropout_p,
-    block_q, block_k, interpret,
-):
-    b, n, s_q, d = q.shape
-    s_k = k.shape[2]
-    bq = _pick_block(s_q, block_q)
-    bk = _pick_block(s_k, block_k)
-    have_bias = bias is not None
+def _side_inputs(plan, bias, kv_mask, seg_q, seg_k, seed, kmajor):
+    """Arrays and BlockSpecs of the bias, the key mask, the two segment-id
+    rows and the dropout seed, for a grid whose last two indices are
+    ``(iq, ik)`` (``kmajor``: ``(ik, iq)``, the dkv backward kernel's)."""
+    (s_q, s_k), (bq, bk) = plan.seqs, plan.blocks
+    b = plan.lay.b
+    pick_q, pick_k = (_second, _first) if kmajor else (_first, _second)
+    bias_arg, bias_spec = _bias_input(bias, bq, bk, plan.lay.hp, pick_q,
+                                      pick_k)
     have_mask = kv_mask is not None
-    if not interpret:
-        # mask/seg/bias blocks put bq/bk on a lane dim (Mosaic: %128 or
-        # whole-dim); interpret mode skips this so CPU tests can exercise
-        # small multi-tile configs
-        if seg_q is not None:
-            bq = _lane_block(s_q, bq)
-        if have_mask or have_bias or seg_k is not None:
-            bk = _lane_block(s_k, bk)
-    n_q, n_k = s_q // bq, s_k // bk
-
-    bias_arg, bias_spec, _ = _bias_args(bias, bq, bk, False)
     mask_arg = (
         kv_mask.astype(jnp.int8).reshape(b, 1, s_k)
         if have_mask
@@ -412,73 +599,110 @@ def _fwd(
     )
     mask_spec = pl.BlockSpec(
         (1, 1, bk if have_mask else 8),
-        (lambda ib, ih, iq, ik: (ib, 0, ik if have_mask else 0)),
-    )
-    if (seg_q is None) != (seg_k is None):
-        raise ValueError("seg_q and seg_k must be provided together")
+        lambda ib, ip, i2, i3: (ib, 0, pick_k(i2, i3) if have_mask else 0))
     segq_arg, have_segs = _seg_args(seg_q, s_q)
     segk_arg, _ = _seg_args(seg_k, s_k)
-    segq_spec = pl.BlockSpec(
-        (1, 1, bq if have_segs else 8),
-        (lambda ib, ih, iq, ik: (ib if have_segs and segq_arg.shape[0] > 1 else 0,
-                                 0, iq if have_segs else 0)),
-    )
-    segk_spec = pl.BlockSpec(
-        (1, 1, bk if have_segs else 8),
-        (lambda ib, ih, iq, ik: (ib if have_segs and segk_arg.shape[0] > 1 else 0,
-                                 0, ik if have_segs else 0)),
-    )
-    seed_arg = jnp.asarray([seed if seed is not None else 0], jnp.int32)
 
-    kernel = functools.partial(
-        _fwd_kernel,
-        scale=scale, causal=causal, block_q=bq, block_k=bk, n_k=n_k,
-        n_heads=n, have_bias=have_bias, have_mask=have_mask,
-        have_segs=have_segs, dropout_p=dropout_p,
+    def seg_spec(arr, blk, pick):
+        per_batch = have_segs and arr.shape[0] > 1
+        return pl.BlockSpec(
+            (1, 1, blk if have_segs else 8),
+            lambda ib, ip, i2, i3: (ib if per_batch else 0, 0,
+                                    pick(i2, i3) if have_segs else 0))
+
+    seed_arg = jnp.asarray([seed if seed is not None else 0], jnp.int32)
+    return (
+        (bias_arg, mask_arg, segq_arg, segk_arg, seed_arg),
+        [bias_spec, mask_spec, seg_spec(segq_arg, bq, pick_q),
+         seg_spec(segk_arg, bk, pick_k),
+         pl.BlockSpec(memory_space=pltpu.SMEM)],
     )
-    grid = (b, n, n_q, n_k)
-    out_shape = [
-        _sds((b, n, s_q, d), q.dtype, q, k, v, bias_arg, mask_arg,
-             segq_arg, segk_arg, seed_arg),
-        _sds((b, n, s_q, 1), jnp.float32, q, k, v, bias_arg, mask_arg,
-             segq_arg, segk_arg, seed_arg),
-    ]
+
+
+class _Plan(NamedTuple):
+    """What ``_fwd`` and ``_bwd`` work out alike from their arguments."""
+    lay: _Layout
+    qkv: tuple          # the flat q, k, v operands
+    parts: tuple        # where each one's blocks start (``_Layout.qkv``)
+    seqs: tuple         # (s_q, s_k)
+    blocks: tuple       # (bq, bk)
+    tile: _Tile
+
+
+def _plan(q, k, v, bias, kv_mask, seg_q, seg_k, scale, causal, dropout_p,
+          block_q, block_k, interpret, kind) -> _Plan:
+    if (seg_q is None) != (seg_k is None):
+        raise ValueError("seg_q and seg_k must be provided together")
+    lay = _Layout.of(q, kind)
+    flat, parts = lay.qkv(q, k, v)
+    s_q, s_k = lay.seq(q), lay.seq(q if k is None else k)
+    bq = _pick_block(s_q, block_q)
+    bk = _pick_block(s_k, block_k)
+    if not interpret:
+        # mask/seg/bias blocks put bq/bk on a lane dim (Mosaic: %128 or
+        # whole-dim); interpret mode skips this so CPU tests can exercise
+        # small multi-tile configs
+        if seg_q is not None:
+            bq = _lane_block(s_q, bq)
+        if kv_mask is not None or bias is not None or seg_k is not None:
+            bk = _lane_block(s_k, bk)
+    tile = _Tile(
+        scale=scale, causal=causal, block_q=bq, block_k=bk, n_heads=lay.n,
+        d=lay.d, hp=lay.hp,
+        bias_heads=lay.hp if bias is not None and bias.shape[1] > 1 else 1,
+        have_bias=bias is not None, have_mask=kv_mask is not None,
+        have_segs=seg_q is not None, dropout_p=dropout_p)
+    return _Plan(lay, flat, parts, (s_q, s_k), (bq, bk), tile)
+
+
+def _fwd(
+    q, k, v, bias, kv_mask, seg_q, seg_k, seed, scale, causal, dropout_p,
+    block_q, block_k, interpret, kind="bnsd",
+):
+    """``(o, lse)`` of the dense forward kernel, ``q``, ``k``, ``v`` in the
+    layout ``kind`` names (``_Layout``; ``"qkv"``: ``q`` is the one array,
+    ``k`` and ``v`` None): ``o`` head-major ``[b, n, s, d]`` for
+    ``"bnsd"``, else ``[b, s, n, d]``; ``lse`` float32 ``[b, n, s_q]``."""
+    plan = _plan(q, k, v, bias, kv_mask, seg_q, seg_k, scale, causal,
+                 dropout_p, block_q, block_k, interpret, kind)
+    lay, parts, (s_q, s_k), (bq, bk) = (
+        plan.lay, plan.parts, plan.seqs, plan.blocks)
+    b, n, hp = lay.b, lay.n, lay.hp
+    n_q, n_k = s_q // bq, s_k // bk
+    side_args, side_specs = _side_inputs(
+        plan, bias, kv_mask, seg_q, seg_k, seed, False)
+    ins = plan.qkv + side_args
+    o_shape = lay.context(s_q)
     # the single-k-block fast path (n_k == 1) runs a direct softmax with
     # NO recurrence scratch — keep that ~1.25 MB of VMEM per program free
     # for the data tiles
     scratch = [] if n_k == 1 else [
-        pltpu.VMEM((bq, 128), jnp.float32),
-        pltpu.VMEM((bq, 128), jnp.float32),
-        pltpu.VMEM((bq, d), jnp.float32),
+        pltpu.VMEM((hp, bq, 128), jnp.float32),
+        pltpu.VMEM((hp, bq, 128), jnp.float32),
+        pltpu.VMEM((bq, hp * lay.d), jnp.float32),
     ]
     o, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, t=plan.tile, n_k=n_k),
         # stable kernel id: remat policies save these outputs by name
         # (standalone_transformer_lm._selective_policy)
         name="apex_tpu_flash_fwd",
-        grid=grid,
+        grid=(b, n // hp, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-            bias_spec,
-            mask_spec,
-            segq_spec,
-            segk_spec,
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            lay.spec(bq, n_q, _first, parts[0]),
+            lay.spec(bk, n_k, _second, parts[1]),
+            lay.spec(bk, n_k, _second, parts[2]),
+            *side_specs,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec(
-                (1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
-            ),
+        out_specs=[lay.spec(bq, n_q, _first), lay.row_spec(bq, _first)],
+        out_shape=[
+            _sds(lay.flat_shape(s_q), q.dtype, *ins),
+            _sds((b, n, s_q, 1), jnp.float32, *ins),
         ],
-        out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(q, k, v, bias_arg, mask_arg, segq_arg, segk_arg, seed_arg)
-    return o, lse[..., 0]  # lse [b, n, s_q]
+    )(*ins)
+    return o.reshape(o_shape), lse[..., 0]  # lse [b, n, s_q]
 
 
 def _compiler_params():
@@ -494,14 +718,12 @@ def _compiler_params():
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref, mask_ref,
-    segq_ref, segk_ref, seed_ref, dq_ref, *rest,
-    scale, causal, block_q, block_k, n_k, n_heads, have_bias, emit_dbias,
-    have_mask, have_segs, dropout_p,
+    segq_ref, segk_ref, seed_ref, dq_ref, *rest, t, n_k, emit_dbias,
 ):
     # with dbias: rest = (dbias_ref, acc_scr); without: rest = (acc_scr,)
     dbias_ref = rest[0] if emit_dbias else None
     acc_scr = rest[-1]
-    ib, ih = pl.program_id(0), pl.program_id(1)
+    ib, ip = pl.program_id(0), pl.program_id(1)
     iq, ik = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
@@ -511,74 +733,48 @@ def _bwd_dq_kernel(
     if emit_dbias:
         # each (iq, ik) block is visited exactly once; causal-skipped tiles
         # keep this zero fill
-        dbias_ref[0, 0] = jnp.zeros_like(dbias_ref[0, 0])
+        dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
-    def compute():
-        q = _scaled_q(q_ref, scale)
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if have_bias:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        qi, ki = _tile_indices(iq, ik, block_q, block_k)
-        s = _mask_scores(
-            s, qi, ki, causal=causal, have_mask=have_mask, mask_ref=mask_ref,
-            have_segs=have_segs, segq_ref=segq_ref, segk_ref=segk_ref,
-        )
-        lse = lse_ref[0, 0][:, :1]  # [bq, 1]
-        p = jnp.exp(s - lse)
-        if have_mask or have_segs or have_bias:
-            # fully-masked rows have lse = -inf (see _fwd_kernel; a -1e30
-            # folded-mask bias counts); without them the -1e30 scores
-            # underflow exp to 0 already
-            p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
-        do = do_ref[0, 0]
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0],
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if dropout_p > 0.0:
-            bh = ib * n_heads + ih
-            keep = _keep_mask(seed_ref[0], bh, qi, ki, dropout_p)
-            dp = dp * keep * (1.0 / (1.0 - dropout_p))
-        delta = delta_ref[0, 0][:, :1]
-        ds = p * (dp - delta)
+    def head(j):
+        lanes, _, s, qi, ki = _head_scores(
+            t, j, iq, ik, q_ref, k_ref, bias_ref, mask_ref, segq_ref,
+            segk_ref)
+        p = _probs(t, s, lse_ref[j])
+        dp = _dot(_only(do_ref[...], lanes), v_ref[...], _NT)
+        keep = _keep_scaled(t, seed_ref, ib, ip, j, qi, ki)
+        if keep is not None:
+            dp = dp * keep
+        ds = p * (dp - delta_ref[j])
         if emit_dbias:
-            # d(logits): the bias enters the logits additively, so its grad
-            # is ds itself (per [bq, bk] tile; broadcast dims summed in XLA)
-            dbias_ref[0, 0] = ds.astype(dbias_ref.dtype)
-        acc_scr[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0, 0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+            # d(logits): the bias enters the logits additively, so its
+            # grad is ds itself (per [bq, bk] tile; broadcast dims summed
+            # in XLA)
+            dbias_ref[0, j] = ds.astype(dbias_ref.dtype)
+        part = _dot(ds.astype(k_ref.dtype), k_ref[...], _NN) * t.scale
+        acc_scr[...] += part if lanes is None else jnp.where(lanes, part, 0.0)
 
-    if causal:
-        @pl.when(ik * block_k <= iq * block_q + (block_q - 1))
+    if t.causal:
+        @pl.when(ik * t.block_k <= iq * t.block_q + (t.block_q - 1))
         def _():
-            compute()
+            _for_heads(t, head)
     else:
-        compute()
+        _for_heads(t, head)
 
     @pl.when(ik == n_k - 1)
     def _finish():
-        dq_ref[0, 0] = acc_scr[:].astype(dq_ref.dtype)
+        dq_ref[...] = acc_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref, mask_ref,
-    segq_ref, segk_ref, seed_ref, dk_ref, dv_ref, *rest,
-    scale, causal, block_q, block_k, n_q, n_heads, have_bias, have_mask,
-    have_segs, dropout_p, emit_dq=False,
+    segq_ref, segk_ref, seed_ref, dk_ref, dv_ref, *rest, t, n_q,
+    emit_dq=False,
 ):
     # with emit_dq (single-k-block fast path): rest = (dq_ref, dk_scr, dv_scr)
     # and delta_ref carries O itself (delta computed in-kernel)
     dq_ref = rest[0] if emit_dq else None
     dk_scr, dv_scr = rest[-2], rest[-1]
-    ib, ih = pl.program_id(0), pl.program_id(1)
+    ib, ip = pl.program_id(0), pl.program_id(1)
     ik, iq = pl.program_id(2), pl.program_id(3)
 
     @pl.when(iq == 0)
@@ -586,181 +782,88 @@ def _bwd_dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def compute():
-        # NB: dk accumulates dsT @ q_scaled directly — the chain-rule
-        # *scale rides in with _scaled_q
-        q = _scaled_q(q_ref, scale)
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, bk]
-        if have_bias:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        qi, ki = _tile_indices(iq, ik, block_q, block_k)
-        s = _mask_scores(
-            s, qi, ki, causal=causal, have_mask=have_mask, mask_ref=mask_ref,
-            have_segs=have_segs, segq_ref=segq_ref, segk_ref=segk_ref,
-        )
-        lse = lse_ref[0, 0][:, :1]
-        p = jnp.exp(s - lse)
-        if have_mask or have_segs or have_bias:
-            # same fully-masked-row guard rationale as the dq kernel
-            p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
-        do = do_ref[0, 0]
-        if dropout_p > 0.0:
-            bh = ib * n_heads + ih
-            keep = _keep_mask(seed_ref[0], bh, qi, ki, dropout_p)
-            inv = 1.0 / (1.0 - dropout_p)
-            p_d = p * keep * inv
-        else:
-            keep = None
-            p_d = p
-        # dv += p_d.T @ do
-        dv_scr[:] += jax.lax.dot_general(
-            p_d.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def head(j):
+        # q is scaled (the chain rule's *scale for dk rides in with it)
+        # and, like do below, zero outside the head's lanes: what the two
+        # accumulate lands on this head's lanes of dk and dv alone
+        lanes, q, s, qi, ki = _head_scores(
+            t, j, iq, ik, q_ref, k_ref, bias_ref, mask_ref, segq_ref,
+            segk_ref)
+        p = _probs(t, s, lse_ref[j])
+        do = _only(do_ref[...], lanes)
+        keep = _keep_scaled(t, seed_ref, ib, ip, j, qi, ki)
+        p_d = p if keep is None else p * keep
+        dv_scr[...] += _dot(p_d.astype(do.dtype), do, _TN)      # p_d.T @ do
+        dp = _dot(do, v_ref[...], _NT)
         if keep is not None:
-            dp = dp * keep * (1.0 / (1.0 - dropout_p))
+            dp = dp * keep
         if emit_dq:
             # delta_ref holds O: delta = rowsum(do * o) computed here, so
             # the XLA-side delta pass (+ its [.., 1] re-layout) disappears
             delta = jnp.sum(
-                do.astype(jnp.float32) * delta_ref[0, 0].astype(jnp.float32),
+                do.astype(jnp.float32) * delta_ref[...].astype(jnp.float32),
                 axis=1, keepdims=True,
             )
         else:
-            delta = delta_ref[0, 0][:, :1]
+            delta = delta_ref[j]
         ds = p * (dp - delta)  # [bq, bk]
-        # dk += ds.T @ q_scaled (the chain-rule *scale rode in with q)
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_scr[...] += _dot(ds.astype(q.dtype), q, _TN)         # ds.T @ q
         if emit_dq:
             # single-k-block fast path (n_k == 1): every iq block is
             # visited exactly once, so dq = ds @ k * scale is complete
             # here — the separate dq kernel (a second score recompute,
             # exp, and do@v.T) is skipped entirely
-            dq_ref[0, 0] = (jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale).astype(dq_ref.dtype)
+            _write_lanes(
+                dq_ref, lanes,
+                _dot(ds.astype(k_ref.dtype), k_ref[...], _NN) * t.scale)
 
-    if causal:
-        @pl.when(ik * block_k <= iq * block_q + (block_q - 1))
+    if t.causal:
+        @pl.when(ik * t.block_k <= iq * t.block_q + (t.block_q - 1))
         def _():
-            compute()
+            _for_heads(t, head)
     else:
-        compute()
+        _for_heads(t, head)
 
     @pl.when(iq == n_q - 1)
     def _finish():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _bwd(
     q, k, v, bias, kv_mask, seg_q, seg_k, seed, o, lse, do, scale, causal,
-    dropout_p, block_q, block_k, interpret, bias_grad,
+    dropout_p, block_q, block_k, interpret, bias_grad, kind="bnsd",
 ):
-    b, n, s_q, d = q.shape
-    s_k = k.shape[2]
-    bq = _pick_block(s_q, block_q)
-    bk = _pick_block(s_k, block_k)
-    have_bias = bias is not None
-    have_mask = kv_mask is not None
-    if not interpret:
-        # same lane-dim constraint as the forward (see _lane_block)
-        if seg_q is not None:
-            bq = _lane_block(s_q, bq)
-        if have_mask or have_bias or seg_k is not None:
-            bk = _lane_block(s_k, bk)
+    """``(dq, dk, dv, dbias or None)`` in the layouts of ``q``, ``k``, ``v``
+    (see :func:`_fwd`; ``"qkv"``: the one array's gradient, None, None;
+    ``o`` and ``do`` in the context's layout); ``dbias`` comes out full
+    ``[b, n, s_q, s_k]``."""
+    plan = _plan(q, k, v, bias, kv_mask, seg_q, seg_k, scale, causal,
+                 dropout_p, block_q, block_k, interpret, kind)
+    lay, parts, (s_q, s_k), (bq, bk) = (
+        plan.lay, plan.parts, plan.seqs, plan.blocks)
+    (q2, k2, v2), tile = plan.qkv, plan.tile
+    b, n, hp = lay.b, lay.n, lay.hp
     n_q, n_k = s_q // bq, s_k // bk
     # the dq kernel only emits the O(s^2) dbias buffer when the bias
     # actually needs a gradient (bias_grad=False: ALiBi slopes, folded
     # masks — constants whose cotangent would be discarded)
-    emit_dbias = have_bias and bias_grad
+    emit_dbias = bias is not None and bias_grad
     # single-k-block fast path decided early: it also computes delta
     # in-kernel from O, skipping the XLA delta pass entirely
     fuse_dq = n_k == 1 and not emit_dbias
 
-    if fuse_dq:
-        delta_b = None
-    else:
-        delta = jnp.sum(
-            do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-        )  # [b, n, s_q]
-        delta_b = delta[..., None]
     # row stats as lane-dim-1 buffers (tiny DMA per block; the same layout
     # trick as ops/layer_norm.py's per-row stat blocks)
     lse_b = lse[..., None]
-
-    mask_arg = (
-        kv_mask.astype(jnp.int8).reshape(b, 1, s_k)
-        if have_mask
-        else jnp.zeros((b, 1, 8), jnp.int8)
-    )
-    if (seg_q is None) != (seg_k is None):
-        raise ValueError("seg_q and seg_k must be provided together")
-    segq_arg, have_segs = _seg_args(seg_q, s_q)
-    segk_arg, _ = _seg_args(seg_k, s_k)
-    seed_arg = jnp.asarray([seed if seed is not None else 0], jnp.int32)
-
-    def mask_spec(kmajor):
-        if have_mask:
-            if kmajor:
-                return pl.BlockSpec((1, 1, bk), lambda ib, ih, ik, iq: (ib, 0, ik))
-            return pl.BlockSpec((1, 1, bk), lambda ib, ih, iq, ik: (ib, 0, ik))
-        return pl.BlockSpec((1, 1, 8), lambda ib, ih, i2, i3: (ib, 0, 0))
-
-    def segq_spec(kmajor):
-        nb = segq_arg.shape[0]
-        if have_segs:
-            if kmajor:
-                return pl.BlockSpec(
-                    (1, 1, bq),
-                    lambda ib, ih, ik, iq: (ib if nb > 1 else 0, 0, iq))
-            return pl.BlockSpec(
-                (1, 1, bq), lambda ib, ih, iq, ik: (ib if nb > 1 else 0, 0, iq))
-        return pl.BlockSpec((1, 1, 8), lambda ib, ih, i2, i3: (0, 0, 0))
-
-    def segk_spec(kmajor):
-        nb = segk_arg.shape[0]
-        if have_segs:
-            if kmajor:
-                return pl.BlockSpec(
-                    (1, 1, bk),
-                    lambda ib, ih, ik, iq: (ib if nb > 1 else 0, 0, ik))
-            return pl.BlockSpec(
-                (1, 1, bk), lambda ib, ih, iq, ik: (ib if nb > 1 else 0, 0, ik))
-        return pl.BlockSpec((1, 1, 8), lambda ib, ih, i2, i3: (0, 0, 0))
-
-    seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_spec = lambda im: pl.BlockSpec((1, 1, bq, d), im)
-    k_spec = lambda im: pl.BlockSpec((1, 1, bk, d), im)
-    row_spec = lambda im: pl.BlockSpec((1, 1, bq, 1), im)
-
-    bias_q, bias_spec_q, _ = _bias_args(bias, bq, bk, False)
-    bias_k, bias_spec_k, _ = _bias_args(bias, bq, bk, True)
-
-    _ins = (q, k, v, do, bias_q, mask_arg, segq_arg, segk_arg, seed_arg)
-    dq_out_specs = [q_spec(lambda ib, ih, iq, ik: (ib, ih, iq, 0))]
-    dq_out_shape = [_sds(q.shape, q.dtype, *_ins)]
-    if emit_dbias:
-        # dbias comes out FULL [b, n, s_q, s_k] (each grid step owns one
-        # (iq, ik) tile); broadcast input dims are reduced by the caller.
-        # O(s^2) memory, but only on backward and only when the bias itself
-        # is an input that needs a gradient — the same cost torch autograd
-        # pays for an expanded bias in the reference openfold kernels.
-        dq_out_specs.append(pl.BlockSpec(
-            (1, 1, bq, bk), lambda ib, ih, iq, ik: (ib, ih, iq, ik)))
-        dq_out_shape.append(_sds((b, n, s_q, s_k), jnp.float32, *_ins))
+    if fuse_dq:
+        delta_b = None
+    else:
+        delta_b = lay.row_stat(jnp.sum(
+            do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
+        ))[..., None]  # [b, n, s_q, 1]
+    do2 = lay.flat(do)
+    dtype = q.dtype
 
     # single-k-block fast path: with n_k == 1 every (iq) block is visited
     # exactly once by the dkv kernel, so dq = ds @ k completes in the same
@@ -769,92 +872,93 @@ def _bwd(
     # path (its tile ownership is laid out (iq, ik)).
     dbias_full = None
     if not fuse_dq:
+        side_args, side_specs = _side_inputs(
+            plan, bias, kv_mask, seg_q, seg_k, seed, False)
+        ins = (q2, k2, v2, do2, lse_b, delta_b) + side_args
+        out_specs = [lay.spec(bq, n_q, _first)]
+        out_shape = [_sds(lay.flat_shape(s_q), dtype, *ins)]
+        if emit_dbias:
+            # dbias comes out FULL [b, n, s_q, s_k] (each grid step owns
+            # one (iq, ik) tile of its heads); broadcast input dims are
+            # reduced by the caller. O(s^2) memory, but only on backward
+            # and only when the bias itself is an input that needs a
+            # gradient — the same cost torch autograd pays for an expanded
+            # bias in the reference openfold kernels.
+            out_specs.append(pl.BlockSpec(
+                (1, hp, bq, bk), lambda ib, ip, iq, ik: (ib, ip, iq, ik)))
+            out_shape.append(_sds((b, n, s_q, s_k), jnp.float32, *ins))
         dq_res = pl.pallas_call(
             functools.partial(
-                _bwd_dq_kernel,
-                scale=scale, causal=causal, block_q=bq, block_k=bk, n_k=n_k,
-                n_heads=n, have_bias=have_bias, emit_dbias=emit_dbias,
-                have_mask=have_mask, have_segs=have_segs, dropout_p=dropout_p,
-            ),
+                _bwd_dq_kernel, t=tile, n_k=n_k,
+                emit_dbias=emit_dbias),
             name="apex_tpu_flash_bwd_dq",
-            grid=(b, n, n_q, n_k),
+            grid=(b, n // hp, n_q, n_k),
             in_specs=[
-                q_spec(lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-                k_spec(lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-                k_spec(lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-                q_spec(lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-                row_spec(lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-                row_spec(lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-                bias_spec_q,
-                mask_spec(False),
-                segq_spec(False),
-                segk_spec(False),
-                seed_spec,
+                lay.spec(bq, n_q, _first, parts[0]),
+                lay.spec(bk, n_k, _second, parts[1]),
+                lay.spec(bk, n_k, _second, parts[2]),
+                lay.spec(bq, n_q, _first),
+                lay.row_spec(bq, _first),
+                lay.row_spec(bq, _first),
+                *side_specs,
             ],
-            out_specs=dq_out_specs if emit_dbias else dq_out_specs[0],
-            out_shape=dq_out_shape if emit_dbias else dq_out_shape[0],
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            out_specs=out_specs if emit_dbias else out_specs[0],
+            out_shape=out_shape if emit_dbias else out_shape[0],
+            scratch_shapes=[pltpu.VMEM((bq, hp * lay.d), jnp.float32)],
             compiler_params=_compiler_params(),
             interpret=interpret,
-        )(q, k, v, do, lse_b, delta_b, bias_q, mask_arg, segq_arg, segk_arg,
-          seed_arg)
+        )(*ins)
         if emit_dbias:
             dq, dbias_full = dq_res
         else:
             dq = dq_res
 
-    dkv_out_specs = [
-        k_spec(lambda ib, ih, ik, iq: (ib, ih, ik, 0)),
-        k_spec(lambda ib, ih, ik, iq: (ib, ih, ik, 0)),
-    ]
-    dkv_out_shape = [
-        _sds(k.shape, k.dtype, *_ins),
-        _sds(v.shape, v.dtype, *_ins),
-    ]
+    side_args, side_specs = _side_inputs(
+        plan, bias, kv_mask, seg_q, seg_k, seed, True)
+    # fused path: the delta slot carries O (delta computed in-kernel);
+    # generic path: the precomputed row deltas
+    ins = (q2, k2, v2, do2, lse_b,
+           lay.flat(o) if fuse_dq else delta_b) + side_args
+    out_specs = [lay.spec(bk, n_k, _first), lay.spec(bk, n_k, _first)]
+    out_shape = [_sds(lay.flat_shape(s_k), dtype, *ins)] * 2
     if fuse_dq:
-        dkv_out_specs.append(q_spec(lambda ib, ih, ik, iq: (ib, ih, iq, 0)))
-        dkv_out_shape.append(_sds(q.shape, q.dtype, *_ins))
-
+        out_specs.append(lay.spec(bq, n_q, _second))
+        out_shape.append(_sds(lay.flat_shape(s_q), dtype, *ins))
     dkv_res = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel,
-            scale=scale, causal=causal, block_q=bq, block_k=bk, n_q=n_q,
-            n_heads=n, have_bias=have_bias, have_mask=have_mask,
-            have_segs=have_segs, dropout_p=dropout_p, emit_dq=fuse_dq,
-        ),
+            _bwd_dkv_kernel, t=tile, n_q=n_q,
+            emit_dq=fuse_dq),
         name="apex_tpu_flash_bwd_dkv",
-        grid=(b, n, n_k, n_q),
+        grid=(b, n // hp, n_k, n_q),
         in_specs=[
-            q_spec(lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-            k_spec(lambda ib, ih, ik, iq: (ib, ih, ik, 0)),
-            k_spec(lambda ib, ih, ik, iq: (ib, ih, ik, 0)),
-            q_spec(lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-            row_spec(lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-            # fused path: the delta slot carries O (delta computed
-            # in-kernel); generic path: the precomputed row deltas
-            q_spec(lambda ib, ih, ik, iq: (ib, ih, iq, 0)) if fuse_dq
-            else row_spec(lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-            bias_spec_k,
-            mask_spec(True),
-            segq_spec(True),
-            segk_spec(True),
-            seed_spec,
+            lay.spec(bq, n_q, _second, parts[0]),
+            lay.spec(bk, n_k, _first, parts[1]),
+            lay.spec(bk, n_k, _first, parts[2]),
+            lay.spec(bq, n_q, _second),
+            lay.row_spec(bq, _second),
+            lay.spec(bq, n_q, _second) if fuse_dq
+            else lay.row_spec(bq, _second),
+            *side_specs,
         ],
-        out_specs=dkv_out_specs,
-        out_shape=dkv_out_shape,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, hp * lay.d), jnp.float32),
+            pltpu.VMEM((bk, hp * lay.d), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(q, k, v, do, lse_b, o if fuse_dq else delta_b, bias_k, mask_arg,
-      segq_arg, segk_arg, seed_arg)
+    )(*ins)
     if fuse_dq:
         dk, dv, dq = dkv_res
     else:
         dk, dv = dkv_res
-    return dq, dk, dv, dbias_full
+    if kind == "qkv":
+        # the one array's gradient: the three side by side, as its views lie
+        dqkv = jnp.concatenate([dq, dk, dv], axis=-1).reshape(q.shape)
+        return dqkv, None, None, dbias_full
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dbias_full)
 
 
 # ---------------------------------------------------------------------------
@@ -878,28 +982,30 @@ def _name_residuals(o, lse):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14)
+    jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15)
 )
 def _flash(q, k, v, bias, kv_mask, segs, seed, scale, causal, dropout_p,
-           block_q, block_k, interpret, bias_grad=True, bwd_blocks=None):
+           block_q, block_k, interpret, bias_grad=True, bwd_blocks=None,
+           kind="bnsd"):
     seg_q, seg_k = segs if segs is not None else (None, None)
     o, _ = _fwd(q, k, v, bias, kv_mask, seg_q, seg_k, seed, scale, causal,
-                dropout_p, block_q, block_k, interpret)
+                dropout_p, block_q, block_k, interpret, kind)
     return o
 
 
 def _flash_fwd(q, k, v, bias, kv_mask, segs, seed, scale, causal, dropout_p,
-               block_q, block_k, interpret, bias_grad=True, bwd_blocks=None):
+               block_q, block_k, interpret, bias_grad=True, bwd_blocks=None,
+               kind="bnsd"):
     seg_q, seg_k = segs if segs is not None else (None, None)
     o, lse = _name_residuals(*_fwd(
         q, k, v, bias, kv_mask, seg_q, seg_k, seed, scale, causal, dropout_p,
-        block_q, block_k, interpret,
+        block_q, block_k, interpret, kind,
     ))
     return o, (q, k, v, bias, kv_mask, segs, seed, o, lse)
 
 
 def _flash_bwd(scale, causal, dropout_p, block_q, block_k, interpret,
-               bias_grad, bwd_blocks, res, do):
+               bias_grad, bwd_blocks, kind, res, do):
     q, k, v, bias, kv_mask, segs, seed, o, lse = res
     seg_q, seg_k = segs if segs is not None else (None, None)
     if bwd_blocks is not None:
@@ -909,7 +1015,7 @@ def _flash_bwd(scale, causal, dropout_p, block_q, block_k, interpret,
         block_q, block_k = bwd_blocks
     dq, dk, dv, dbias_full = _bwd(
         q, k, v, bias, kv_mask, seg_q, seg_k, seed, o, lse, do, scale,
-        causal, dropout_p, block_q, block_k, interpret, bias_grad,
+        causal, dropout_p, block_q, block_k, interpret, bias_grad, kind,
     )
     dbias = None
     if bias is not None:
@@ -988,7 +1094,7 @@ def _band_mask(s, qi, ki, window):
 
 
 def _band_scores(q_ref, k_ref, iq, ik, *, scale, window, block_q, block_k):
-    q = _scaled_q(q_ref, scale)
+    q = _scaled_q(q_ref[0, 0], scale)
     s = jax.lax.dot_general(
         q, k_ref[0, 0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -1295,11 +1401,29 @@ def flash_attention(
     if window is not None or k.shape[1] != q.shape[1]:
         return _banded(q, k, v, causal, window, kv_mask, bias, scale,
                        dropout_p, block_q, block_k, interpret)
+    return _dense(
+        q, k, v, "bnsd", causal=causal, kv_mask=kv_mask, bias=bias,
+        bias_grad=bias_grad, scale=scale, dropout_p=dropout_p,
+        dropout_seed=dropout_seed, block_q=block_q, block_k=block_k,
+        bwd_block_q=bwd_block_q, bwd_block_k=bwd_block_k,
+        interpret=interpret)
+
+
+def _dense(q, k, v, kind, *, causal=False, kv_mask=None, bias=None,
+           bias_grad=True, scale=None, dropout_p=0.0, dropout_seed=None,
+           block_q=1024, block_k=1024, bwd_block_q=None, bwd_block_k=None,
+           interpret=False):
+    """The checks of the dense kernels, and their call: ``q``, ``k``, ``v``
+    in the layout ``kind`` names (``_Layout``); the context comes back
+    head-major for ``"bnsd"``, else batch-major ``[b, s, n, d]``."""
+    lay = _Layout.of(q, kind)
+    s_q, s_k = lay.seq(q), lay.seq(q if k is None else k)
+    if scale is None:
+        scale = 1.0 / (lay.d ** 0.5)
     if kv_mask is not None:
         kv_mask = kv_mask.astype(jnp.int8)
     if bias is not None:
-        b, n, s_q = q.shape[0], q.shape[1], q.shape[2]
-        s_k = k.shape[2]
+        b, n = lay.b, lay.n
         if (bias.ndim != 4 or bias.shape[0] not in (1, b)
                 or bias.shape[1] not in (1, n)
                 or bias.shape[2] not in (1, s_q)
@@ -1309,23 +1433,24 @@ def flash_attention(
                 f"[{b}|1, {n}|1, {s_q}|1, {s_k}]"
             )
         # a [1024, 1024] fp32 score tile + bias tile + dbias tile would
-        # crowd VMEM; cap blocks at 512 when a bias is present
-        block_q = min(block_q, 512)
-        block_k = min(block_k, 512)
+        # crowd VMEM; cap blocks at 512 when a bias is present (256 where
+        # a block holds the bias and dbias tiles of several heads)
+        cap = 512 if lay.hp == 1 else 256
+        block_q = min(block_q, cap)
+        block_k = min(block_k, cap)
         if bwd_block_q is not None:
-            bwd_block_q = min(bwd_block_q, 512)
+            bwd_block_q = min(bwd_block_q, cap)
         if bwd_block_k is not None:
-            bwd_block_k = min(bwd_block_k, 512)
+            bwd_block_k = min(bwd_block_k, cap)
     if bwd_block_q is None and bwd_block_k is None:
-        bwd_blocks = _bwd_block_table(
-            q.shape[2], k.shape[2], q.shape[3], block_q, block_k)
+        bwd_blocks = _bwd_block_table(s_q, s_k, lay.d, block_q, block_k)
     else:
         bwd_blocks = (bwd_block_q or block_q, bwd_block_k or block_k)
     seed = _resolve_seed(dropout_p, dropout_seed)
     # kernel dots run in the operand dtype (MXU-native); normalise mixed
     # inputs to q's dtype so e.g. (fp32 q, bf16 k/v) still compiles
-    k = k.astype(q.dtype)
-    v = v.astype(q.dtype)
+    if k is not None:
+        k, v = k.astype(q.dtype), v.astype(q.dtype)
     # off-TPU the kernel runs in the Pallas interpreter (tests exercise the
     # same code path the TPU compiles)
     if not interpret and jax.default_backend() != "tpu":
@@ -1333,7 +1458,7 @@ def flash_attention(
     return _flash(
         q, k, v, bias, kv_mask, None, seed, float(scale), bool(causal),
         float(dropout_p), int(block_q), int(block_k), bool(interpret),
-        bool(bias_grad), tuple(int(x) for x in bwd_blocks),
+        bool(bias_grad), tuple(int(x) for x in bwd_blocks), kind,
     )
 
 
@@ -1365,18 +1490,77 @@ def _banded(q, k, v, causal, window, kv_mask, bias, scale, dropout_p,
         bool(interpret))
 
 
+@jax.named_scope("apex_tpu.flash_attention")
+def flash_attention_bshd(
+    q: jax.Array,  # [b, s_q, n, d]
+    k: jax.Array,  # [b, s_k, n, d]
+    v: jax.Array,
+    **kw,
+) -> jax.Array:
+    """:func:`flash_attention` over batch-major ``[b, s, n, d]``, the
+    array a projection over ``[b, s, hidden]`` writes once its last axis
+    is seen as ``[n, d]``: context ``[b, s, n, d]``, as an output
+    projection reads it.
+
+    The dense kernels read these arrays where they lie: no transpose, copy
+    or split of q, k, v, the context or their gradients, in forward,
+    backward or replay. A block is ``heads_per_block(n, d)`` heads of one
+    batch row side by side in 128 lanes (two at ``d = 64``); a kernel
+    zeroes the other heads' lanes of q (of ``do``) before a product that
+    contracts over the lanes, and keeps its own lanes of one that writes
+    them.
+
+    Where the heads do not cut into 128-lane blocks (``heads_per_block``
+    is 0: one head of 64 on a tensor-parallel rank, ``d`` of 96), and for
+    a ``window`` or grouped K/V heads (the banded kernels are head-major),
+    the arguments are transposed to ``[b, n, s, d]`` and the context back,
+    by XLA."""
+    n, d = q.shape[2], q.shape[3]
+    window = kw.pop("window", None)
+    if window is not None or k.shape[2] != n or not heads_per_block(n, d):
+        o = flash_attention(
+            *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), window=window, **kw)
+        return jnp.swapaxes(o, 1, 2)
+    return _dense(q, k, v, "bshd", **kw)
+
+
+@jax.named_scope("apex_tpu.flash_attention")
+def flash_attention_qkv(qkv: jax.Array, **kw) -> jax.Array:
+    """Self-attention over ONE array ``[b, s, 3, n, d]``: what a fused
+    q / k / v projection over ``[b, s, hidden]`` writes when the rows of
+    its weight run ``[(q, k, v), head, d]``. Context ``[b, s, n, d]``.
+
+    The kernels take the array three times under three index maps (q, k
+    and v of a block of heads lie ``n x d`` lanes apart): nothing is split
+    or copied in forward or replay, and one GEMM serves all three. In
+    backward the kernels write ``dq``, ``dk`` and ``dv`` and XLA puts them
+    side by side as the array's gradient, the one copy this entry point
+    costs. Otherwise as :func:`flash_attention_bshd`, its fallback
+    included (the three parts are then sliced out first)."""
+    n, d = qkv.shape[3], qkv.shape[4]
+    if kw.get("window") is not None or not heads_per_block(n, d):
+        return flash_attention_bshd(*(qkv[:, :, i] for i in range(3)), **kw)
+    kw.pop("window", None)
+    return _dense(qkv, None, None, "qkv", **kw)
+
+
 def flash_attention_sbhd(
     q: jax.Array,  # [s, b, n, d]
     k: jax.Array,
     v: jax.Array,
     **kw,
 ) -> jax.Array:
-    """Megatron ``[s, b, n, d]`` layout wrapper → context [s, b, n, d]."""
-    qt = jnp.transpose(q, (1, 2, 0, 3))
-    kt = jnp.transpose(k, (1, 2, 0, 3))
-    vt = jnp.transpose(v, (1, 2, 0, 3))
-    o = flash_attention(qt, kt, vt, **kw)
-    return jnp.transpose(o, (2, 0, 1, 3))
+    """Megatron ``[s, b, n, d]`` in, context ``[s, b, n, d]`` out, through
+    :func:`flash_attention_bshd`: the first two axes of each argument and
+    of the context are swapped by XLA (rows of ``n x d`` lanes move whole;
+    no head is cut out of its row), which a caller that holds ``[b, s,
+    hidden]`` avoids by calling ``flash_attention_bshd`` itself
+    (``parallel_attention`` does). Where that function falls back to the
+    head-major kernels the two moves compose into one transpose to
+    ``[b, n, s, d]``."""
+    o = flash_attention_bshd(
+        *(jnp.swapaxes(x, 0, 1) for x in (q, k, v)), **kw)
+    return jnp.swapaxes(o, 0, 1)
 
 
 def segment_ids_from_cu_seqlens(cu_seqlens: jax.Array, total: int) -> jax.Array:
@@ -1419,17 +1603,21 @@ def flash_attention_varlen(
     segs = segment_ids_from_cu_seqlens(cu_seqlens, total)
     k = k.astype(q.dtype)
     v = v.astype(q.dtype)
-    qb = q.transpose(1, 0, 2)[None]  # [1, n, total, d]
-    kb = k.transpose(1, 0, 2)[None]
-    vb = v.transpose(1, 0, 2)[None]
+    # packed tokens are batch-major with one batch row: [1, total, n, d].
+    # Where the heads do not cut into 128-lane blocks: head-major.
+    kind = "bshd" if heads_per_block(n, d) else "bnsd"
+    if kind == "bshd":
+        qb, kb, vb = q[None], k[None], v[None]
+    else:
+        qb, kb, vb = (x.transpose(1, 0, 2)[None] for x in (q, k, v))
     if not interpret and jax.default_backend() != "tpu":
         interpret = True
     o = _flash(
         qb, kb, vb, None, None, (segs, segs), seed, float(scale),
         bool(causal), float(dropout_p), int(block_q), int(block_k),
-        bool(interpret),
+        bool(interpret), True, None, kind,
     )
-    return o[0].transpose(1, 0, 2)  # [total, n, d]
+    return o[0] if kind == "bshd" else o[0].transpose(1, 0, 2)
 
 
 def masked_scores(q, k, kv_mask, causal, scale, bias=None,

@@ -47,7 +47,9 @@ from ...ops.flash_attention import (
     FLASH_RESIDUAL_NAMES,
     flash_attention,
     flash_attention_available,
+    flash_attention_qkv,
     flash_attention_sbhd,
+    heads_per_block,
     mha_reference,
 )
 from ...ops.fused_block import (
@@ -468,6 +470,58 @@ def _fp8_dense(cfg, fp8, name, x, w, b):
     return y, new_state
 
 
+def _use_flash(cfg: GPTConfig, s: int, hn: int, compatible: bool) -> bool:
+    """Whether ``parallel_attention`` takes the flash kernels. They replace
+    the materialised ``[b, np, sq, sk]`` scores when applicable: no traced
+    per-layer scaling, and a mask expressible as causal or key-padding
+    (``[b, 1, 1, sk]``-broadcast): ``compatible``. Attention dropout runs
+    IN-KERNEL (hash counters, the reference fmha's Philox analogue) so
+    dropout > 0 does not re-materialise ``[s, s]`` probabilities. In
+    causal mode any provided mask is ignored on every path — parity with
+    the reference's upper-triangular kernel, which takes no mask."""
+    if cfg.use_flash_attention is None:
+        return compatible and flash_attention_available(s, s, hn)
+    if not cfg.use_flash_attention:
+        return False
+    if not compatible:
+        raise ValueError(
+            "use_flash_attention=True but the configuration is not "
+            "flash-compatible (traced qk scaling or a non-causal/"
+            "non-padding mask)"
+        )
+    # the TPU-tileability rule of flash_attention_available, checked on
+    # every backend so a forced-on config fails loudly in CPU tests rather
+    # than at TPU compile time
+    from ...ops.flash_attention import require_kernel_tileable
+
+    require_kernel_tileable(s, hn, "use_flash_attention=True")
+    return True
+
+
+def _flash_batch_major(cfg: GPTConfig, lp, hidden, flash_kw, fuse_tail):
+    """The flash path of ``parallel_attention`` on one device, batch-major
+    inside (see there): ``hidden [s, b, h]`` in, the projected context
+    ``[s, b, h]`` out."""
+    s, b, h = hidden.shape
+    n, hn = cfg.num_attention_heads, cfg.kv_channels
+    dt = hidden.dtype
+    # qkv_w keeps Megatron's row order (the checkpoint's), [head, (q, k, v),
+    # hn]; the GEMM takes its rows as [(q, k, v), head, hn], so that q, k
+    # and v of a pair of heads are each 128 whole lanes of what it writes
+    w = jnp.swapaxes(lp["qkv_w"].astype(dt).reshape(n, 3, hn, h), 0, 1)
+    bias = jnp.swapaxes(lp["qkv_b"].astype(dt).reshape(n, 3, hn), 0, 1)
+    qkv = (jnp.einsum("bsh,oh->bso", jnp.swapaxes(hidden, 0, 1),
+                      w.reshape(3 * n * hn, h))
+           + bias.reshape(3 * n * hn))
+    ctx = flash_attention_qkv(
+        qkv.reshape(b, s, 3, n, hn), **flash_kw).astype(dt)
+    out = jnp.einsum("bso,ho->sbh", ctx.reshape(b, s, n * hn),
+                     lp["proj_w"].astype(dt))
+    if not fuse_tail:
+        out = out + lp["proj_b"].astype(dt)
+    return out
+
+
 @jax.named_scope("apex_tpu.attention")
 def parallel_attention(
     cfg: GPTConfig,
@@ -485,12 +539,70 @@ def parallel_attention(
     ``standalone_transformer_lm.py:210-400``): column-parallel fused QKV,
     head-parallel scaled-masked softmax, row-parallel output projection.
 
+    Who moves data. On the flash path without tensor parallelism or fp8,
+    where the heads cut into 128-lane blocks (``heads_per_block``: pairs
+    of 64, heads of 128), the block runs batch-major inside
+    (``_flash_batch_major``): ``hidden`` is swapped to ``[b, s, h]``, ONE
+    GEMM writes ``[b, s, (q, k, v), head, hn]`` (the rows of ``qkv_w``,
+    kept in Megatron's ``[head, (q, k, v), hn]`` order, are reordered in
+    the weight, not in the activation), the flash kernels read q, k and v
+    as three views of that array and the output projection reads the
+    context where the kernels wrote it (``flash_attention_qkv``), and its
+    result is swapped back to ``[s, b, h]``. XLA moves: the two swaps of
+    ``[s, b, h]`` (rows moved whole, fused into the norm before and the
+    tail after where it can) and, in backward, ``dq``, ``dk``, ``dv`` put
+    side by side as the GEMM's output gradient; no transpose or split of
+    q, k, v or the context. Elsewhere the fused projection's ``[s, b,
+    heads, 3 x hn]`` is split and the kernels are reached through
+    ``flash_attention_sbhd``, which swaps and, where heads do not pair,
+    transposes (ring attention and the XLA scores take their own layouts
+    from the split).
+
     ``fuse_tail=True`` returns the projection WITHOUT ``proj_b`` — the
     caller fuses the bias into the block tail (fused_block path)."""
     s, b, _ = hidden.shape
     tp = cfg.tensor_model_parallel_size if axis_name is not None else 1
     np_local = cfg.num_attention_heads // tp
     hn = cfg.kv_channels
+
+    # fp16 query-key layer scaling (reference coeff trick): divide scores
+    # by the 1-based layer number before any fp16 cast and multiply back
+    # inside the fp32 softmax, so deep-layer fp16 scores cannot overflow
+    qk_scaling = (
+        cfg.apply_query_key_layer_scaling
+        and cfg.compute_dtype == jnp.float16
+        and layer_number is not None
+    )
+    causal = cfg.attn_mask_type == AttnMaskType.causal
+    kv_mask = None
+    mask_ok = causal
+    if (
+        not causal
+        and attention_mask is not None
+        and attention_mask.ndim == 4
+        and attention_mask.shape[1] == 1
+        and attention_mask.shape[2] == 1
+    ):
+        kv_mask = attention_mask[:, 0, 0, :] == 0  # True = attend
+        mask_ok = True
+    attn_dropout_p = (
+        0.0 if deterministic or dropout_key is None
+        else float(cfg.attention_dropout)
+    )
+    flash_kw = dict(causal=causal, kv_mask=kv_mask, scale=1.0 / (hn ** 0.5))
+    if attn_dropout_p > 0.0:
+        # int32 seed derived from the step's dropout key: the kernel
+        # regenerates the identical mask in backward from this counter
+        flash_kw.update(
+            dropout_p=attn_dropout_p,
+            dropout_seed=jax.random.randint(
+                dropout_key, (), -(2 ** 31), 2 ** 31 - 1, jnp.int32))
+
+    if (fp8 is None and axis_name is None
+            and cfg.context_parallel_axis is None
+            and heads_per_block(np_local, hn)
+            and _use_flash(cfg, s, hn, not qk_scaling and mask_ok)):
+        return _flash_batch_major(cfg, lp, hidden, flash_kw, fuse_tail)
 
     new_fp8 = {}
     if fp8 is not None and axis_name is not None:
@@ -523,15 +635,6 @@ def parallel_attention(
     s = qkv.shape[0]
     qkv = qkv.reshape(s, b, np_local, 3 * hn)
     q, kk, vv = jnp.split(qkv, 3, axis=-1)  # [s, b, np, hn]
-
-    # fp16 query-key layer scaling (reference coeff trick): divide scores
-    # by the 1-based layer number before any fp16 cast and multiply back
-    # inside the fp32 softmax, so deep-layer fp16 scores cannot overflow
-    qk_scaling = (
-        cfg.apply_query_key_layer_scaling
-        and cfg.compute_dtype == jnp.float16
-        and layer_number is not None
-    )
 
     # --- context-parallel path (ring attention over the cp axis) --------
     if cfg.context_parallel_axis is not None:
@@ -592,67 +695,8 @@ def parallel_attention(
                               fuse_tail)
 
     # --- flash attention path (Pallas, O(s) memory) ---------------------
-    # Replaces the materialised-[b,np,sq,sk] scores below when applicable:
-    # no traced per-layer scaling, and a mask expressible as causal or
-    # key-padding ([b,1,1,sk]-broadcast). Attention dropout runs IN-KERNEL
-    # (hash counters, the reference fmha's Philox analogue) so dropout > 0
-    # no longer re-materialises [s,s] probabilities.
-    # In causal mode any provided mask is ignored on every path — parity
-    # with the reference's upper-triangular kernel, which takes no mask.
-    causal = cfg.attn_mask_type == AttnMaskType.causal
-    kv_mask = None
-    mask_ok = causal
-    if (
-        not causal
-        and attention_mask is not None
-        and attention_mask.ndim == 4
-        and attention_mask.shape[1] == 1
-        and attention_mask.shape[2] == 1
-    ):
-        kv_mask = attention_mask[:, 0, 0, :] == 0  # True = attend
-        mask_ok = True
-    attn_dropout_p = (
-        0.0 if deterministic or dropout_key is None
-        else float(cfg.attention_dropout)
-    )
-    flash_compatible = not qk_scaling and mask_ok
-    if cfg.use_flash_attention is None:
-        use_flash = flash_compatible and flash_attention_available(s, s, hn)
-    elif cfg.use_flash_attention:
-        if not flash_compatible:
-            raise ValueError(
-                "use_flash_attention=True but the configuration is not "
-                "flash-compatible (traced qk scaling or a non-causal/"
-                "non-padding mask)"
-            )
-        # the TPU-tileability rule of flash_attention_available, checked
-        # on every backend so a forced-on config fails loudly in CPU
-        # tests rather than at TPU compile time
-        from ...ops.flash_attention import require_kernel_tileable
-
-        require_kernel_tileable(s, hn, "use_flash_attention=True")
-        use_flash = True
-    else:
-        use_flash = False
-
-    if use_flash:
-        flash_kw = {}
-        if attn_dropout_p > 0.0:
-            # int32 seed derived from the step's dropout key: the kernel
-            # regenerates the identical mask in backward from this counter
-            flash_kw = dict(
-                dropout_p=attn_dropout_p,
-                dropout_seed=jax.random.randint(
-                    dropout_key, (), -(2 ** 31), 2 ** 31 - 1, jnp.int32
-                ),
-            )
-        ctx = flash_attention_sbhd(
-            q, kk, vv,
-            causal=causal,
-            kv_mask=kv_mask,
-            scale=1.0 / (hn ** 0.5),
-            **flash_kw,
-        ).astype(hidden.dtype)
+    if _use_flash(cfg, s, hn, not qk_scaling and mask_ok):
+        ctx = flash_attention_sbhd(q, kk, vv, **flash_kw).astype(hidden.dtype)
         ctx = ctx.reshape(s, b, np_local * hn)
     else:
         norm_factor = hn ** 0.5
@@ -887,8 +931,12 @@ def attention_by_kind(cfg: GPTConfig, kind: LayerKind, lp, x: jax.Array):
     h]``: separate q / k / v projections with ``kv_heads`` K/V heads,
     optionally RMSNorm over each head of q and k, rotary positions, a
     window, and a sigmoid gate on the context before the output
-    projection. Heads come off the projections as ``[b, n, s, d]``, the
-    layout the flash kernels read."""
+    projection. Heads come off the projections head-major, ``[b, n, s,
+    d]`` (``einsum "sbh,ndh->bnsd"``), the layout the banded flash kernels
+    read (a window or grouped K/V heads; ``flash_attention``): XLA moves
+    the data, a transpose behind each projection GEMM and one before the
+    output projection. The dense kernels' batch-major layout
+    (``parallel_attention``) is not taken here."""
     s, b, h = x.shape
     n, nkv, d = cfg.num_attention_heads, cfg.kv_heads, cfg.kv_channels
     q = _linear(lp, "q", x, "sbh,ndh->bnsd", (n, d, h))

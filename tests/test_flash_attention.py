@@ -593,3 +593,164 @@ def test_the_banded_path_refuses_what_it_does_not_compute():
                         jnp.zeros((1, 3, 32, 16)), causal=True)
     with pytest.raises(ValueError, match="at least 1"):
         flash_attention(q, q, q, causal=True, window=0)
+
+
+# ---------------------------------------------------------------------------
+# the batch-major layout: the kernels read [b, s, n x d] where a projection
+# wrote it, hp heads side by side in a 128-lane block
+# ---------------------------------------------------------------------------
+from apex_tpu.ops.flash_attention import (  # noqa: E402
+    flash_attention_bshd,
+    flash_attention_qkv,
+    flash_attention_sbhd,
+    heads_per_block,
+)
+
+
+def _bnsd(x):
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _bshd_case(key, n, d, b=2, s=64):
+    ks = jax.random.split(key, 4)
+    q, k, v, w = (jax.random.normal(kk, (b, s, n, d), jnp.float32)
+                  for kk in ks)
+    return q, k, v, w
+
+
+def test_heads_per_block():
+    assert heads_per_block(16, 64) == 2 and heads_per_block(8, 32) == 4
+    assert heads_per_block(32, 128) == 1 and heads_per_block(2, 256) == 1
+    # one head of 64 on a tensor-parallel rank, an odd count, a head size
+    # that is no divisor or multiple of 128: the head-major route
+    assert heads_per_block(1, 64) == 0 and heads_per_block(3, 64) == 0
+    assert heads_per_block(8, 96) == 0 and heads_per_block(8, 16) == 0
+
+
+@pytest.mark.parametrize("block", [16, 64])     # two kernels / the fused one
+@pytest.mark.parametrize("causal,masked",
+                         [(True, False), (False, True), (False, False)])
+@pytest.mark.parametrize("n,d", [(4, 64), (2, 128), (4, 32)])
+def test_flash_bshd_matches_reference(n, d, causal, masked, block):
+    """Outputs and all three gradients of the batch-major entry point
+    against the materialised reference: two heads of 64 (four of 32) share
+    a 128-lane block, a head of 128 has its own."""
+    q, k, v, w = _bshd_case(jax.random.PRNGKey(n * d + block), n, d)
+    kv_mask = None
+    if masked:
+        kv_mask = jnp.arange(64)[None, :] < jnp.array([64, 40])[:, None]
+
+    def flash(q, k, v):
+        return jnp.sum(w * flash_attention_bshd(
+            q, k, v, causal=causal, kv_mask=kv_mask, block_q=block,
+            block_k=block))
+
+    def ref(q, k, v):
+        return jnp.sum(w * _bnsd(mha_reference(
+            _bnsd(q), _bnsd(k), _bnsd(v), causal=causal, kv_mask=kv_mask)))
+
+    got, g_got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    want, g_want = jax.value_and_grad(ref, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
+    for a, b, name in zip(g_got, g_want, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("bias_shape", [(2, 4, 64, 64), (1, 1, 64, 64),
+                                        (1, 4, 1, 64)])
+def test_flash_bshd_bias_and_dropout(bias_shape):
+    """A bias block holds the tiles of the heads of a q block (or one,
+    broadcast over heads), dbias comes out by head, and the dropout
+    counters stay ``batch x heads + head``: the reference's mask."""
+    q, k, v, w = _bshd_case(jax.random.PRNGKey(5), 4, 64)
+    bias = jax.random.normal(jax.random.PRNGKey(6), bias_shape, jnp.float32)
+    kw = dict(causal=True, dropout_p=0.2, dropout_seed=21)
+
+    def flash(q, k, v, bias):
+        return jnp.sum(w * flash_attention_bshd(
+            q, k, v, bias=bias, block_q=32, block_k=32, **kw))
+
+    def ref(q, k, v, bias):
+        return jnp.sum(w * _bnsd(mha_reference(
+            _bnsd(q), _bnsd(k), _bnsd(v), bias=bias, **kw)))
+
+    got = jax.grad(flash, (0, 1, 2, 3))(q, k, v, bias)
+    want = jax.grad(ref, (0, 1, 2, 3))(q, k, v, bias)
+    for a, b, name in zip(got, want, ("q", "k", "v", "bias")):
+        np.testing.assert_allclose(a, b, atol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("n,d", [(4, 64), (2, 128), (3, 64)])
+def test_flash_sbhd_matches_reference(n, d):
+    """Megatron's ``[s, b, n, d]`` through the batch-major kernels; three
+    heads of 64 do not pair and take the head-major route."""
+    q, k, v, w = (jnp.swapaxes(x, 0, 1)
+                  for x in _bshd_case(jax.random.PRNGKey(7), n, d))
+    to_bnsd = lambda x: jnp.transpose(x, (1, 2, 0, 3))
+
+    def flash(q, k, v):
+        return jnp.sum(w * flash_attention_sbhd(q, k, v, causal=True))
+
+    def ref(q, k, v):
+        o = mha_reference(to_bnsd(q), to_bnsd(k), to_bnsd(v), causal=True)
+        return jnp.sum(w * jnp.transpose(o, (2, 0, 1, 3)))
+
+    got, g_got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    want, g_want = jax.value_and_grad(ref, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@pytest.mark.parametrize("n,d,block", [(4, 64, 16), (4, 64, 64),
+                                       (2, 128, 32), (3, 64, 64)])
+def test_flash_qkv_matches_reference(n, d, block):
+    """q, k and v as three views of one ``[b, s, 3, n, d]`` array (what a
+    fused projection writes): the context and the array's gradient against
+    the reference over its three parts; three heads of 64 do not pair and
+    are sliced out for the head-major route."""
+    qkv = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 3, n, d))
+    w = jax.random.normal(jax.random.PRNGKey(10), (2, 64, n, d))
+    mask = jnp.arange(64)[None, :] < jnp.array([64, 40])[:, None]
+
+    def flash(x):
+        return jnp.sum(w * flash_attention_qkv(
+            x, kv_mask=mask, block_q=block, block_k=block))
+
+    def ref(x):
+        return jnp.sum(w * _bnsd(mha_reference(
+            *(_bnsd(x[:, :, i]) for i in range(3)), kv_mask=mask)))
+
+    got, g_got = jax.value_and_grad(flash)(qkv)
+    want, g_want = jax.value_and_grad(ref)(qkv)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
+    np.testing.assert_allclose(g_got, g_want, atol=5e-5)
+
+
+def test_flash_bshd_moves_nothing():
+    """Between the arguments and the kernel, and between the kernel and
+    the result, the batch-major entry point only reshapes: no transpose,
+    slice or concatenation of q, k, v or the context, forward or backward."""
+    q, k, v, w = _bshd_case(jax.random.PRNGKey(8), 4, 64)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(w * flash_attention_bshd(
+            q, k, v, causal=True)), (0, 1, 2)))(q, k, v)
+    kernels, prims = set(), set()
+
+    def walk(jp):       # every equation outside the kernels' own bodies
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                kernels.add(eqn.params["name"])
+                continue
+            if any(getattr(x.aval, "size", 0) >= q.size for x in eqn.invars):
+                prims.add(eqn.primitive.name)   # reads a whole activation
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert kernels == {"apex_tpu_flash_fwd", "apex_tpu_flash_bwd_dkv"}
+    assert not prims & {"transpose", "concatenate", "slice", "gather",
+                        "dynamic_slice", "split", "copy"}, prims

@@ -368,3 +368,141 @@ def test_scanned_block_equals_block_by_kind_where_they_overlap(
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6,
             err_msg=jax.tree_util.keystr(path))
+
+
+# ---------------------------------------------------------------------------
+# the dense block's attention runs batch-major inside: the flash kernels read
+# q, k and v where the projection wrote them
+# ---------------------------------------------------------------------------
+def _attention_case(heads, padding):
+    from apex_tpu.transformer.enums import AttnMaskType
+    from apex_tpu.transformer.testing import standalone_transformer_lm as lm
+
+    cfg = _small_cfg(
+        num_layers=1, hidden_size=64 * heads, num_attention_heads=heads,
+        use_flash_attention=True,
+        attn_mask_type=(AttnMaskType.padding if padding
+                        else AttnMaskType.causal))
+    s, b = 32, 2
+    lp = jax.tree_util.tree_map(
+        lambda x: x[0], init_gpt_params(cfg, jax.random.PRNGKey(0))["layers"])
+    # biases start at zero: give every parameter of the block a gradient
+    lp = {k: v + 0.02 * jax.random.normal(jax.random.PRNGKey(i), v.shape)
+          for i, (k, v) in enumerate(sorted(lp.items()))}
+    hidden = jax.random.normal(jax.random.PRNGKey(1),
+                               (s, b, cfg.hidden_size), jnp.float32)
+    mask = None
+    if padding:    # nonzero = masked out, [b, 1, 1, s]
+        mask = (jnp.arange(s)[None, :] >= jnp.array([s, 20])[:, None]
+                )[:, None, None, :].astype(jnp.int32)
+    return lm, cfg, lp, hidden, mask
+
+
+def _split_path_attention(cfg, lp, hidden, mask):
+    """``parallel_attention`` as it was before the kernels read the
+    projection's output: one fused GEMM in Megatron's row order, its
+    ``[s, b, heads, 3 x hn]`` split and every part transposed to the
+    head-major kernels, the context transposed back."""
+    from apex_tpu.ops.flash_attention import flash_attention
+
+    s, b, h = hidden.shape
+    n, hn = cfg.num_attention_heads, cfg.kv_channels
+    qkv = jnp.einsum("sbh,oh->sbo", hidden, lp["qkv_w"]) + lp["qkv_b"]
+    q, k, v = (jnp.transpose(x, (1, 2, 0, 3))
+               for x in jnp.split(qkv.reshape(s, b, n, 3 * hn), 3, axis=-1))
+    ctx = flash_attention(
+        q, k, v, causal=mask is None,
+        kv_mask=None if mask is None else mask[:, 0, 0, :] == 0,
+        scale=1.0 / hn ** 0.5)
+    ctx = jnp.transpose(ctx, (2, 0, 1, 3)).reshape(s, b, h)
+    return jnp.einsum("sbo,ho->sbh", ctx, lp["proj_w"]) + lp["proj_b"]
+
+
+@pytest.mark.parametrize("heads,padding", [(4, False), (4, True), (3, False)])
+def test_parallel_attention_equals_split_path(heads, padding):
+    """Output and parameter gradients of the batch-major path (one GEMM over
+    the rows of ``qkv_w``, Megatron's ``[head, (q, k, v), hn]``, reordered
+    in the weight; q, k, v three views of what it writes) to the
+    split-and-transpose path's, to float32 rounding; three heads of 64 do
+    not pair and reach the head-major kernels."""
+    lm, cfg, lp, hidden, mask = _attention_case(heads, padding)
+    w = jax.random.normal(jax.random.PRNGKey(2), hidden.shape)
+
+    def new(lp, hidden):
+        return jnp.sum(w * lm.parallel_attention(
+            cfg, lp, hidden, mask, None, None, True))
+
+    def old(lp, hidden):
+        return jnp.sum(w * _split_path_attention(cfg, lp, hidden, mask))
+
+    got, g_got = jax.value_and_grad(new, (0, 1))(lp, hidden)
+    want, g_want = jax.value_and_grad(old, (0, 1))(lp, hidden)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name in ("qkv_w", "qkv_b", "proj_w", "proj_b"):
+        np.testing.assert_allclose(g_got[0][name], g_want[0][name],
+                                   rtol=1e-4, atol=1e-5, err_msg=name)
+    np.testing.assert_allclose(g_got[1], g_want[1], rtol=1e-4, atol=1e-5)
+
+
+def test_no_transpose_between_qkv_gemm_and_flash_kernel():
+    """The dense layer's trace: from each of q, k, v of the
+    ``apex_tpu_flash_fwd`` call back to the GEMM that wrote it, and from
+    the kernel's context on to the GEMM that reads it, nothing moves an
+    activation: reshapes and the bias add only."""
+    from jax.extend.core import Var
+
+    lm, cfg, lp, hidden, _ = _attention_case(4, False)
+    jaxpr = jax.make_jaxpr(lambda lp, x: lm.transformer_layer(
+        cfg, lp, x, None, None, None, True))(lp, hidden).jaxpr
+
+    producer, consumers, alias = {}, {}, {}
+
+    def resolve(v):
+        while isinstance(v, Var) and v in alias:
+            v = alias[v]
+        return v
+
+    def walk(jp):   # sub-jaxprs inlined; a kernel's own body left alone
+        for eqn in jp.eqns:
+            subs = [getattr(p, "jaxpr", p) for p in eqn.params.values()
+                    if hasattr(getattr(p, "jaxpr", p), "eqns")]
+            if eqn.primitive.name != "pallas_call" and subs:
+                sub = subs[0]
+                for inner, outer in zip(sub.invars, eqn.invars):
+                    alias[inner] = outer
+                walk(sub)
+                for outer, inner in zip(eqn.outvars, sub.outvars):
+                    alias[outer] = inner
+                continue
+            ins = [resolve(v) for v in eqn.invars]
+            for v in eqn.outvars:
+                producer[v] = (eqn, ins)
+            for v in ins:
+                if isinstance(v, Var):
+                    consumers.setdefault(v, []).append(eqn)
+
+    walk(jaxpr)
+    kernel, ins = next(
+        (e, i) for e, i in producer.values()
+        if e.primitive.name == "pallas_call"
+        and e.params["name"] == "apex_tpu_flash_fwd")
+    still = {"reshape", "add", "convert_element_type", "name"}
+    for v in ins[:3]:                       # q, k, v back to their GEMMs
+        seen = []
+        while True:
+            eqn, srcs = producer[resolve(v)]
+            seen.append(eqn.primitive.name)
+            if eqn.primitive.name == "dot_general":
+                break
+            assert eqn.primitive.name in still, seen
+            v = max(srcs, key=lambda x: getattr(x.aval, "size", 0))
+        assert seen[-1] == "dot_general" and "transpose" not in seen
+    v, seen = kernel.outvars[0], []         # the context on to its GEMM
+    while True:
+        eqn = consumers[v][0]
+        seen.append(eqn.primitive.name)
+        if eqn.primitive.name == "dot_general":
+            break
+        assert eqn.primitive.name in still, seen
+        v = eqn.outvars[0]
+    assert "transpose" not in seen
