@@ -1,49 +1,52 @@
-"""Fused transformer-block *tail* kernels: the elementwise/data-movement
-answer to the round-5 step-time profile.
+"""Fused transformer-block *tail* operations: bias + GeLU, bias + dropout +
+residual, and the attention tail with the next sublayer's LayerNorm.
 
-BENCH_r05's op breakdown of the headline GPT step books 42.7% of device
-time to ``fusion(elementwise)`` and 17.7% to ``data-movement`` — 3x the
-matmuls. XLA emits the block tail (bias add, GeLU, dropout, residual
-add, the next sublayer's LayerNorm) as a parade of separate elementwise
-fusions plus convert/copy traffic, each sweeping the ``[s, b, h]``
-activations through HBM again. This module is the TPU-native analogue of
-Apex's signature fused epilogues — ``csrc/fused_dense_cuda``'s
+The TPU-native analogue of Apex's fused epilogues — ``csrc/fused_dense_cuda``'s
 GEMM+bias+GeLU, ``csrc/fused_layer_norm_cuda``, and Megatron's
-``bias_dropout_add`` fusion — collapsing each tail into a single HBM
-sweep:
+``bias_dropout_add`` — as three operations, each computed in float32 inside
+and rounded once to the I/O dtype at every tensor it writes:
 
 - :func:`bias_gelu`              ``gelu(x + bias)`` — the MLP
   up-projection epilogue (reference ``fused_dense_cuda``'s
-  ``bias_gelu``/``bgradb`` kernel pair). Matches
-  ``jax.nn.gelu(approximate=True)`` bitwise on the XLA fallback path.
+  ``bias_gelu``/``bgradb`` kernel pair). For float32 inputs bitwise
+  ``jax.nn.gelu(x + bias, approximate=True)`` on the XLA form.
 - :func:`bias_dropout_residual`  ``residual + dropout(x + bias)`` — the
-  Megatron ``bias_dropout_add`` fusion. Dropout is in-kernel
-  counter-hash dropout (the ``flash_attention.py`` pattern): the keep
-  mask is a murmur3 hash of ``(seed, row, col)``, bit-identical between
-  forward/backward and between kernel/fallback, so no ``[s, b, h]``
-  mask tensor ever exists.
+  Megatron ``bias_dropout_add`` fusion. Dropout is counter-hash dropout
+  (the ``flash_attention.py`` pattern): the keep mask is a murmur3 hash of
+  ``(seed, row, col)``, bit-identical between forward/backward and between
+  kernel and XLA form, so no ``[s, b, h]`` mask tensor is ever kept.
 - :func:`residual_add_layer_norm` ``sum = residual + dropout(x + bias);
-  y = LN(sum)`` — the attention-tail fusion: the next sublayer's pre-LN
-  reads the residual straight from VMEM instead of a second HBM round
-  trip. Returns BOTH ``sum`` (the onward residual stream) and ``y``.
+  y = LN(sum)`` — the attention tail. Returns BOTH ``sum`` (the onward
+  residual stream) and ``y``.
 
-Contract (the ``packed_optimizer.py``/``flash_decode.py`` selection
-contract): every op is a ``custom_vjp`` with a Pallas forward AND
-backward kernel, an XLA fallback computing identical math (auto-selected
-off-TPU; backward via ``jax.vjp`` of the fallback forward, so fallback
-grads are exactly the autodiff of the reference math), and
-``interpret=True`` runs the real kernel bodies on CPU for parity tests.
-Kernel selection is :func:`apex_tpu.ops.layer_norm._use_pallas` with
-``fused=True`` — ON by default on TPU (see that module's decision
-table; the plain-LN "XLA wins" default does NOT apply to these fused
-tails, whose roofline includes the sweeps XLA fails to fuse).
+Every op is a ``custom_vjp`` with two forms of the same arithmetic: a
+Pallas forward AND backward kernel over a ``(rows, n)`` view, and an XLA
+form over ``[..., n]`` as it lies (backward via ``jax.vjp`` of the forward,
+so its gradients are the autodiff of the reference math). Which one a call
+lowers to is :func:`apex_tpu.ops.layer_norm._use_pallas`, one gate for
+these and the plain norms: **on a TPU the XLA form, by what the chip
+said** (PR 34; the table is in ``docs/fused_block.md``). GPT-2 345M on one
+v5e, 8 x 1024 tokens a step: 36,389 tokens/s with the three kernels,
+43,432 with XLA's own fusions of the same arithmetic (step 225.1 -> 188.6
+ms), and each kernel loses on its own against a step whose other two tails
+are XLA's: XLA folds a tail into the GEMMs either side of it, a kernel
+stands between them as three sweeps and, seeing ``[s, b, n]`` as ``(rows,
+n)``, pays a copy each way where 8 rows of bf16 batch do not fill a tile.
+The kernels were on by default until then on the strength of
+``BENCH_r05.json``'s 42.7% elementwise share, a profile older than the
+packed optimizer, the flash layouts and selective recomputation.
+``interpret=True`` runs the kernel bodies on the CPU (parity tests, the
+benchmark's rehearsal, ``tools/static_audit.py``);
+``APEX_TPU_FORCE_PALLAS_LN`` compiles them, for whoever asks the chip
+again; :func:`fused_block_available` says which form a compiled call gets.
 
 All public entry points run under an ``apex_tpu.fused_block`` named
 scope (analysis rule 6: xplane breakdowns must attribute kernel time),
 and the forward kernels carry stable names
 (``apex_tpu_bias_gelu_fwd`` etc.) so name-matching remat policies — the
 ``recompute_granularity="selective_elementwise"`` policy in
-``standalone_transformer_lm.py`` — can pin their outputs as saveable.
+``standalone_transformer_lm.py`` — can pin their outputs as saveable
+where they are in the trace.
 """
 from __future__ import annotations
 
@@ -103,6 +106,17 @@ def _tile_keep(seed, i, br, n, dropout_p):
     rowg = i * br + jax.lax.broadcasted_iota(jnp.int32, (br, n), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (br, n), 1)
     return _keep_mask(seed, jnp.int32(0), rowg, col, dropout_p)
+
+
+def _keep_as_shaped(seed, shape, dropout_p):
+    """The same mask over ``[..., n]`` as it lies: the row counter is the
+    row-major index over the leading dims, so no ``(rows, n)`` view (a
+    copy where the leading dims do not fill the tiles) is taken."""
+    row = jnp.zeros(shape, jnp.int32)
+    for d, size in enumerate(shape[:-1]):
+        row = row * size + jax.lax.broadcasted_iota(jnp.int32, shape, d)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return _keep_mask(seed, jnp.int32(0), row, col, dropout_p)
 
 
 def dropout_mask_reference(seed, rows: int, n: int,
@@ -173,9 +187,11 @@ def _bias_gelu_bwd_kernel(dy_ref, x_ref, b_ref, dx_ref, db_ref):
 
 
 def _bias_gelu_fallback(x, bias):
-    # the reference epilogue verbatim — bitwise parity with the unfused
-    # model path is the fallback's contract
-    return jax.nn.gelu(x + bias.astype(x.dtype), approximate=True)
+    """The kernel's arithmetic as XLA ops: bias add and GeLU in fp32, one
+    rounding to the output dtype (for fp32 inputs bitwise the unfused
+    ``jax.nn.gelu(x + bias, approximate=True)``)."""
+    xb = x.astype(jnp.float32) + bias.astype(jnp.float32)
+    return jax.nn.gelu(xb, approximate=True).astype(x.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -185,9 +201,9 @@ def _bias_gelu(x, bias, interpret):
 
 
 def _bias_gelu_fwd(x, bias, interpret):
-    x2, shape = _flat2d(x)
-    rows, n = x2.shape
-    if _use_pallas(n, interpret, fused=True):
+    if _use_pallas(x.shape[-1], interpret):
+        x2, shape = _flat2d(x)
+        rows, n = x2.shape
         br = _row_block(rows, n)
         with _kernel_scope():
             y2 = pl.pallas_call(
@@ -205,9 +221,9 @@ def _bias_gelu_fwd(x, bias, interpret):
 
 def _bias_gelu_bwd(interpret, res, dy):
     x, bias = res
-    x2, shape = _flat2d(x)
-    rows, n = x2.shape
-    if _use_pallas(n, interpret, fused=True):
+    if _use_pallas(x.shape[-1], interpret):
+        x2, shape = _flat2d(x)
+        rows, n = x2.shape
         br = _row_block(rows, n)
         dy2, _ = _flat2d(dy)
         with _kernel_scope():
@@ -238,9 +254,9 @@ def bias_gelu(x: jax.Array, bias: jax.Array, *,
     """Fused ``gelu(x + bias, approximate=True)`` over the trailing dim.
 
     The MLP up-projection epilogue (reference ``fused_dense_cuda``
-    GEMM+bias+GeLU): call the projection with ``bias=None`` and fuse the
-    bias here, one HBM sweep for bias add + GeLU instead of two XLA
-    elementwise fusions. ``bias`` is 1-D ``[n]``.
+    GEMM+bias+GeLU): call the projection with ``bias=None`` and add the
+    bias here, in float32 with one rounding; on a TPU XLA makes it the
+    GEMM's own epilogue. ``bias`` is 1-D ``[n]``.
     """
     if bias.ndim != 1 or bias.shape[0] != x.shape[-1]:
         raise ValueError(
@@ -284,9 +300,7 @@ def _bdr_fallback(x, bias, residual, seed, dropout_p):
     same counters, one rounding to the output dtype."""
     xb = x.astype(jnp.float32) + bias.astype(jnp.float32)
     if dropout_p > 0.0:
-        x2, _ = _flat2d(xb)
-        keep = _tile_keep(seed, jnp.int32(0), x2.shape[0], x2.shape[1],
-                          dropout_p).reshape(xb.shape)
+        keep = _keep_as_shaped(seed, xb.shape, dropout_p)
         xb = xb * keep * (1.0 / (1.0 - dropout_p))
     return (residual.astype(jnp.float32) + xb).astype(residual.dtype)
 
@@ -298,9 +312,9 @@ def _bias_dropout_residual(x, bias, residual, seed, dropout_p, interpret):
 
 
 def _bdr_fwd(x, bias, residual, seed, dropout_p, interpret):
-    x2, shape = _flat2d(x)
-    rows, n = x2.shape
-    if _use_pallas(n, interpret, fused=True):
+    if _use_pallas(x.shape[-1], interpret):
+        x2, shape = _flat2d(x)
+        rows, n = x2.shape
         br = _row_block(rows, n)
         r2, _ = _flat2d(residual)
         with _kernel_scope():
@@ -374,7 +388,7 @@ def bias_dropout_residual(
     ``seed`` in forward, backward, kernel and fallback alike — no mask
     tensor is ever materialised, and a fixed seed reproduces the exact
     mask everywhere. With ``dropout_p == 0`` this is a pure
-    bias+residual fusion (still one sweep).
+    bias+residual add.
     """
     if bias.ndim != 1 or bias.shape[0] != x.shape[-1]:
         raise ValueError(
@@ -469,9 +483,9 @@ def _residual_add_layer_norm(x, bias, residual, w, lb, seed, eps,
 
 
 def _raln_fwd(x, bias, residual, w, lb, seed, eps, dropout_p, interpret):
-    x2, shape = _flat2d(x)
-    rows, n = x2.shape
-    if _use_pallas(n, interpret, fused=True):
+    if _use_pallas(x.shape[-1], interpret):
+        x2, shape = _flat2d(x)
+        rows, n = x2.shape
         br = _row_block(rows, n)
         stat = pl.BlockSpec((br, 1), lambda i: (i, 0))
         r2, _ = _flat2d(residual)
@@ -562,10 +576,8 @@ def residual_add_layer_norm(
     """Fused ``sum = residual + dropout(x + bias); y = LayerNorm(sum)``.
 
     Returns ``(sum, y)``: ``sum`` is the onward residual stream (stored
-    once, in the residual dtype), ``y`` the next sublayer's pre-LN input
-    — computed while the residual is still resident in VMEM, so the tail
-    costs one HBM sweep instead of bias-add + dropout + add + LN each
-    re-reading ``[s, b, h]``. LN stats are fp32 per row over the ROUNDED
+    once, in the residual dtype), ``y`` the next sublayer's pre-LN input.
+    LN stats are fp32 per row over the ROUNDED
     sum, matching the unfused ``astype(dt) -> layer_norm(f32)`` chain.
     """
     if bias.ndim != 1 or bias.shape[0] != x.shape[-1]:
@@ -581,6 +593,7 @@ def residual_add_layer_norm(
 
 
 def fused_block_available(n: int) -> bool:
-    """Whether the kernel path would engage for trailing dim ``n`` on
-    this backend (the bench/docs introspection hook)."""
-    return _use_pallas(n, False, fused=True)
+    """Whether a compiled call would engage the kernels for trailing dim
+    ``n`` on this backend (on a TPU: only when forced; the introspection
+    hook ``chip_smoke.py``'s kernel inventory reads)."""
+    return _use_pallas(n, False)

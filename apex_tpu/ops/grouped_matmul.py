@@ -24,7 +24,7 @@ walks the ``(row tile, group)`` visits the sizes give, their count a traced
 value: a row tile two groups share is visited once for each and its rows
 masked by the group's range.
 
-Selection contract (``packed_optimizer.py`` / ``fused_block.py``): the
+Selection contract (``packed_optimizer.py``): the
 kernels on TPU; off the TPU the identical-math XLA fallback
 (``jax.lax.ragged_dot`` and its autodiff); ``interpret=True`` runs the
 kernel bodies under the Pallas interpreter for the CPU tests.

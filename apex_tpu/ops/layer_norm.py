@@ -20,36 +20,44 @@ functions used by ``apex_tpu.normalization`` — each with a
 normalized input in backward (reference ``apex/normalization/
 fused_layer_norm.py`` ``memory_efficient`` flag).
 
-Kernel-dispatch decision table (``_use_pallas``, also consulted by the
-fused-block tail kernels in ``ops/fused_block.py`` via ``fused=True``):
+Kernel-dispatch decision table (``_use_pallas``, one gate for the plain
+norms here and for the block tails of ``ops/fused_block.py``:
+``bias_gelu``, ``residual_add_layer_norm``, ``bias_dropout_residual``):
 
-===========================  =========================  ==================
-condition                    plain LN / RMSNorm         fused tails
-                                                        (residual+LN,
-                                                        bias_gelu, ...)
-===========================  =========================  ==================
-``APEX_TPU_DISABLE_PALLAS``  XLA fallback               XLA fallback
-``interpret=True``           Pallas interpreter         Pallas interpreter
-TPU, hidden % 128 == 0       XLA **by default** (XLA's  **Pallas by
-                             own LN fusion measured     default** — the
-                             ~4x faster on v5e;         fused tail
-                             ``APEX_TPU_FORCE_          replaces several
-                             PALLAS_LN`` overrides)     XLA sweeps XLA
-                                                        does NOT fuse
-                                                        (BENCH_r05: 42.7%
-                                                        elementwise +
-                                                        17.7% data
-                                                        movement), a
-                                                        different
-                                                        roofline from one
-                                                        row-normalisation
-non-TPU / ragged hidden      XLA fallback               XLA fallback
-===========================  =========================  ==================
+============================  ==========================================
+condition                     what runs
+============================  ==========================================
+``APEX_TPU_DISABLE_PALLAS``   XLA form
+``interpret=True``            the kernel body under the Pallas
+                              interpreter (parity tests, the benchmark's
+                              CPU rehearsal, ``tools/static_audit.py``)
+TPU, hidden % 128 == 0        XLA form **by default**; the compiled
+                              kernel only under
+                              ``APEX_TPU_FORCE_PALLAS_LN`` (the
+                              experimenter's override: how the A/B below
+                              is asked again on another chip)
+non-TPU / ragged hidden       XLA form
+============================  ==========================================
 
-The asymmetry is deliberate: losing to XLA on a *single* fused LN says
-nothing about a kernel that replaces bias-add + dropout + residual-add +
-LN round trips with one HBM sweep. Gating both on the same
-force-flag (the pre-PR-9 behaviour) silently disabled the fused path.
+What the chip said (TPU v5e, GPT-2 345M, 8 x 1024 tokens a step):
+
+- plain LayerNorm: XLA's own fusion is ~4x faster than the kernel at
+  transformer shapes (279 against 301 ms a step in-model).
+- the three block tails (the A/B of PR 34; ``docs/fused_block.md`` "What
+  the chip has said" has the table per operation and per cell):
+  36,389 tokens/s with the three kernels, 43,432 with XLA's form of all
+  three (+19.4%; step 225.1 -> 188.6 ms, the MLP scope 83.0 -> 55.1, the
+  compiled program 13.74 -> 11.69 GB). Each kernel loses on its own once
+  the other two are XLA's: ``residual_add_layer_norm`` kept as a kernel
+  reads 41,771, ``bias_dropout_residual`` 39,800; ``bias_gelu`` alone
+  handed to XLA is worth +7.7% of the kernels' rate. XLA fuses the tails
+  into the GEMMs either side and needs no ``(rows, n)`` view, which in
+  bf16 with 8 rows of batch is a copy before and after every kernel.
+  The tails were on by default until then on the strength of a profile
+  (``BENCH_r05.json``: 42.7% of device time in elementwise fusions) taken
+  before the packed optimizer, the flash layouts and selective
+  recomputation; the same record read that model at 177.4 ms a step with
+  unfused tails.
 """
 from __future__ import annotations
 
@@ -63,25 +71,19 @@ from jax.experimental import pallas as pl
 
 
 
-def _use_pallas(hidden: int, interpret: bool, *, fused: bool = False) -> bool:
-    """Kernel-dispatch gate, shared with ``ops/fused_block.py``
-    (``fused=True``). See the decision table in the module docstring:
-    the "XLA LN wins" default applies ONLY to the plain-LN path —
-    gating the fused residual+LN tail on the same flag would silently
-    disable a kernel with a different roofline."""
+def _use_pallas(hidden: int, interpret: bool) -> bool:
+    """Kernel-dispatch gate of the plain norms and, since the chip was
+    asked (decision table in the module docstring), of the block tails in
+    ``ops/fused_block.py`` alike: on a TPU the compiler's own fusions are
+    the faster form of all of them, so the kernel bodies run where
+    ``interpret=True`` asks for them and the compiled program holds XLA's
+    code unless ``APEX_TPU_FORCE_PALLAS_LN`` asks for the kernels."""
     if os.environ.get("APEX_TPU_DISABLE_PALLAS"):
         return False
     if interpret:
         return True
-    if not fused:
-        # Honest default: on v5e, XLA's fused LN beats this hand-written
-        # kernel by ~4x at transformer shapes (measured in-model: 279 vs
-        # 301 ms/step for GPT-2 345M) — row-normalisation is exactly the
-        # fusion XLA already does well. The Pallas kernel is kept for
-        # interpret-mode parity tests and for experimentation via
-        # APEX_TPU_FORCE_PALLAS_LN.
-        if not os.environ.get("APEX_TPU_FORCE_PALLAS_LN"):
-            return False
+    if not os.environ.get("APEX_TPU_FORCE_PALLAS_LN"):
+        return False
     return jax.default_backend() == "tpu" and hidden % 128 == 0
 
 

@@ -98,9 +98,10 @@ class GPTConfig:
     # runs the flash kernel, that kernel's output and row statistics (the
     # replay in backward holds no forward kernel); everything else is
     # recomputed. "selective_elementwise" additionally pins the
-    # fused-block tail kernel outputs as saveable, so backward replays
-    # only the cheap unfused elementwise remainder (pairs with
-    # fused_block=True; docs/fused_block.md has the decision table).
+    # fused-block tail kernel outputs as saveable where those kernels are
+    # in the trace (interpreted: tests, the rehearsal); a program compiled
+    # for a TPU holds XLA's form of the tails, and the mode keeps what
+    # "selective" keeps (docs/fused_block.md has the decision table).
     recompute_granularity: Optional[str] = None
     # Layer-scan unroll factor. 1 = one compiled layer body (fast compile,
     # the default for tests/virtual meshes); -1 = fully unrolled whatever
@@ -113,15 +114,17 @@ class GPTConfig:
     # True forces it (errors if inapplicable); False forces the XLA path.
     use_flash_attention: Optional[bool] = None
     # Fused transformer-block tail (ops/fused_block.py): the projection
-    # GEMMs run bias-free and the tails collapse into single sweeps —
-    # bias+GeLU on the MLP up-projection, bias+dropout+residual on the
-    # MLP output, bias+dropout+residual+LN on the attention output (the
-    # post-LN reads the residual straight from VMEM). Hidden dropout
-    # then uses counter-hash dropout (seeded from the step key) instead
-    # of bernoulli-from-key — same rate, different (deterministic)
-    # stream. fused_block_interpret runs the kernels under the Pallas
-    # interpreter (CPU parity tests; off-TPU without it the ops fall
-    # back to identical-math XLA).
+    # GEMMs run bias-free and each tail is one operation computed in
+    # float32 and rounded once — bias+GeLU on the MLP up-projection,
+    # bias+dropout+residual on the MLP output, bias+dropout+residual+LN
+    # on the attention output. What an operation lowers to is the
+    # library's choice (ops/layer_norm._use_pallas): on a TPU, XLA's own
+    # fusions into the neighbouring GEMMs, which the chip read faster
+    # than the Pallas kernels. Hidden dropout then uses counter-hash
+    # dropout (seeded from the step key) instead of bernoulli-from-key —
+    # same rate, different (deterministic) stream.
+    # fused_block_interpret runs the kernel bodies under the Pallas
+    # interpreter (CPU parity tests, the benchmark's rehearsal).
     fused_block: bool = False
     fused_block_interpret: bool = False
     # Context parallelism (long context): name of a mesh axis the SEQUENCE
@@ -812,10 +815,10 @@ def parallel_mlp(
     active, returns ``(out, new_fp8)``.
 
     ``fuse_tail=True`` is the fused-block MLP: fc1 runs bias-free and the
-    bias+GeLU epilogue is the :func:`apex_tpu.ops.bias_gelu` kernel (one
-    sweep over the [s, b, 4h] intermediate — the ``fused_dense_cuda``
-    GEMM+bias+GeLU shape); fc2 also runs bias-free and the caller fuses
-    ``fc2_b`` into the block-tail bias+dropout+residual sweep.
+    bias+GeLU epilogue is :func:`apex_tpu.ops.bias_gelu` (float32 inside,
+    one rounding — the ``fused_dense_cuda`` GEMM+bias+GeLU shape; on a TPU
+    XLA fuses it into the GEMMs either side); fc2 also runs bias-free and
+    the caller fuses ``fc2_b`` into the block-tail bias+dropout+residual.
     """
 
     def act(inter):
@@ -1039,7 +1042,7 @@ def transformer_layer(
     forward-only runs, the same restriction as the pipeline tick hooks).
 
     With ``cfg.fused_block`` the two sublayer tails run as the
-    ``ops/fused_block.py`` single-sweep kernels: the attention tail is
+    ``ops/fused_block.py`` operations: the attention tail is
     ``residual_add_layer_norm`` (proj bias + hidden dropout + residual
     add + the MLP's pre-LN, one sweep), the MLP tail is
     ``bias_dropout_residual``; the taps then observe the bias-free
@@ -1149,10 +1152,10 @@ def _policy_with_saveable_kernels(prim, kernels, *args, **kwargs):
 
 
 # the fused-block tail kernels' forward outputs are 'selective_elementwise'
-# saveable on top of the selective set: each is the collapsed form of the
-# exact elementwise chain the round-5 profile pays 42.7% for — storing the
-# single fused output means backward replays only the cheap UNFUSED
-# remainder (embedding adds, casts) instead of the whole layer tail
+# saveable on top of the selective set, where those kernels are in the
+# trace (interpreted). A program compiled for a TPU holds none (the gate
+# hands the tails to XLA, which replays them inside the backward GEMMs),
+# and this policy then keeps exactly what 'selective' keeps.
 _FUSED_BLOCK_SAVEABLE_KERNELS = frozenset({
     BIAS_GELU_FWD, BIAS_DROPOUT_RESIDUAL_FWD, RESIDUAL_LN_FWD,
 })
@@ -1162,7 +1165,7 @@ def _selective_elementwise_policy(prim, *args, **kwargs):
     """The fused-block remat policy: matmul/attention/norm outputs plus
     the fused tail-kernel outputs are saved; only unfused elementwise
     remains to replay. Pairs with ``GPTConfig.fused_block`` (without the
-    fused kernels in the trace it degrades to exactly 'selective')."""
+    fused kernels in the trace, as on a TPU, it is exactly 'selective')."""
     return _policy_with_saveable_kernels(
         prim, _SELECTIVE_SAVEABLE_KERNELS | _FUSED_BLOCK_SAVEABLE_KERNELS,
         *args, **kwargs)
@@ -1249,8 +1252,8 @@ def transformer_block(
     keeps matmul outputs and replays only the cheap elementwise/softmax work
     (the reference's ``--recompute-granularity selective``);
     ``"selective_elementwise"`` additionally keeps the fused-block tail
-    kernel outputs (pairs with ``cfg.fused_block`` — backward then replays
-    only the unfused elementwise remainder).
+    kernel outputs where those kernels are in the trace (interpreted); on a
+    TPU, where ``cfg.fused_block``'s tails are XLA's, it is ``"selective"``.
 
     With ``fp8_states``/``fp8_carriers`` the per-layer state slices ride
     the scan's xs and the rolled states come back as ys: returns

@@ -10,8 +10,9 @@ vocab 50304; random weights from a seed):
 
 - **trainer** — ``amp.initialize(O2)`` -> ``amp.scaled_value_and_grad`` ->
   ``FusedAdam(packed=True).step(found_inf=)`` -> ``scaler.update_scale`` on
-  the step of ``gpt2-345m.train-1chip`` (flash attention, fused block
-  tails with ``selective_elementwise`` recompute, chunk-fused LM-head CE),
+  the step of ``gpt2-345m.train-1chip`` (flash attention, the
+  ``fused_block`` tails with ``selective_elementwise`` recompute, which on
+  the chip lower to XLA's own fusions, chunk-fused LM-head CE),
   batch 8 x seq 1024: the loss falls over a few chained steps, and a step
   with an injected overflow leaves params untouched and halves the scale;
 - **flat scaler** — ``LossScaler.unscale_flat`` / ``found_inf_flat`` on a
@@ -52,7 +53,7 @@ import json
 import os
 import sys
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -195,13 +196,20 @@ def _all_finite(tree) -> bool:
 
 
 def require_kernels(smoke: Smoke, program: str, inventory,
-                    names: Sequence[str], *, interpret: bool) -> None:
+                    names: Sequence[str], *, interpret: bool,
+                    absent: Sequence[str] = ()) -> None:
     """Every name in ``names`` must be a ``pallas_call`` of the traced
     program, and none of them may run under the interpreter unless the
-    size asked for it."""
+    size asked for it; a name in ``absent`` (a kernel whose gate says it
+    does not engage here) must not be there at all."""
     seen = {}
     for rec in inventory:
         seen.setdefault(rec.name, []).append(rec.compiled)
+    unexpected = sorted(set(absent) & set(seen))
+    if unexpected:
+        raise SmokeFailure(
+            f"{program}: kernels in the traced program whose gate says "
+            f"they do not engage here: {unexpected}")
     missing = sorted(set(names) - set(seen))
     if missing:
         raise SmokeFailure(
@@ -221,14 +229,30 @@ def require_kernels(smoke: Smoke, program: str, inventory,
               + ", ".join(f"{n} x{len(seen[n])}" for n in sorted(names)))
 
 
-TRAIN_KERNELS = (
-    "apex_tpu_flash_fwd", "apex_tpu_flash_bwd_dkv",
+# the block-tail kernels of ops/fused_block.py: in the traced step where
+# their gate engages them (interpreted at toy width; on a chip the gate
+# hands the tails to XLA's own fusions, so there they must be absent)
+TAIL_KERNELS = (
     "apex_tpu_bias_gelu_fwd", "apex_tpu_bias_gelu_bwd",
     "apex_tpu_bias_dropout_residual_fwd",
     "apex_tpu_bias_dropout_residual_bwd",
     "apex_tpu_residual_ln_fwd", "apex_tpu_residual_ln_bwd",
-    "apex_tpu_packed_adam",
 )
+CHIP_TRAIN_KERNELS = (
+    "apex_tpu_flash_fwd", "apex_tpu_flash_bwd_dkv", "apex_tpu_packed_adam",
+)
+TRAIN_KERNELS = CHIP_TRAIN_KERNELS + TAIL_KERNELS
+
+
+def train_kernels(size: Size) -> Dict[str, Tuple[str, ...]]:
+    """``require_kernels``' ``names`` and ``absent`` for ``train_config``'s
+    step at this size: the tails are expected where
+    ``fused_block_available`` says their gate engages, else forbidden."""
+    from apex_tpu.ops import fused_block_available
+
+    if size.interpret or fused_block_available(size.hidden):
+        return {"names": TRAIN_KERNELS, "absent": ()}
+    return {"names": CHIP_TRAIN_KERNELS, "absent": TAIL_KERNELS}
 
 
 def _batch(size: Size, n_rows: int):
@@ -307,7 +331,7 @@ def train_leg(smoke: Smoke, size: Size) -> None:
     with smoke.section("train_trace"):
         traced = step.trace(params, opt_state, sstate, one)
         require_kernels(smoke, "train_step", kernel_inventory(traced.jaxpr),
-                        TRAIN_KERNELS, interpret=size.interpret)
+                        interpret=size.interpret, **train_kernels(size))
     with smoke.section("train_compile") as rec:
         compiled = traced.lower().compile()
         rec.update(_memory_analysis(compiled))
@@ -646,7 +670,7 @@ def dp_train_leg(smoke: Smoke, size: Size, n_dev: int) -> None:
         traced = step.trace(opt_state, sstate, tokens, labels)
         require_kernels(
             smoke, "dp_train_step", kernel_inventory(traced.jaxpr),
-            TRAIN_KERNELS, interpret=size.interpret)
+            interpret=size.interpret, **train_kernels(size))
         budget = ddp.collective_budget(buckets, extra_psums=1)
         findings = check_collective_budget(
             collective_inventory(traced.jaxpr.jaxpr), budget,

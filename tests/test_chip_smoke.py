@@ -7,6 +7,7 @@
   and the dense-reference check goes red on a wrong token;
 - the compile-cache rule (``apex_tpu.chip.use_compile_cache``).
 """
+import dataclasses
 import os
 import shutil
 import subprocess
@@ -150,3 +151,37 @@ def test_kernel_assertion_goes_red_when_a_gate_disables_kernels(
     with pytest.raises(chip_smoke.SmokeFailure, match="interpreted"):
         chip_smoke.require_kernels(smoke, "train_step", inventory,
                                    chip_smoke.TRAIN_KERNELS, interpret=False)
+    # a kernel the chip does run (the tails are XLA's there): its gate
+    # saying no is red on the chip's own list too
+    on_chip = chip_smoke.train_kernels(
+        dataclasses.replace(TOY, interpret=False))
+    assert "apex_tpu_flash_fwd" in on_chip["names"]
+    flashless = [r for r in inventory if r.name != "apex_tpu_flash_fwd"]
+    with pytest.raises(chip_smoke.SmokeFailure, match="apex_tpu_flash_fwd"):
+        chip_smoke.require_kernels(smoke, "train_step", flashless,
+                                   on_chip["names"], interpret=True)
+
+
+@pytest.mark.parametrize("interpret", [True, False])
+def test_train_kernels_follow_the_tails_gate(smoke, monkeypatch, interpret):
+    """What the smoke expects of the train step is what the gate engages:
+    interpreted, all nine; compiled for a TPU, flash and the packed sweep,
+    and a tail kernel in the trace is as red as a missing one."""
+    from apex_tpu.analysis import kernel_inventory
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    size = dataclasses.replace(TOY, interpret=interpret)
+    expected = chip_smoke.train_kernels(size)
+    if interpret:
+        assert expected == {"names": chip_smoke.TRAIN_KERNELS, "absent": ()}
+        return
+    assert expected == {"names": chip_smoke.CHIP_TRAIN_KERNELS,
+                        "absent": chip_smoke.TAIL_KERNELS}
+    assert set(expected["names"]) == {
+        "apex_tpu_flash_fwd", "apex_tpu_flash_bwd_dkv",
+        "apex_tpu_packed_adam"}
+    step, state = chip_smoke.train_program(TOY)  # interpreted: tails in it
+    inventory = kernel_inventory(step, *state, 1.0)
+    with pytest.raises(chip_smoke.SmokeFailure, match="do not engage"):
+        chip_smoke.require_kernels(smoke, "train_step", inventory,
+                                   interpret=True, **expected)

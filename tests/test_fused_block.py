@@ -233,6 +233,146 @@ def test_raln_dropout_deterministic():
 
 
 # ---------------------------------------------------------------------------
+# the gate, and the XLA form it hands a compiled program (PR 34)
+# ---------------------------------------------------------------------------
+
+def _op_call(op, dtype=jnp.float32, dropout_p=0.0, interpret=False):
+    """``(fn, args)``: one tail op as a function of the arguments it is
+    differentiated by."""
+    x, b, r = _data(dtype=dtype, key=20)
+    w = (jnp.ones((128,)) * 1.1).astype(dtype)
+    lb = jnp.full((128,), 0.2, dtype)
+    drop = dict(dropout_p=dropout_p, seed=11) if dropout_p else {}
+    if op == "bias_gelu":
+        return (lambda x, b: bias_gelu(x, b, interpret=interpret)), (x, b)
+    if op == "bias_dropout_residual":
+        return (lambda x, b, r: bias_dropout_residual(
+            x, b, r, interpret=interpret, **drop)), (x, b, r)
+    return (lambda x, b, r, w, lb: residual_add_layer_norm(
+        x, b, r, w, lb, interpret=interpret, **drop)), (x, b, r, w, lb)
+
+
+_OPS = ["bias_gelu", "bias_dropout_residual", "residual_add_layer_norm"]
+
+
+def _kernel_names(fn, args):
+    """Names of the pallas_calls in the forward-and-backward jaxpr."""
+    from apex_tpu.analysis import kernel_inventory
+
+    def scalar(*a):
+        out = fn(*a)
+        return sum(o.astype(jnp.float32).sum()
+                   for o in jax.tree_util.tree_leaves(out))
+
+    grad = jax.grad(scalar, argnums=tuple(range(len(args))))
+    return sorted(rec.name for rec in kernel_inventory(grad, *args))
+
+
+@pytest.mark.parametrize("op", _OPS)
+@pytest.mark.parametrize("mode", ["tpu", "tpu_interpret", "tpu_forced",
+                                  "tpu_disabled_interpret", "cpu"])
+def test_gate_table(monkeypatch, op, mode):
+    """``_use_pallas``' table, per operation: a program compiled for a TPU
+    holds XLA's form of every tail (what the chip said, PR 34), the kernel
+    bodies run where ``interpret=True`` asks, and ``fused_block_available``
+    says what engages."""
+    from apex_tpu.ops import fused_block_available
+
+    monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
+    monkeypatch.delenv("APEX_TPU_FORCE_PALLAS_LN", raising=False)
+    if mode != "cpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if mode == "tpu_forced":
+        monkeypatch.setenv("APEX_TPU_FORCE_PALLAS_LN", "1")
+    if mode == "tpu_disabled_interpret":
+        monkeypatch.setenv("APEX_TPU_DISABLE_PALLAS", "1")
+    interpret = mode.endswith("interpret")
+    fn, args = _op_call(op, interpret=interpret)
+    names = _kernel_names(fn, args)
+    stem = {"bias_gelu": "apex_tpu_bias_gelu",
+            "bias_dropout_residual": "apex_tpu_bias_dropout_residual",
+            "residual_add_layer_norm": "apex_tpu_residual_ln"}[op]
+    kernels = mode in ("tpu_interpret", "tpu_forced")
+    assert names == ([stem + "_bwd", stem + "_fwd"] if kernels else [])
+    # the introspection hook answers for a compiled (not interpreted) call
+    assert fused_block_available(128) is (mode == "tpu_forced")
+    assert fused_block_available(100) is False
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("op,dropout_p", [
+    ("bias_gelu", 0.0), ("bias_dropout_residual", 0.0),
+    ("bias_dropout_residual", 0.3), ("residual_add_layer_norm", 0.0),
+    ("residual_add_layer_norm", 0.3)])
+def test_xla_form_matches_interpreted_kernel(op, dropout_p, dtype):
+    """The form a compiled program holds against the kernel body: every
+    output and every gradient within one rounding of the I/O dtype (both
+    compute in float32 inside and round where the kernel writes)."""
+    outs, grads = {}, {}
+    for interpret in (False, True):
+        fn, args = _op_call(op, dtype, dropout_p, interpret)
+
+        def scalar(*a, fn=fn):
+            out = jax.tree_util.tree_leaves(fn(*a))
+            # weights that differ by output, so no gradient cancels
+            return sum(((i + 1) * o.astype(jnp.float32) ** 2).sum()
+                       for i, o in enumerate(out))
+
+        outs[interpret] = jax.tree_util.tree_leaves(fn(*args))
+        grads[interpret] = jax.grad(
+            scalar, argnums=tuple(range(len(args))))(*args)
+    eps = float(jnp.finfo(dtype).eps)
+
+    def close(a, c, what):
+        a, c = a.astype(jnp.float32), c.astype(jnp.float32)
+        assert a.shape == c.shape, what
+        # bf16: one rounding (both forms round a float32 result once);
+        # float32: the few ulps two orders of summation over 128 columns
+        # differ by. A reduced gradient (dbias, dgamma) sums 32 rows, each
+        # within that.
+        ulps = (1.0 if dtype == jnp.bfloat16 else 8.0) * (
+            32.0 if a.ndim == 1 else 1.0)
+        tol = ulps * eps * jnp.maximum(jnp.abs(c), 1.0)
+        assert bool(jnp.all(jnp.abs(a - c) <= tol)), what
+
+    for a, c in zip(outs[False], outs[True]):
+        assert a.dtype == c.dtype == dtype
+        close(a, c, f"{op} output")
+    for i, (a, c) in enumerate(zip(grads[False], grads[True])):
+        assert a.dtype == c.dtype
+        close(a, c, f"{op} gradient {i}")
+
+
+def test_bias_gelu_xla_form_computes_in_float32():
+    """bf16 in, bf16 out, float32 between: the XLA form is the float32
+    result rounded ONCE. Adding the bias and applying GeLU in bf16 (the
+    form before PR 34) rounds twice more and lands an ulp away on part of
+    any tensor; this input holds such elements."""
+    x, b, _ = _data(dtype=jnp.bfloat16, key=21)
+    once = jax.nn.gelu(x.astype(jnp.float32) + b.astype(jnp.float32),
+                       approximate=True).astype(jnp.bfloat16)
+    in_bf16 = jax.nn.gelu(x + b, approximate=True)
+    assert in_bf16.dtype == jnp.bfloat16
+    differ = np.asarray(once != in_bf16)
+    assert differ.any()  # the case exists in this input
+    y = bias_gelu(x, b)
+    assert y.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(y.astype(jnp.float32)),
+                                  np.asarray(once.astype(jnp.float32)))
+    # and so does the backward: dx is dy * gelu'(x + b) in float32,
+    # rounded once
+    dy = jnp.ones_like(x)
+    dx = jax.vjp(lambda x: bias_gelu(x, b), x)[1](dy)[0]
+    ref = jax.vjp(lambda xf: jax.nn.gelu(xf + b.astype(jnp.float32),
+                                         approximate=True),
+                  x.astype(jnp.float32))[1](dy.astype(jnp.float32))[0]
+    assert dx.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(dx.astype(jnp.float32)),
+        np.asarray(ref.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+# ---------------------------------------------------------------------------
 # model-level parity (GPTConfig.fused_block)
 # ---------------------------------------------------------------------------
 
@@ -358,6 +498,47 @@ def test_selective_elementwise_saves_fewer_residuals():
     b_sel = res_bytes(
         jax.checkpoint(layer, policy=_selective_elementwise_policy))
     assert b_full < b_sel < b_none
+
+
+def test_selective_elementwise_over_the_xla_form_saves_what_selective_saves(
+        monkeypatch):
+    """With no tail ``pallas_call`` in the trace (a program compiled for a
+    TPU since PR 34) ``selective_elementwise`` keeps exactly what
+    ``selective`` keeps: the GEMM outputs and the layer's input, no output
+    of a tail."""
+    saved_residuals = pytest.importorskip(
+        "jax._src.ad_checkpoint").saved_residuals
+    from apex_tpu.transformer.testing.standalone_transformer_lm import (
+        _selective_policy,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(_CFG, fused_block=True,
+                              use_flash_attention=False)
+    params = init_gpt_params(cfg, jax.random.PRNGKey(0))
+    lp = jax.tree_util.tree_map(lambda x: x[0], params["layers"])
+    h = jax.random.normal(jax.random.PRNGKey(3), (32, 2, 64))
+
+    def layer(lp, h):
+        return transformer_layer(cfg, lp, h, None, None, None, True)
+
+    def kept(policy):
+        res = saved_residuals(jax.checkpoint(layer, policy=policy), lp, h)
+        return sorted((tuple(aval.shape), str(aval.dtype), why)
+                      for aval, why in res if hasattr(aval, "shape"))
+
+    sel, sel_el = kept(_selective_policy), kept(_selective_elementwise_policy)
+    assert sel_el == sel
+    # the interpreted kernels' outputs are what the policy adds: the same
+    # layer with them in the trace keeps more
+    cfg_i = dataclasses.replace(cfg, fused_block_interpret=True)
+
+    def layer_i(lp, h):
+        return transformer_layer(cfg_i, lp, h, None, None, None, True)
+
+    res_i = saved_residuals(
+        jax.checkpoint(layer_i, policy=_selective_elementwise_policy), lp, h)
+    assert len([1 for aval, _ in res_i if hasattr(aval, "shape")]) > len(sel)
 
 
 # ---------------------------------------------------------------------------
