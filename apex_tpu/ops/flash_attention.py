@@ -1039,8 +1039,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
-# the banded path: causal attention with a sliding window and/or grouped
-# K/V heads. The grid walks a list of the score tiles that touch the band
+# the banded path: causal attention with a sliding window, grouped K/V
+# heads and/or a value width that is not the query/key width (the kernel
+# bodies read every width off their blocks). The grid walks a list of the
+# score tiles that touch the band
 # ``0 <= i - j < window`` (scalar-prefetched: tile t is q block ``qi[t]``
 # against k block ``ki[t]``), so a tile wholly outside the band costs no
 # grid step and no DMA, in the forward and in both backward kernels. Query
@@ -1212,6 +1214,7 @@ def _band_params():
 
 def _band_fwd(q, k, v, scale, window, block_q, block_k, interpret):
     b, n, s, d = q.shape
+    dv = v.shape[-1]            # the value's width, and the context's
     group = n // k.shape[1]
     bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
     lists = _tile_lists(band_tiles(s, bq, bk, window))
@@ -1225,13 +1228,13 @@ def _band_fwd(q, k, v, scale, window, block_q, block_k, interpret):
             num_scalar_prefetch=5, grid=(b, n, int(lists[0].shape[0])),
             in_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
                       pl.BlockSpec((1, 1, bk, d), k_map),
-                      pl.BlockSpec((1, 1, bk, d), k_map)],
-            out_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bk, dv), k_map)],
+            out_specs=[pl.BlockSpec((1, 1, bq, dv), q_map),
                        pl.BlockSpec((1, 1, bq, 1), q_map)],
             scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
                             pltpu.VMEM((bq, 128), jnp.float32),
-                            pltpu.VMEM((bq, d), jnp.float32)]),
-        out_shape=[_sds((b, n, s, d), q.dtype, q, k, v),
+                            pltpu.VMEM((bq, dv), jnp.float32)]),
+        out_shape=[_sds((b, n, s, dv), q.dtype, q, k, v),
                    _sds((b, n, s, 1), jnp.float32, q, k, v)],
         compiler_params=_band_params(),
         interpret=interpret,
@@ -1244,6 +1247,7 @@ def _band_fwd(q, k, v, scale, window, block_q, block_k, interpret):
 def _band_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
               interpret):
     b, n, s, d = q.shape
+    dv = v.shape[-1]
     n_kv = k.shape[1]
     group = n // n_kv
     bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
@@ -1263,8 +1267,8 @@ def _band_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
             num_scalar_prefetch=5, grid=(b, n, int(lists[0].shape[0])),
             in_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
                       pl.BlockSpec((1, 1, bk, d), k_map),
-                      pl.BlockSpec((1, 1, bk, d), k_map),
-                      pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bk, dv), k_map),
+                      pl.BlockSpec((1, 1, bq, dv), q_map),
                       pl.BlockSpec((1, 1, bq, 1), q_map),
                       pl.BlockSpec((1, 1, bq, 1), q_map)],
             out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
@@ -1285,14 +1289,14 @@ def _band_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
             num_scalar_prefetch=5, grid=(b, n_kv, int(lists[0].shape[0])),
             in_specs=[pl.BlockSpec((1, 1, bq, d), q_map),
                       pl.BlockSpec((1, 1, bk, d), k_map),
-                      pl.BlockSpec((1, 1, bk, d), k_map),
-                      pl.BlockSpec((1, 1, bq, d), q_map),
+                      pl.BlockSpec((1, 1, bk, dv), k_map),
+                      pl.BlockSpec((1, 1, bq, dv), q_map),
                       pl.BlockSpec((1, 1, bq, 1), q_map),
                       pl.BlockSpec((1, 1, bq, 1), q_map)],
             out_specs=[pl.BlockSpec((1, 1, bk, d), k_map),
-                       pl.BlockSpec((1, 1, bk, d), k_map)],
+                       pl.BlockSpec((1, 1, bk, dv), k_map)],
             scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32)]),
+                            pltpu.VMEM((bk, dv), jnp.float32)]),
         out_shape=[_sds(k.shape, k.dtype, q, k, v, do),
                    _sds(v.shape, v.dtype, q, k, v, do)],
         compiler_params=_band_params(),
@@ -1394,11 +1398,16 @@ def flash_attention(
     query head ``h`` reads head ``h // (n // n_kv)``; ``dk``/``dv`` summed
     over the group) take the banded kernels, which walk only the score
     tiles that touch the band: causal self-attention without bias, mask or
-    dropout.
+    dropout. So does a **value width of its own** (``v [b, n_kv, s, dv]``
+    with ``dv != d``, latent attention's 128 beside a query/key width of
+    192): scores contract over ``d``; the context, ``do`` and ``dv`` are
+    ``dv`` wide, ``dq`` and ``dk`` ``d`` wide, each block at its own width
+    (nothing is padded in HBM).
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    if window is not None or k.shape[1] != q.shape[1]:
+    if (window is not None or k.shape[1] != q.shape[1]
+            or v.shape[-1] != q.shape[-1]):
         return _banded(q, k, v, causal, window, kv_mask, bias, scale,
                        dropout_p, block_q, block_k, interpret)
     return _dense(
@@ -1468,12 +1477,17 @@ def _banded(q, k, v, causal, window, kv_mask, bias, scale, dropout_p,
     n, n_kv = q.shape[1], k.shape[1]
     if not causal or q.shape[2] != k.shape[2]:
         raise ValueError(
-            "a window or grouped K/V heads need causal self-attention "
-            f"(causal={causal}, s_q={q.shape[2]}, s_k={k.shape[2]})")
+            "a window, grouped K/V heads or a value width of its own need "
+            f"causal self-attention (causal={causal}, s_q={q.shape[2]}, "
+            f"s_k={k.shape[2]})")
     if kv_mask is not None or bias is not None or dropout_p:
         raise ValueError(
-            "the banded flash kernels (window / grouped K/V heads) take no "
-            "kv_mask, bias or dropout")
+            "the banded flash kernels (window / grouped K/V heads / a value "
+            "width of its own) take no kv_mask, bias or dropout")
+    if k.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            f"q is {q.shape[-1]} wide and k {k.shape[-1]}: scores contract "
+            "over one width (v alone may have its own)")
     if n % n_kv or v.shape[1] != n_kv:
         raise ValueError(
             f"{n} query heads do not divide into {n_kv} K/V heads "
@@ -1512,12 +1526,13 @@ def flash_attention_bshd(
 
     Where the heads do not cut into 128-lane blocks (``heads_per_block``
     is 0: one head of 64 on a tensor-parallel rank, ``d`` of 96), and for
-    a ``window`` or grouped K/V heads (the banded kernels are head-major),
-    the arguments are transposed to ``[b, n, s, d]`` and the context back,
-    by XLA."""
+    a ``window``, grouped K/V heads or a value width of its own (the banded
+    kernels are head-major), the arguments are transposed to ``[b, n, s,
+    d]`` and the context back, by XLA."""
     n, d = q.shape[2], q.shape[3]
     window = kw.pop("window", None)
-    if window is not None or k.shape[2] != n or not heads_per_block(n, d):
+    if (window is not None or k.shape[2] != n or v.shape[3] != d
+            or not heads_per_block(n, d)):
         o = flash_attention(
             *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), window=window, **kw)
         return jnp.swapaxes(o, 1, 2)
@@ -1649,7 +1664,8 @@ def mha_reference(
 ) -> jax.Array:
     """Materialised-score reference (for tests): same math, O(s^2) — incl.
     the kernels' exact hash-dropout mask and the zeros-for-fully-masked-rows
-    convention. Grouped K/V heads are repeated to the query heads."""
+    convention. Grouped K/V heads are repeated to the query heads; ``v``
+    may have a width of its own (the context's)."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if k.shape[1] != q.shape[1]:

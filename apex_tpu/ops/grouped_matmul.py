@@ -57,6 +57,18 @@ def _tile(size: int, want: int) -> int:
     return size
 
 
+def _width_tile(size: int) -> int:
+    """The tile of a contraction or an output width: ``_tile(size, 1024)``,
+    but the whole width where that rule answers under 512 and the width is
+    at most 2048. 1408 = 11 x 128 has no larger power-of-two-times-128
+    divisor, and eleven tiles of 128 read the rows eleven times: on the
+    chip the whole width took the three products from 64.1 to 31.2 ms a
+    step (25.2% to 51.9% of their roof; PERF.md, PR 35). Widths of 1024
+    and 2048 keep their tile of 1024."""
+    t = _tile(size, 1024)
+    return size if t < 512 and size <= 2048 else t
+
+
 def row_tile(m: int) -> int:
     """The row tile the kernels use for ``m`` rows: callers size their
     buffers to a multiple of it."""
@@ -121,7 +133,7 @@ def _gmm_kernel(offs_ref, group_ref, tile_ref, lhs_ref, rhs_ref, out_ref,
 def _gmm(lhs, rhs, group_sizes, *, transpose_rhs, interpret, name):
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tm, tk, tn = row_tile(m), _tile(k, 1024), _tile(n, 1024)
+    tm, tk, tn = row_tile(m), _width_tile(k), _width_tile(n)
     offsets, group_of, tile_of, count = _visits(group_sizes, m, tm, False)
     tiles_k = k // tk
     if transpose_rhs:
@@ -180,7 +192,7 @@ def _tgmm(lhs, dout, group_sizes, out_dtype, *, interpret):
     m, k = lhs.shape
     n = dout.shape[1]
     g = group_sizes.shape[0]
-    tm, tk, tn = row_tile(m), _tile(k, 1024), _tile(n, 1024)
+    tm, tk, tn = row_tile(m), _width_tile(k), _width_tile(n)
     offsets, group_of, tile_of, count = _visits(group_sizes, m, tm, True)
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, tm=tm),

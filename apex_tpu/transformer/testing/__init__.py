@@ -12,6 +12,7 @@ from .distributed_test_base import (  # noqa: F401
 )
 from .standalone_transformer_lm import (  # noqa: F401
     GPTConfig,
+    LatentKV,
     LayerKind,
     bert_forward,
     gpt_embed,

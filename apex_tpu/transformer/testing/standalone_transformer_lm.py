@@ -73,6 +73,16 @@ class LayerKind(NamedTuple):
     experts: bool = False           # expert MLP (else the dense one)
 
 
+class LatentKV(NamedTuple):
+    """The shape of latent attention (``GPTConfig.latent_kv``): keys and
+    values come from one compressed vector a token."""
+
+    rank: int           # width of the compressed K/V vector (its own RMSNorm)
+    nope_dim: int       # a head's q/k lanes that carry no position
+    rope_dim: int       # its rotary lanes: ONE such key head serves all heads
+    value_dim: int      # a head's value (and context) width
+
+
 @dataclasses.dataclass
 class GPTConfig:
     """Model shape config (the relevant subset of the reference's
@@ -171,6 +181,10 @@ class GPTConfig:
     sandwich_norm: bool = False             # a norm after each branch too
     qk_norm: bool = False                   # RMSNorm over each head of q and k
     attention_gate: bool = False            # out = Wo (ctx * sigmoid(Wg x))
+    # latent attention (attention_by_kind): q is nope_dim + rope_dim a head,
+    # k and v are up-projected from a normed latent of ``rank``, the rotary
+    # key is shared by all heads; head_dim plays no part
+    latent_kv: Optional[LatentKV] = None
     gated_mlp: bool = False                 # down(silu(gate x) * up x)
     linear_bias: bool = True                # False: bias-free linears
     learned_positions: bool = True          # False: no position table
@@ -192,7 +206,7 @@ class GPTConfig:
         shaped = {
             "num_kv_heads": None, "head_dim": None, "norm": "layernorm",
             "sandwich_norm": False, "qk_norm": False,
-            "attention_gate": False, "gated_mlp": False,
+            "attention_gate": False, "latent_kv": None, "gated_mlp": False,
             "linear_bias": True, "learned_positions": True,
             "embedding_scale": None, "untied_head": False, "num_experts": 0}
         if self.layer_kinds is None:
@@ -229,6 +243,28 @@ class GPTConfig:
             raise ValueError(
                 f"{self.num_attention_heads} query heads do not divide into "
                 f"{self.kv_heads} K/V heads")
+        if self.latent_kv is not None:
+            self.latent_kv = LatentKV(*self.latent_kv)
+            for what, on in (
+                    ("a window: the two-width flash path is not built with "
+                     "one", any(k.window for k in self.layer_kinds)),
+                    ("grouped K/V heads (num_kv_heads): every head has keys "
+                     "and values of its own from the latent",
+                     self.kv_heads != self.num_attention_heads),
+                    ("head_dim: a head is nope_dim + rope_dim wide",
+                     self.head_dim is not None),
+                    ("qk_norm: the latent has a norm of its own",
+                     self.qk_norm),
+                    ("attention_gate: not built on this branch",
+                     self.attention_gate),
+                    ("linear_bias: its projections are bias-free",
+                     self.linear_bias)):
+                if on:
+                    raise ValueError(f"latent_kv does not support {what}")
+            if min(self.latent_kv) < 1 or self.latent_kv.rope_dim % 2:
+                raise ValueError(
+                    f"latent_kv {tuple(self.latent_kv)}: every width at "
+                    "least 1 and an even rope_dim")
         if any(k.experts for k in self.layer_kinds):
             first, count = self.experts_held or (0, 0)
             if not (self.num_experts > 0 and count > 0 and first >= 0
@@ -346,10 +382,21 @@ def _init_params_by_kind(cfg: GPTConfig, key: jax.Array) -> Pytree:
         lp = norm_gains("input_ln", "post_ln")
         if cfg.sandwich_norm:
             lp.update(norm_gains("post_attn_ln", "post_mlp_ln"))
-        lp.update(linear("q", n * d, h))
-        lp.update(linear("k", nkv * d, h))
-        lp.update(linear("v", nkv * d, h))
-        lp.update(linear("proj", h, n * d))
+        if cfg.latent_kv is not None:
+            lat = cfg.latent_kv
+            # the published tensors' row order: q [head, (nope, rope)],
+            # kv_down [(latent, rope)], kv_up [head, (nope, value)]
+            lp.update(linear("q", n * (lat.nope_dim + lat.rope_dim), h))
+            lp.update(linear("kv_down", lat.rank + lat.rope_dim, h))
+            lp["kv_norm_w"] = jnp.ones((lat.rank,), dt)
+            lp.update(linear("kv_up", n * (lat.nope_dim + lat.value_dim),
+                             lat.rank))
+            lp.update(linear("proj", h, n * lat.value_dim))
+        else:
+            lp.update(linear("q", n * d, h))
+            lp.update(linear("k", nkv * d, h))
+            lp.update(linear("v", nkv * d, h))
+            lp.update(linear("proj", h, n * d))
         if cfg.attention_gate:
             lp.update(linear("attn_gate", n * d, h))
         if cfg.qk_norm:
@@ -928,30 +975,87 @@ def _rotary(x: jax.Array, theta: float) -> jax.Array:
     return (x32 * cos + rot * sin).astype(x.dtype)
 
 
+def _pairs_to_halves(w: jax.Array, first: int) -> jax.Array:
+    """The rows of ``w [..., rows, in]`` from ``first`` on, published as
+    rotary pairs ``(2i, 2i + 1)``, put as halves (every even row, then
+    every odd one): rotating halves then gives the scores that rotating
+    pairs gives, and the weight moves, never an activation."""
+    rope = w[..., first:, :]
+    half = rope.shape[-2] // 2
+    rope = jnp.swapaxes(
+        rope.reshape(*rope.shape[:-2], half, 2, rope.shape[-1]), -3, -2)
+    return jnp.concatenate(
+        [w[..., :first, :], rope.reshape(*w.shape[:-2], 2 * half,
+                                         w.shape[-1])], axis=-2)
+
+
+def _latent_qkv(cfg: GPTConfig, kind: LayerKind, lp, x: jax.Array):
+    """q ``[b, n, s, nope + rope]``, k of the same shape and v ``[b, n, s,
+    value]`` of latent attention (``GPTConfig.latent_kv``), bias-free: ``q
+    = W_q x``; ``(c, k_r) = W_dkv x``; ``(k_n, v)`` a head ``= W_ukv
+    rms(c)``; ``q_r`` and the ONE ``k_r`` rotated over their ``rope``
+    lanes; ``k = (k_n, k_r)``, the rotary key broadcast to every head (its
+    gradient is XLA's sum over the heads of ``dk``'s last lanes)."""
+    n, dt = cfg.num_attention_heads, x.dtype
+    rank, nope, rope, dv = cfg.latent_kv
+    h = x.shape[-1]
+    q_w = _pairs_to_halves(lp["q_w"].astype(dt).reshape(n, nope + rope, h),
+                           nope)
+    q = jnp.einsum("sbh,ndh->bnsd", x, q_w)
+    with jax.named_scope("apex_tpu.mla_latent"):
+        ckv = jnp.einsum("sbh,ch->bsc", x,
+                         _pairs_to_halves(lp["kv_down_w"].astype(dt), rank))
+        c = rms_norm(ckv[..., :rank].astype(jnp.float32),
+                     lp["kv_norm_w"].astype(jnp.float32), 1,
+                     cfg.layernorm_epsilon).astype(dt)
+        up_w = lp["kv_up_w"].astype(dt).reshape(n, nope + dv, rank)
+        k_n = jnp.einsum("bsc,ndc->bnsd", c, up_w[:, :nope])
+        v = jnp.einsum("bsc,ndc->bnsd", c, up_w[:, nope:])
+    k_r = ckv[:, None, :, rank:]                        # [b, 1, s, rope]
+    if kind.rotary:
+        with jax.named_scope("apex_tpu.mla_rope"):
+            q = jnp.concatenate(
+                [q[..., :nope], _rotary(q[..., nope:], cfg.rope_theta)],
+                axis=-1)
+            k_r = _rotary(k_r, cfg.rope_theta)
+    with jax.named_scope("apex_tpu.mla_latent"):
+        k = jnp.concatenate(
+            [k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
+    return q, k, v
+
+
 @jax.named_scope("apex_tpu.attention")
 def attention_by_kind(cfg: GPTConfig, kind: LayerKind, lp, x: jax.Array):
     """Causal self-attention of a ``layer_kinds`` layer over ``x [s, b,
     h]``: separate q / k / v projections with ``kv_heads`` K/V heads,
     optionally RMSNorm over each head of q and k, rotary positions, a
     window, and a sigmoid gate on the context before the output
-    projection. Heads come off the projections head-major, ``[b, n, s,
+    projection; or, with ``cfg.latent_kv``, latent attention
+    (:func:`_latent_qkv`: the value and the context have a width of their
+    own). Heads come off the projections head-major, ``[b, n, s,
     d]`` (``einsum "sbh,ndh->bnsd"``), the layout the banded flash kernels
-    read (a window or grouped K/V heads; ``flash_attention``): XLA moves
-    the data, a transpose behind each projection GEMM and one before the
-    output projection. The dense kernels' batch-major layout
+    read (a window, grouped K/V heads or two widths; ``flash_attention``):
+    XLA moves the data, a transpose behind each projection GEMM and one
+    before the output projection. The dense kernels' batch-major layout
     (``parallel_attention``) is not taken here."""
     s, b, h = x.shape
     n, nkv, d = cfg.num_attention_heads, cfg.kv_heads, cfg.kv_channels
-    q = _linear(lp, "q", x, "sbh,ndh->bnsd", (n, d, h))
-    k = _linear(lp, "k", x, "sbh,ndh->bnsd", (nkv, d, h))
-    v = _linear(lp, "v", x, "sbh,ndh->bnsd", (nkv, d, h))
-    if cfg.qk_norm:
-        q = rms_norm(q.astype(jnp.float32), lp["q_norm_w"].astype(jnp.float32),
-                     1, cfg.layernorm_epsilon).astype(x.dtype)
-        k = rms_norm(k.astype(jnp.float32), lp["k_norm_w"].astype(jnp.float32),
-                     1, cfg.layernorm_epsilon).astype(x.dtype)
-    if kind.rotary:
-        q, k = _rotary(q, cfg.rope_theta), _rotary(k, cfg.rope_theta)
+    if cfg.latent_kv is not None:
+        q, k, v = _latent_qkv(cfg, kind, lp, x)
+        d = q.shape[-1]
+    else:
+        q = _linear(lp, "q", x, "sbh,ndh->bnsd", (n, d, h))
+        k = _linear(lp, "k", x, "sbh,ndh->bnsd", (nkv, d, h))
+        v = _linear(lp, "v", x, "sbh,ndh->bnsd", (nkv, d, h))
+        if cfg.qk_norm:
+            q = rms_norm(q.astype(jnp.float32),
+                         lp["q_norm_w"].astype(jnp.float32), 1,
+                         cfg.layernorm_epsilon).astype(x.dtype)
+            k = rms_norm(k.astype(jnp.float32),
+                         lp["k_norm_w"].astype(jnp.float32), 1,
+                         cfg.layernorm_epsilon).astype(x.dtype)
+        if kind.rotary:
+            q, k = _rotary(q, cfg.rope_theta), _rotary(k, cfg.rope_theta)
     scale = 1.0 / (d ** 0.5)
     use_flash = cfg.use_flash_attention
     if use_flash is None:
@@ -969,7 +1073,7 @@ def attention_by_kind(cfg: GPTConfig, kind: LayerKind, lp, x: jax.Array):
     if cfg.attention_gate:
         gate = _linear(lp, "attn_gate", x, "sbh,ndh->bnsd", (n, d, h))
         ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(x.dtype)
-    return _linear(lp, "proj", ctx, "bnsd,hnd->sbh", (h, n, d))
+    return _linear(lp, "proj", ctx, "bnsd,hnd->sbh", (h, n, v.shape[-1]))
 
 
 @jax.named_scope("apex_tpu.mlp")

@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.ops.grouped_matmul import grouped_matmul, row_tile
+from apex_tpu.ops.grouped_matmul import (_tile, _width_tile, grouped_matmul,
+                                          row_tile)
 
 
 def by_groups(lhs, rhs, sizes):
@@ -58,6 +59,35 @@ def test_forward_and_both_backward_products(case):
     for g, size in enumerate(sizes):
         if size == 0:
             assert not np.any(np.asarray(got[1][g]))
+
+
+def test_a_width_with_no_large_power_of_two_tile_is_taken_whole():
+    """1408 = 11 x 128 (the width of ``moonlight-16b-a3b``'s experts): the
+    whole width is the tile, where the power-of-two rule walked eleven of
+    128; the widths ``trinity-mini`` has keep their tile of 1024."""
+    assert _tile(1408, 1024) == 128 and _width_tile(1408) == 1408
+    assert _width_tile(1024) == _width_tile(2048) == 1024
+    assert _width_tile(512) == 512 and _width_tile(64) == 64
+    assert _width_tile(4224) == 128             # past 2048: the rule's own
+
+
+@pytest.mark.parametrize("k, n", [(384, 128), (128, 384), (384, 640)])
+def test_the_products_at_a_whole_width_tile(k, n):
+    """Widths of 3 x 128 and 5 x 128: one whole-width tile each way."""
+    assert _width_tile(384) == 384 and _width_tile(640) == 640
+    m, sizes = CASES["tiles_shared_by_three_groups"]
+    lhs, rhs, w = operands(m, k, n, len(sizes))
+    gs = jnp.asarray(sizes, jnp.int32)
+    valid = (jnp.arange(m) < sum(sizes))[:, None]
+    kernel = lambda l, r: jnp.where(  # noqa: E731
+        valid, grouped_matmul(l, r, gs, interpret=True), 0)
+    np.testing.assert_allclose(kernel(lhs, rhs), by_groups(lhs, rhs, sizes),
+                               atol=1e-4)
+    got = jax.grad(lambda l, r: jnp.sum(kernel(l, r) * w), (0, 1))(lhs, rhs)
+    ref = jax.grad(lambda l, r: jnp.sum(by_groups(l, r, sizes) * w),
+                   (0, 1))(lhs, rhs)
+    np.testing.assert_allclose(jnp.where(valid, got[0], 0), ref[0], atol=2e-4)
+    np.testing.assert_allclose(got[1], ref[1], atol=2e-4)
 
 
 def test_nothing_is_dropped_under_any_imbalance():

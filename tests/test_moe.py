@@ -52,11 +52,11 @@ FLASH_FWD, FLASH_BWD = "apex_tpu_flash_fwd", ("apex_tpu_flash_bwd_dq",
                                               "apex_tpu_flash_bwd_dkv")
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    """The rehearsal model: its configuration file at the family's tiny
-    sizes, the family, the sizes, float32 weights and one batch."""
-    cell = mf.Cell(mf.load_manifest(), CELL)
+@functools.lru_cache(maxsize=None)
+def _tiny(cell_name):
+    """A cell's rehearsal model: its configuration file at the family's
+    tiny sizes, the family, the sizes, float32 weights and one batch."""
+    cell = mf.Cell(mf.load_manifest(), cell_name)
     harness.rehearsal_cell(cell)
     family = cell.family
     d = family.sizes(cell.config)
@@ -65,6 +65,11 @@ def tiny():
     tokens, labels = traffic.train_batch(7, 0, 2, 64, d["vocab"], "next")
     return cell.config, family, d, params, jnp.asarray(tokens), jnp.asarray(
         labels)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return _tiny(CELL)
 
 
 def _f32(family, config, **kw):
@@ -114,10 +119,14 @@ def _layer(x, lp, held, d, shared):
         interpret=True)
 
 
-def test_the_shares_add_up_to_the_uncut_layer(tiny):
+@pytest.mark.parametrize("cell_name", [CELL, "moonlight-16b-a3b.train-1chip"])
+def test_the_shares_add_up_to_the_uncut_layer(cell_name):
     """4 shares of 2 of 8 experts: the routed parts of all shares plus the
-    shared expert once equal the reference's layer holding all 8."""
-    _, family, d, params, _, _ = tiny
+    shared expert once equal the reference's layer holding all 8. (The
+    second cell's two shared experts are one MLP of twice the width,
+    counted once.)"""
+    _, family, d, params, _, _ = _tiny(cell_name)
+    assert d["router"] == 8 and d["experts"] < d["router"]
     h, f, router = d["hidden"], d["expert_ffn"], d["router"]
     ks = jax.random.split(jax.random.PRNGKey(11), 4)
     whole = dict(params["layers"][1])
