@@ -49,7 +49,7 @@ LAYER_SCOPES = (
     "apex_tpu.transformer_layer",  # one layer; directly under it, under neither child: norms and residual tails
     "apex_tpu.attention",          # qkv GEMM, layout changes, flash kernel, out projection; with latent attention the two below nested in it
     "apex_tpu.mla_latent",         # in attention: the K/V side of latent attention from the normed input to the kernel's operands: down-projection, the latent's RMSNorm, up-projection, assembling k
-    "apex_tpu.mla_rope",           # in attention: the partial rotary on the rotary lanes of q and on the one shared rotary key, with the slices and joins round it
+    "apex_tpu.mla_rope",           # in attention: the partial rotary, one pass over the whole q row (its rotary lanes rotated by a product with a constant signed permutation, the others passed through) and one over the shared rotary key
     "apex_tpu.mlp",                # both GEMMs and the activation; on an expert layer the whole expert MLP, the four below nested in it
     "apex_tpu.moe_router",         # in mlp: float32 scores, top-k, weights
     "apex_tpu.moe_dispatch",       # in mlp: sort, gather into expert order, each row in use added back into its token

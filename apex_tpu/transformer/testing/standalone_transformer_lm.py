@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import parallel_state
 from ..enums import AttnMaskType
+from ..functional.fused_rope import _apply_rope
 from ..functional.fused_softmax import FusedScaleMaskSoftmax
 from ..tensor_parallel import (
     column_parallel_linear,
@@ -961,18 +962,16 @@ def _linear(lp, name: str, x: jax.Array, spec: str, shape=None):
     return y + b
 
 
-def _rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions over the whole last dimension of ``[b, n, s, d]``
-    (rotate-half convention), computed in float32."""
-    s, d = x.shape[-2:]
+def _rotary(x: jax.Array, theta: float, first: int = 0) -> jax.Array:
+    """Rotary positions ``0..s`` over the lanes of ``[b, n, s, d]`` from
+    ``first`` on (rotate-half convention; the lanes before pass through),
+    in float32, as one pass over ``x`` (``fused_rope._apply_rope``)."""
+    s, d = x.shape[-2], x.shape[-1] - first
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None]   # [s, d/2]
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    rot = jnp.concatenate([-x2, x1], axis=-1)
-    return (x32 * cos + rot * sin).astype(x.dtype)
+    return _apply_rope(x, cos, sin, first)
 
 
 def _pairs_to_halves(w: jax.Array, first: int) -> jax.Array:
@@ -1014,9 +1013,7 @@ def _latent_qkv(cfg: GPTConfig, kind: LayerKind, lp, x: jax.Array):
     k_r = ckv[:, None, :, rank:]                        # [b, 1, s, rope]
     if kind.rotary:
         with jax.named_scope("apex_tpu.mla_rope"):
-            q = jnp.concatenate(
-                [q[..., :nope], _rotary(q[..., nope:], cfg.rope_theta)],
-                axis=-1)
+            q = _rotary(q, cfg.rope_theta, nope)
             k_r = _rotary(k_r, cfg.rope_theta)
     with jax.named_scope("apex_tpu.mla_latent"):
         k = jnp.concatenate(
