@@ -33,11 +33,16 @@ Four parts, designed so instrumentation costs nothing on the hot path:
   per-tensor overflow provenance (pytree and packed flat-buffer paths),
   opt-in activation-watch taps, and an anomaly-rule engine
   (non-finite grads / grad-norm spike / loss-scale collapse) emitting
-  structured events through the same cond-gated async drain path.
+  structured events through the same cond-gated async drain path;
+- :mod:`~apex_tpu.telemetry.compiles` — the compile ledger: jax's own
+  trace, lower and compile-or-load spans by function on
+  ``perf_counter``'s clock and the persistent cache's counters, listening
+  from the moment this package is imported (no switch); ``steady()``
+  marks the end of warm-up, after which a recompile is counted and logged.
 
 See ``docs/observability.md`` for the end-to-end story.
 """
-from . import numerics  # noqa: F401
+from . import compiles, numerics  # noqa: F401
 from .metrics import (  # noqa: F401
     MetricsState,
     accumulate,
@@ -117,7 +122,12 @@ from .tracing import (  # noqa: F401
     trace_session,
 )
 
+# the ledger listens from here on: every program that trains imports this
+# package (``optimizers/fused_adam.py``) before it compiles anything
+compiles.install()
+
 __all__ = [
+    "compiles",
     "MetricsState", "accumulate", "drain", "init_metrics",
     "observe_scale_update", "summarize",
     "numerics", "NumericsMonitor", "NumericsState", "ActivationWatch",

@@ -59,9 +59,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT = "/jax/compilation_cache/cache_hits"
-
 # The dense forward and the paged engine compute the same logits in bf16
 # through different kernels and summation orders, so on random weights
 # (logit spread ~0.6, top-two gap ~0.1) greedy argmax is not bit-stable
@@ -122,27 +119,19 @@ FULL = Size()
 
 class Smoke:
     """The run's record: timed sections (compile seconds apart from wall
-    seconds, from jax's own compile events), checks, and notes."""
+    seconds, from the package's compile ledger), checks, and notes."""
 
     def __init__(self, out=None):
+        from apex_tpu.telemetry import compiles
+
         self.out = out if out is not None else sys.stdout
         self.record: Dict[str, Any] = {"sections": {}}
-        self._compile_s = 0.0
-        self._cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
-        jax.monitoring.register_event_listener(self._on_event)
+        self._totals = compiles.totals
 
     def close(self) -> None:
-        jax.monitoring.unregister_event_duration_listener(self._on_secs)
-        jax.monitoring.unregister_event_listener(self._on_event)
-
-    def _on_secs(self, event: str, secs: float, **_kw) -> None:
-        if event == _BACKEND_COMPILE:
-            self._compile_s += secs
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == _CACHE_HIT:
-            self._cache_hits += 1
+        """Nothing to release (the listeners are the package's ledger's);
+        the run's callers and ``tests/test_chip_smoke.py`` end with it."""
+        self.out.flush()
 
     def say(self, msg: str) -> None:
         print(msg, file=self.out, flush=True)
@@ -156,12 +145,13 @@ class Smoke:
     def section(self, name: str):
         """Time a block; ``compile_s`` is what jax spent in the backend
         compiler (or fetching from the persistent cache) inside it."""
-        c0, h0, t0 = self._compile_s, self._cache_hits, time.perf_counter()
+        before, t0 = self._totals(), time.perf_counter()
         rec: Dict[str, Any] = {}
         yield rec
+        after = self._totals()
         rec["wall_s"] = round(time.perf_counter() - t0, 3)
-        rec["compile_s"] = round(self._compile_s - c0, 3)
-        rec["cache_hits"] = self._cache_hits - h0
+        rec["compile_s"] = round(after["compile_s"] - before["compile_s"], 3)
+        rec["cache_hits"] = after["hits"] - before["hits"]
         self.record["sections"][name] = rec
         self.say(f"[{name}] " + json.dumps(rec))
 
