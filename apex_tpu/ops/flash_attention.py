@@ -32,7 +32,9 @@ batch-major with one batch row) — implemented with per-token segment ids so
 tokens only attend within their own sequence.
 
 Supports: causal masking (block-skipped: tiles strictly above the diagonal
-are neither loaded nor computed), a key-padding mask ``[b, s_k]`` (True =
+are neither loaded nor computed; a causal square that is one tile is
+walked as its lower triangle in chunks of rows, ``dense_walk_share``),
+a key-padding mask ``[b, s_k]`` (True =
 attend), an **additive logit bias** ``[b|1, n|1, s_q|1, s_k]`` streamed in
 ``[block_q, block_k]`` tiles (never fully VMEM-resident) with gradients —
 the AlphaFold pair bias / ALiBi / T5 relative-position case, and the
@@ -196,26 +198,28 @@ def dropout_mask_reference(seed: int, b: int, n: int, s_q: int, s_k: int,
 # ---------------------------------------------------------------------------
 
 
-def _tile_indices(iq, ik, block_q, block_k):
-    qi = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    ki = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+def _tile_indices(iq, ik, block_q, block_k, part=None):
+    """Global ``(q, k)`` indices of tile ``(iq, ik)``, or of ``part``, the
+    ``(first row, rows, keys)`` of it a chunk of the triangular walk reads
+    (``_walk_chunks``)."""
+    r0, rows, keys = part or (0, block_q, block_k)
+    q0 = iq * block_q + r0 if r0 else iq * block_q
+    qi = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
+    ki = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
     return qi, ki
 
 
 def _mask_scores(s, qi, ki, *, causal, have_mask, mask_ref, have_segs,
-                 segq_ref, segk_ref):
+                 segq_ref, segk_ref, rows=slice(None), keys=slice(None)):
+    """``rows`` / ``keys``: the part of the q / k block ``s`` covers."""
     if causal:
         s = jnp.where(ki > qi, _NEG_INF, s)
     if have_mask:
-        keep = mask_ref[0] != 0  # [1, bk]
+        keep = mask_ref[0, :, keys] != 0  # [1, bk]
         s = jnp.where(keep, s, _NEG_INF)
     if have_segs:
-        seg_q = segq_ref[0, 0][:, None]  # [bq, 1]
-        seg_k = segk_ref[0, 0][None, :]  # [1, bk]
+        seg_q = segq_ref[0, 0, rows][:, None]  # [bq, 1]
+        seg_k = segk_ref[0, 0, keys][None, :]  # [1, bk]
         s = jnp.where(seg_q == seg_k, s, _NEG_INF)
     return s
 
@@ -340,6 +344,64 @@ def _second(i2, i3):
     return i3
 
 
+# ---------------------------------------------------------------------------
+# the triangular walk: a causal square that is ONE tile (s <= 1024 at the
+# default blocks: one grid step a batch row and block of heads) is walked
+# in static chunks of rows inside the kernel body. Chunk r reads keys
+# [0, (r+1) c): the sub-blocks above the diagonal, all -1e30 under the mask
+# (their exp underflows to exactly 0), are not computed. The one-pass
+# forward softmax and the fused backward (dq beside dk, dv) stay.
+# ---------------------------------------------------------------------------
+
+
+def _chunk_rows(bq: int) -> int:
+    """Rows of a chunk of the triangular walk of a ``bq``-row tile: a
+    quarter of it, at least 256 (``tools/flash_block_sweep.py walk`` on
+    v5e, ``docs/flash_block_sweep.md``: at 1024 chunks of 256 beat 512
+    and 128, whose narrower products slow the forward more than the 1/16
+    of the square they save; at 512, chunks of 128 lose to the square)."""
+    return max(bq // 4, 256)
+
+
+def _walk_rows(s_q, s_k, bq, bk, causal, has_bias) -> int:
+    """Rows of a chunk where the dense kernels walk the lower triangle of
+    their tile, 0 where they walk the whole tile. The triangle engages on
+    a causal square that is one tile (``s_q == s_k == bq == bk``) without
+    a bias: the bias path keeps the square, its ``dbias`` tiles are owned
+    ``(iq, ik)``."""
+    if not causal or has_bias or not s_q == s_k == bq == bk:
+        return 0
+    c = _chunk_rows(bq)
+    return c if c < bq and bq % c == 0 else 0
+
+
+def _walk_chunks(bq, bk, walk):
+    """``(first row, rows, keys)`` of each static chunk a kernel body walks
+    in its tile: the whole tile as one chunk (``walk`` 0), or chunk ``r``
+    rows ``[r walk, (r+1) walk)`` against keys ``[0, (r+1) walk)``."""
+    if not walk:
+        return ((0, bq, bk),)
+    return tuple((r0, walk, r0 + walk) for r0 in range(0, bq, walk))
+
+
+def dense_walk_share(s_q: int, s_k: int, block_q: int = 1024,
+                     block_k: int = 1024, causal: bool = False,
+                     has_bias: bool = False) -> float:
+    """Share of the ``[s_q, s_k]`` score square the dense kernels compute a
+    (batch row, head): ``(n_c + 1) / (2 n_c)`` where a one-tile causal
+    square is walked as its lower triangle in ``n_c`` chunks (5/8 at
+    1024), 1.0 everywhere else. Decided from the static shapes and
+    arguments alone, at trace time."""
+    bq, bk = _pick_block(s_q, block_q), _pick_block(s_k, block_k)
+    return _walk_share(bq, bk, _walk_rows(s_q, s_k, bq, bk, causal,
+                                          has_bias))
+
+
+def _walk_share(bq, bk, walk):
+    return sum(rows * keys for _, rows, keys in _walk_chunks(bq, bk, walk)
+               ) / (bq * bk)
+
+
 class _Tile(NamedTuple):
     """What the three dense kernel bodies share (all static)."""
     scale: float
@@ -354,6 +416,22 @@ class _Tile(NamedTuple):
     have_mask: bool
     have_segs: bool
     dropout_p: float
+    walk: int           # rows of a chunk of the triangular walk; 0: the square
+
+    @property
+    def chunks(self):
+        return _walk_chunks(self.block_q, self.block_k, self.walk)
+
+    @property
+    def share(self):
+        """Of the tile's scores, the share the kernels compute."""
+        return _walk_share(self.block_q, self.block_k, self.walk)
+
+    def slices(self, part=None):
+        """The rows of the q block and the keys of the k block a chunk
+        (``part``, one of ``chunks``; None: the whole tile) covers."""
+        r0, rows, keys = part or (0, self.block_q, self.block_k)
+        return slice(r0, r0 + rows), slice(0, keys)
 
 
 def _lanes_of(t: _Tile, j: int):
@@ -406,22 +484,26 @@ _TN = ((0,), (0,))      # a.T @ b
 
 
 def _head_scores(t: _Tile, j, iq, ik, q_ref, k_ref, bias_ref, mask_ref,
-                 segq_ref, segk_ref):
+                 segq_ref, segk_ref, part=None):
     """Head ``j`` of the block: its lanes, its scaled q (the other heads'
     lanes zero, so the 128-lane contraction is this head's), the masked
     float32 ``[bq, bk]`` scores and the tile's global indices — one
     implementation for the three kernels, so the score and mask semantics
-    cannot desynchronise. Dots run in the INPUT dtype with fp32
-    accumulation: bf16 inputs hit the MXU's native rate."""
+    cannot desynchronise. ``part``, a chunk of ``t.chunks``: its rows of
+    q against its keys alone (``[rows, keys]`` scores; no bias: the walk
+    never takes one). Dots run in the INPUT dtype with fp32 accumulation:
+    bf16 inputs hit the MXU's native rate."""
+    rs, ks = t.slices(part)
     lanes = _lanes_of(t, j)
-    q = _scaled_q(q_ref[...], t.scale, lanes)
-    s = _dot(q, k_ref[...], _NT)
+    q = _scaled_q(q_ref[rs], t.scale, lanes)
+    s = _dot(q, k_ref[ks], _NT)
     if t.have_bias:
         s = s + bias_ref[0, j if t.bias_heads > 1 else 0].astype(jnp.float32)
-    qi, ki = _tile_indices(iq, ik, t.block_q, t.block_k)
+    qi, ki = _tile_indices(iq, ik, t.block_q, t.block_k, part)
     s = _mask_scores(
         s, qi, ki, causal=t.causal, have_mask=t.have_mask, mask_ref=mask_ref,
-        have_segs=t.have_segs, segq_ref=segq_ref, segk_ref=segk_ref)
+        have_segs=t.have_segs, segq_ref=segq_ref, segk_ref=segk_ref,
+        rows=rs, keys=ks)
     return lanes, q, s, qi, ki
 
 
@@ -455,13 +537,13 @@ def _for_heads(t: _Tile, body):
         jax.lax.fori_loop(0, t.hp, lambda j, c: (body(j), c)[1], 0)
 
 
-def _write_lanes(ref, lanes, val):
-    """``val`` on the head's lanes of the block in ``ref``; the other
-    heads' lanes stay as they are (unwritten ones until their head's
+def _write_lanes(ref, lanes, val, rows=slice(None)):
+    """``val`` on the head's lanes of ``rows`` of the block in ``ref``; the
+    other heads' lanes stay as they are (unwritten ones until their head's
     turn: every lane belongs to one head of the loop)."""
     if lanes is not None:
-        val = jnp.where(lanes, val, ref[...].astype(val.dtype))
-    ref[...] = val.astype(ref.dtype)
+        val = jnp.where(lanes, val, ref[rows].astype(val.dtype))
+    ref[rows] = val.astype(ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -489,9 +571,9 @@ def _fwd_kernel(
             l_scr[:] = jnp.zeros_like(l_scr)
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def head(j):
+    def head(j, part=None):
         return _head_scores(t, j, iq, ik, q_ref, k_ref, bias_ref, mask_ref,
-                            segq_ref, segk_ref)
+                            segq_ref, segk_ref, part)
 
     def pv(p, j, qi, ki):
         # softmax normalizer uses the UNDROPPED probabilities; dropout
@@ -501,22 +583,26 @@ def _fwd_kernel(
         keep = _keep_scaled(t, seed_ref, ib, ip, j, qi, ki)
         if keep is not None:
             p = p * keep
-        return _dot(p.astype(v_ref.dtype), v_ref[...], _NN)
+        return _dot(p.astype(v_ref.dtype), v_ref[:p.shape[1]], _NN)
 
-    def finish(j, lanes, acc, m, l):
+    def finish(j, lanes, acc, m, l, rows=slice(None)):
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        lse_ref[j] = jnp.where(l == 0.0, _NEG_INF, m + jnp.log(safe_l))
-        _write_lanes(o_ref, lanes, acc / safe_l)
+        lse_ref[j, rows] = jnp.where(l == 0.0, _NEG_INF, m + jnp.log(safe_l))
+        _write_lanes(o_ref, lanes, acc / safe_l, rows)
 
     if single:
         # with n_k == 1 the (causal) tile skip never fires: ik == 0
-        # always intersects the diagonal band of every q block
+        # always intersects the diagonal band of every q block. Each chunk
+        # of rows sees its whole key range (keys past its last row are
+        # masked, or, walking the triangle, not computed): one direct
+        # softmax a chunk
         def direct(j):
-            lanes, _, s, qi, ki = head(j)
-            m = jnp.max(s, axis=1, keepdims=True)
-            p = _probs(t, s, m)
-            l = jnp.sum(p, axis=1, keepdims=True)
-            finish(j, lanes, pv(p, j, qi, ki), m, l)
+            for part in t.chunks:
+                lanes, _, s, qi, ki = head(j, part)
+                m = jnp.max(s, axis=1, keepdims=True)
+                p = _probs(t, s, m)
+                l = jnp.sum(p, axis=1, keepdims=True)
+                finish(j, lanes, pv(p, j, qi, ki), m, l, t.slices(part)[0])
 
         _for_heads(t, direct)
         return
@@ -651,7 +737,8 @@ def _plan(q, k, v, bias, kv_mask, seg_q, seg_k, scale, causal, dropout_p,
         d=lay.d, hp=lay.hp,
         bias_heads=lay.hp if bias is not None and bias.shape[1] > 1 else 1,
         have_bias=bias is not None, have_mask=kv_mask is not None,
-        have_segs=seg_q is not None, dropout_p=dropout_p)
+        have_segs=seg_q is not None, dropout_p=dropout_p,
+        walk=_walk_rows(s_q, s_k, bq, bk, causal, bias is not None))
     return _Plan(lay, flat, parts, (s_q, s_k), (bq, bk), tile)
 
 
@@ -782,40 +869,47 @@ def _bwd_dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def head(j):
+    def chunk(j, part):
         # q is scaled (the chain rule's *scale for dk rides in with it)
         # and, like do below, zero outside the head's lanes: what the two
-        # accumulate lands on this head's lanes of dk and dv alone
+        # accumulate lands on this head's lanes of dk and dv alone. A
+        # chunk (t.chunks) is rows of the q block against its first keys
         lanes, q, s, qi, ki = _head_scores(
             t, j, iq, ik, q_ref, k_ref, bias_ref, mask_ref, segq_ref,
-            segk_ref)
-        p = _probs(t, s, lse_ref[j])
-        do = _only(do_ref[...], lanes)
+            segk_ref, part)
+        rows, ks = t.slices(part)
+        p = _probs(t, s, lse_ref[j, rows])
+        do = _only(do_ref[rows], lanes)
         keep = _keep_scaled(t, seed_ref, ib, ip, j, qi, ki)
         p_d = p if keep is None else p * keep
-        dv_scr[...] += _dot(p_d.astype(do.dtype), do, _TN)      # p_d.T @ do
-        dp = _dot(do, v_ref[...], _NT)
+        dv_scr[ks] += _dot(p_d.astype(do.dtype), do, _TN)       # p_d.T @ do
+        dp = _dot(do, v_ref[ks], _NT)
         if keep is not None:
             dp = dp * keep
         if emit_dq:
             # delta_ref holds O: delta = rowsum(do * o) computed here, so
             # the XLA-side delta pass (+ its [.., 1] re-layout) disappears
             delta = jnp.sum(
-                do.astype(jnp.float32) * delta_ref[...].astype(jnp.float32),
+                do.astype(jnp.float32) * delta_ref[rows].astype(jnp.float32),
                 axis=1, keepdims=True,
             )
         else:
-            delta = delta_ref[j]
+            delta = delta_ref[j, rows]
         ds = p * (dp - delta)  # [bq, bk]
-        dk_scr[...] += _dot(ds.astype(q.dtype), q, _TN)         # ds.T @ q
+        dk_scr[ks] += _dot(ds.astype(q.dtype), q, _TN)          # ds.T @ q
         if emit_dq:
             # single-k-block fast path (n_k == 1): every iq block is
-            # visited exactly once, so dq = ds @ k * scale is complete
-            # here — the separate dq kernel (a second score recompute,
-            # exp, and do@v.T) is skipped entirely
+            # visited exactly once and a chunk's rows see all their keys,
+            # so dq = ds @ k * scale is complete here — the separate dq
+            # kernel (a second score recompute, exp, and do@v.T) is
+            # skipped entirely
             _write_lanes(
                 dq_ref, lanes,
-                _dot(ds.astype(k_ref.dtype), k_ref[...], _NN) * t.scale)
+                _dot(ds.astype(k_ref.dtype), k_ref[ks], _NN) * t.scale, rows)
+
+    def head(j):
+        for part in t.chunks:
+            chunk(j, part)
 
     if t.causal:
         @pl.when(ik * t.block_k <= iq * t.block_q + (t.block_q - 1))
@@ -1348,11 +1442,15 @@ def _bwd_block_table(s_q, s_k, d, block_q, block_k):
     (dq emitted from the dkv kernel, delta in-kernel), which beat every
     split-tile variant in-model (0.99 vs 1.43 ms/layer at the 345M
     bench shape — the split path pays a second score recompute in the
-    separate dq kernel plus the XLA delta pass). A standalone
-    kernel-only sweep that differentiates w.r.t. q alone will tell you
-    otherwise (0.61 ms): XLA dead-code-eliminates the dkv kernel there;
-    don't trust it. The hook stays so a future chip/shape can diverge
-    fwd and bwd tiles without an API change.
+    separate dq kernel plus the XLA delta pass). Causality is no reason
+    to split: a causal whole-sequence tile walks its lower triangle
+    inside the body (``_walk_rows``), so it costs less than a
+    non-causal one where smaller grid tiles would bring the second
+    recompute back. A standalone kernel-only sweep that differentiates
+    w.r.t. q alone will tell you otherwise (0.61 ms): XLA
+    dead-code-eliminates the dkv kernel there; don't trust it. The hook
+    stays so a future chip/shape can diverge fwd and bwd tiles without
+    an API change.
     """
     return (block_q, block_k)
 

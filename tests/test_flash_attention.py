@@ -754,3 +754,232 @@ def test_flash_bshd_moves_nothing():
     assert kernels == {"apex_tpu_flash_fwd", "apex_tpu_flash_bwd_dkv"}
     assert not prims & {"transpose", "concatenate", "slice", "gather",
                         "dynamic_slice", "split", "copy"}, prims
+
+
+# ---------------------------------------------------------------------------
+# the triangular walk: a causal square that is one tile computes only the
+# chunks of rows on or below its diagonal; every other call walks its tiles
+# whole, as before
+# ---------------------------------------------------------------------------
+import importlib  # noqa: E402
+
+from apex_tpu.ops.flash_attention import (  # noqa: E402
+    dense_walk_share,
+    flash_attention_varlen,
+    mha_reference_varlen,
+)
+
+fa = importlib.import_module("apex_tpu.ops.flash_attention")
+
+WALK_LAYOUTS = {            # kind, heads, head size
+    "bnsd": ("bnsd", 2, 64),            # one head a block
+    "bshd": ("bshd", 4, 64),            # two heads of 64 share a block
+    "bshd-d128": ("bshd", 2, 128),      # one head of 128 a block
+    "qkv": ("qkv", 4, 64),              # three views of one array
+}
+ENTRY = {"bnsd": flash_attention, "bshd": flash_attention_bshd,
+         "qkv": flash_attention_qkv}
+
+
+def _walk_args(layout, s, dtype, b=1, key=0):
+    kind, n, d = WALK_LAYOUTS[layout]
+    k1, k2 = jax.random.split(jax.random.PRNGKey(key))
+    if kind == "qkv":
+        args = (jax.random.normal(k1, (b, s, 3, n, d)).astype(dtype),)
+    else:
+        shape = (b, n, s, d) if kind == "bnsd" else (b, s, n, d)
+        args = tuple(jax.random.normal(kk, shape).astype(dtype)
+                     for kk in jax.random.split(k1, 3))
+    w = jax.random.normal(k2, (b, n, s, d) if kind == "bnsd" else (b, s, n, d))
+    return kind, args, w
+
+
+def _as_bnsd(kind, args):
+    if kind == "qkv":
+        return tuple(_bnsd(args[0][:, :, i]) for i in range(3))
+    return args if kind == "bnsd" else tuple(_bnsd(x) for x in args)
+
+
+def _reference_in(kind, args, **kw):
+    o = mha_reference(*_as_bnsd(kind, args), **kw)
+    return o if kind == "bnsd" else _bnsd(o)
+
+
+def _value_and_grads(fn, w, args):
+    return jax.value_and_grad(
+        lambda *a: jnp.sum(w * fn(*a).astype(jnp.float32)),
+        tuple(range(len(args))))(*args)
+
+
+def _close(got, want, tol, what):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    err = np.abs(got - want).max()
+    assert err <= tol * max(1.0, np.abs(want).max()), (what, err)
+
+
+def _lse(kind, args, block, **kw):
+    q, k, v = args if kind != "qkv" else (args[0], None, None)
+    return fa._fwd(q, k, v, None, kw.get("kv_mask"), None, None,
+                   kw.get("seed"), 1.0 / q.shape[-1] ** 0.5, True,
+                   kw.get("dropout_p", 0.0), block, block, True, kind)[1]
+
+
+@pytest.mark.parametrize("layout,dtype,s", [
+    ("bnsd", "float32", 256), ("bnsd", "float32", 512),
+    ("bnsd", "float32", 1024), ("bnsd", "bfloat16", 512),
+    ("bshd", "float32", 512), ("bshd", "bfloat16", 1024),
+    ("bshd-d128", "float32", 512), ("qkv", "float32", 512),
+    ("qkv", "bfloat16", 1024),
+])
+def test_walk_matches_the_square_and_the_reference(layout, dtype, s):
+    """``n_c`` chunks (1 at 256 rows, 2 at 512, 4 at 1024): the context,
+    ``lse`` and every gradient against the same call in two tiles of
+    ``s / 2`` (the online multi-tile path, its own ``bwd_dq`` kernel: it
+    never walks) and against the materialised reference."""
+    n_c = {256: 1, 512: 2, 1024: 4}[s]
+    assert dense_walk_share(s, s, causal=True) == (n_c + 1) / (2 * n_c)
+    kind, args, w = _walk_args(layout, s, jnp.dtype(dtype))
+    tol = 2e-2 if dtype == "bfloat16" else 2e-5
+    entry = ENTRY[kind]
+    walk = _value_and_grads(
+        lambda *a: entry(*a, causal=True), w, args)
+    square = _value_and_grads(
+        lambda *a: entry(*a, causal=True, block_q=s // 2, block_k=s // 2),
+        w, args)
+    ref = _value_and_grads(
+        lambda *a: _reference_in(kind, a, causal=True), w, args)
+    for oracle, name in ((square, "two tiles"), (ref, "reference")):
+        _close(walk[0], oracle[0], tol, f"loss vs {name}")
+        for g, o, part in zip(walk[1], oracle[1], "qkv"):
+            _close(g, o, 2 * tol, f"d{part} vs {name}")
+    _close(entry(*args, causal=True),
+           entry(*args, causal=True, block_q=s // 2, block_k=s // 2),
+           tol, "context")
+    _close(_lse(kind, args, s), _lse(kind, args, s // 2), 1e-5, "lse")
+
+
+@pytest.mark.parametrize("case", ["kv_mask", "dropout", "segments"])
+def test_walk_masks_and_dropout_match_the_square(case):
+    """A chunk's masks and dropout counters are its own global rows and
+    keys: the key-padding mask, the per-token segments of the packed
+    layout (one tile of 512 packed tokens) and a dropout mask equal bit
+    for bit to the one two tiles of 256 draw (f32: a single flipped keep
+    bit would show as an error of order one)."""
+    s = 512
+    if case == "segments":
+        kind, args, w = _walk_args("bshd", s, jnp.float32)
+        qkv = tuple(x[0] for x in args)
+        cu = jnp.array([0, 100, 260, 397, 512], jnp.int32)
+        fn = lambda blk: lambda *a: flash_attention_varlen(  # noqa: E731
+            *a, cu, causal=True, block_q=blk, block_k=blk)
+        ref = lambda *a: mha_reference_varlen(*a, cu, causal=True)  # noqa
+        got, sq, want = (_value_and_grads(f, w[0], qkv)
+                         for f in (fn(s), fn(s // 2), ref))
+    else:
+        kind, args, w = _walk_args("bshd" if case == "kv_mask" else "bnsd",
+                                   s, jnp.float32, b=2)
+        kw = (dict(kv_mask=jnp.arange(s)[None, :] < jnp.array([[s], [300]]))
+              if case == "kv_mask" else dict(dropout_p=0.2, dropout_seed=21))
+        entry = ENTRY[kind]
+        got, sq = (_value_and_grads(
+            lambda *a, blk=blk: entry(*a, causal=True, block_q=blk,
+                                      block_k=blk, **kw), w, args)
+            for blk in (s, s // 2))
+        want = _value_and_grads(
+            lambda *a: _reference_in(kind, a, causal=True, **kw), w, args)
+    for oracle, name in ((sq, "two tiles"), (want, "reference")):
+        _close(got[0], oracle[0], 2e-5, f"loss vs {name}")
+        for g, o, part in zip(got[1], oracle[1], "qkv"):
+            _close(g, o, 5e-5, f"d{part} vs {name}")
+
+
+@pytest.mark.parametrize("case", ["non_causal", "bias", "rectangular"])
+def test_walk_does_not_engage(case):
+    """Non-causal, a bias (its dbias tiles are owned (iq, ik)) and
+    ``s_q != s_k`` walk the whole square: share 1.0, the tile's ``walk``
+    0, results as the reference's."""
+    s_q, s_k = (256, 512) if case == "rectangular" else (256, 256)
+    causal = case != "non_causal"
+    assert dense_walk_share(s_q, s_k, causal=causal,
+                            has_bias=case == "bias") == 1.0
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    q = jax.random.normal(ks[0], (1, 2, s_q, 64))
+    k, v = (jax.random.normal(kk, (1, 2, s_k, 64)) for kk in ks[1:3])
+    w = jax.random.normal(ks[3], (1, 2, s_q, 64))
+    bias = (jax.random.normal(ks[4], (1, 2, s_q, s_k)) if case == "bias"
+            else None)
+    plan = fa._plan(q, k, v, bias, None, None, None, 0.125, causal, 0.0,
+                    1024, 1024, True, "bnsd")
+    assert plan.tile.walk == 0 and plan.tile.share == 1.0
+    got = _value_and_grads(lambda *a: flash_attention(
+        *a, causal=causal, bias=bias), w, (q, k, v))
+    want = _value_and_grads(lambda *a: mha_reference(
+        *a, causal=causal, bias=bias), w, (q, k, v))
+    _close(got[0], want[0], 2e-5, "loss")
+    for g, o, part in zip(got[1], want[1], "qkv"):
+        _close(g, o, 5e-5, f"d{part}")
+
+
+@pytest.mark.parametrize("s,block,causal,bias,share", [
+    (1024, 1024, True, False, 5 / 8),       # cells 1 and 3: the qkv call
+    (512, 1024, False, False, 1.0),         # BERT: non-causal
+    (512, 1024, True, False, 3 / 4),        # two chunks of 256
+    (256, 1024, True, False, 1.0),          # one chunk: the square
+    (1024, 512, True, False, 1.0),          # two tiles: the tile skip
+    (2048, 1024, True, False, 1.0),         # the online multi-tile path
+    (1024, 1024, True, True, 1.0),          # a bias keeps the square
+])
+def test_dense_walk_share(s, block, causal, bias, share):
+    """The share is the plan's, from static shapes and arguments alone."""
+    assert dense_walk_share(s, s, block, block, causal, bias) == share
+    q = jnp.zeros((1, s, 2, 64), jnp.bfloat16)
+    plan = fa._plan(q, q, q, None, None, None, None, 0.125, causal, 0.0,
+                    block, block, True, "bshd")
+    if not bias:
+        assert plan.tile.share == share
+    t = plan.tile
+    assert sum(r * k for _, r, k in t.chunks) == (
+        t.share * t.block_q * t.block_k)
+
+
+# ---------------------------------------------------------------------------
+# the chip compiler's word on the walked bodies (compile only: a described
+# v5e, no chip): Mosaic takes the static slices at c rows and (r+1) c lanes
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("b,s,causal,share", [
+    (8, 1024, True, 5 / 8),             # gpt2-345m's cells
+    (16, 512, False, 1.0),              # bert-large's cell
+], ids=["gpt2-345m", "bert-large"])
+def test_v5e_compiles_the_walked_kernels_at_the_cells_shapes(
+        one_chip, monkeypatch, b, s, causal, share):
+    """Forward and the fused ``bwd_dkv`` (no ``bwd_dq``) of the cells'
+    ``qkv`` call, 16 heads of 64, bf16."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dense_walk_share(s, s, causal=causal) == share
+    x = jax.ShapeDtypeStruct((b, s, 3, 16, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def step(x):
+        o, vjp = jax.vjp(lambda x: flash_attention_qkv(x, causal=causal), x)
+        return o, vjp(o)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(step).lower(x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert "apex_tpu_flash_fwd" in text and "apex_tpu_flash_bwd_dkv" in text
+    assert "apex_tpu_flash_bwd_dq" not in text

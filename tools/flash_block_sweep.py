@@ -9,27 +9,30 @@ w.r.t. q, k AND v with all cotangents consumed — differentiating w.r.t. q
 alone lets XLA dead-code-eliminate the dkv kernel and reports a fantasy bwd
 time (the round-5 regression this file exists to prevent).
 
-Run on a real TPU:  python tools/flash_block_sweep.py [dense|long]
+Run on a real TPU:  python tools/flash_block_sweep.py [dense|long|walk]
 Prints one line per (shape, layout, tiling): the three kernels' times and
 everything else the step ran on the device (XLA's own copies and
-transposes round the kernels), ms per forward + backward pass. The table
-is in ``docs/flash_block_sweep.md``; ``_bwd_block_table`` holds what it
-concluded.
+transposes round the kernels), ms per forward + backward pass, and the
+share of the score square the kernels compute (``dense_walk_share``).
+``walk`` holds the tile whole (one tile a sequence) and tries the rows
+of a chunk of the triangular walk of a causal square (``WALK``: the
+module's rule ``_chunk_rows`` is stood in for, as no argument sets it).
+The tables are in ``docs/flash_block_sweep.md``; ``_bwd_block_table`` and
+``_chunk_rows`` hold what they concluded.
 """
 import glob
+import importlib
 import sys
 import tempfile
 from collections import defaultdict
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, ".")
 
-from apex_tpu.ops.flash_attention import (  # noqa: E402
-    flash_attention,
-    flash_attention_bshd,
-)
+fa = importlib.import_module("apex_tpu.ops.flash_attention")
 
 REPS = 8
 KERNELS = ("apex_tpu_flash_fwd", "apex_tpu_flash_bwd_dkv",
@@ -44,6 +47,7 @@ SHAPES = {
 }
 CAND = [(1024, 1024), (1024, 512), (512, 1024), (512, 512), (256, 256),
         (2048, 2048)]
+WALK = [0, 512, 256, 128]       # rows of a chunk; 0: the square whole
 
 
 def device_ms(trace_dir):
@@ -93,31 +97,47 @@ def measure(fn, shape, causal, bq, bk):
     return device_ms(trace_dir)
 
 
+def line(fn, shape, s, causal, bq, bk, tag):
+    try:
+        t = measure(fn, shape, causal, bq, bk)
+    except Exception as e:  # e.g. VMEM past the scoped limit
+        msg = str(e).splitlines()[0][:90] if str(e) else ""
+        print(f"{tag} FAILED: {type(e).__name__} {msg}", flush=True)
+        return
+    share = fa.dense_walk_share(s, s, bq, bk, causal)
+    flash = sum(t.get(k, 0.0) for k in KERNELS)
+    parts = " ".join(
+        f"{k.replace('apex_tpu_flash_', '')}={t.get(k, 0.0):.3f}"
+        for k in KERNELS)
+    print(f"{tag} walk={share:.4f} flash={flash:.3f} ms ({parts}) "
+          f"other={t.get('other', 0.0):.3f}", flush=True)
+
+
 def main():
     which = sys.argv[1] if len(sys.argv) > 1 else "dense"
-    for b, n, s, d in SHAPES[which]:
+    for b, n, s, d in SHAPES["dense" if which == "walk" else which]:
         for layout, fn, shape in (
-                ("bnsd", flash_attention, (b, n, s, d)),
-                ("bshd", flash_attention_bshd, (b, s, n, d))):
+                ("bnsd", fa.flash_attention, (b, n, s, d)),
+                ("bshd", fa.flash_attention_bshd, (b, s, n, d))):
             for causal in (True, False):
+                if which == "walk":
+                    # one tile a sequence; causal: each chunk size tried
+                    for c in WALK if causal else [0]:
+                        if c >= s:
+                            continue
+                        rule = lambda bq, c=c: c or bq  # noqa: E731
+                        with mock.patch.object(fa, "_chunk_rows", rule):
+                            line(fn, shape, s, causal, s, s,
+                                 f"b={b} n={n} s={s} d={d} {layout} "
+                                 f"causal={int(causal)} bq={s} bk={s} "
+                                 f"c={c or 'square'}")
+                    continue
                 for bq, bk in CAND:
                     if bq > s or bk > s:
                         continue
-                    tag = (f"b={b} n={n} s={s} d={d} {layout} "
-                           f"causal={int(causal)} bq={bq} bk={bk}")
-                    try:
-                        t = measure(fn, shape, causal, bq, bk)
-                    except Exception as e:  # e.g. VMEM past the scoped limit
-                        msg = str(e).splitlines()[0][:90] if str(e) else ""
-                        print(f"{tag} FAILED: {type(e).__name__} {msg}",
-                              flush=True)
-                        continue
-                    flash = sum(t.get(k, 0.0) for k in KERNELS)
-                    parts = " ".join(
-                        f"{k.replace('apex_tpu_flash_', '')}={t.get(k, 0.0):.3f}"
-                        for k in KERNELS)
-                    print(f"{tag} flash={flash:.3f} ms ({parts}) "
-                          f"other={t.get('other', 0.0):.3f}", flush=True)
+                    line(fn, shape, s, causal, bq, bk,
+                         f"b={b} n={n} s={s} d={d} {layout} "
+                         f"causal={int(causal)} bq={bq} bk={bk}")
 
 
 if __name__ == "__main__":
