@@ -1,4 +1,5 @@
-"""Fused softmax cross entropy with label smoothing.
+"""Fused softmax cross entropy with label smoothing, and the chunked LM-head
+cross entropy.
 
 Reference: ``apex/contrib/xentropy/softmax_xentropy.py:6-30`` over
 ``csrc/xentropy/xentropy_kernel.cu`` (718 LoC). The kernel's exact loss
@@ -18,6 +19,18 @@ residual is the logits; probabilities are never materialised in fp32 unless
 the scheduler chooses to. ``half_to_float`` upcasts the returned losses (the
 kernel always produces fp32 losses; the flag controls the saved softmax
 dtype, moot here).
+
+The LM head carries the kernel's idea across the head GEMM: both chunked
+functions scan over row chunks and never hold the ``[N, V]`` logits.
+``lm_head_cross_entropy`` returns per-row losses and replays each chunk's
+head GEMM in backward. ``lm_head_cross_entropy_sum`` returns a weighted sum
+of them, with the weights given in forward: the chunk's logits, their
+logsumexp and the labels are all the gradient needs besides the scalar
+cotangent, so the forward chunk loop computes ``d(hidden)`` and
+``d(head_weight)`` too and backward only scales them. No GEMM is replayed
+and nothing ``[N, V]`` is stored. Float16 keeps the replaying backward:
+a gradient formed before the loss scale reaches it lies under float16's
+range.
 """
 from __future__ import annotations
 
@@ -25,6 +38,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from apex_tpu._vma import pvary_union_like
 
 
 @jax.named_scope("apex_tpu.cross_entropy")
@@ -59,6 +74,28 @@ class SoftmaxCrossEntropyLoss:
         )
 
 
+def _chunks(n: int, chunk_size: int) -> int:
+    if n % chunk_size:
+        raise ValueError(f"N ({n}) must be divisible by chunk_size ({chunk_size})")
+    return n // chunk_size
+
+
+def _has_float32_range(dtype) -> bool:
+    return jnp.finfo(dtype).minexp <= jnp.finfo(jnp.float32).minexp
+
+
+def _chunk_head(hrow, w, lrow):
+    """One chunk of rows through the head: float32 logits ``[c, V]``, their
+    logsumexp and the gold logit, each ``[c]``."""
+    logits = jnp.einsum(
+        "ch,vh->cv", hrow, w.astype(hrow.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, lrow[:, None], axis=-1)[:, 0]
+    return logits, lse, gold
+
+
 @jax.named_scope("apex_tpu.cross_entropy")
 def lm_head_cross_entropy(
     hidden: jax.Array,  # [N, h] pre-head activations (any float dtype)
@@ -66,7 +103,6 @@ def lm_head_cross_entropy(
     labels: jax.Array,  # [N] int
     *,
     chunk_size: int = 2048,
-    save_logits_dtype=None,
 ) -> jax.Array:
     """Chunk-fused LM-head GEMM + cross entropy: per-row losses WITHOUT
     materialising the full ``[N, V]`` logits tensor.
@@ -78,43 +114,23 @@ def lm_head_cross_entropy(
     backward (``jax.checkpoint``), so peak memory holds ONE ``[chunk, V]``
     block. The loop-level analogue of the reference xentropy kernel's
     save-only-``max_log_sum_exp`` trick (``xentropy_kernel.cu``), applied
-    across the head GEMM as well.
+    across the head GEMM as well. A caller that reduces the losses to one
+    weighted sum takes :func:`lm_head_cross_entropy_sum`, which replays
+    nothing.
 
     Gradients: d(hidden) per chunk and d(head_weight) summed across chunks
     by the scan transpose. ``N`` must be divisible by ``chunk_size`` (pick
     any divisor; it only changes peak memory).
-
-    ``save_logits_dtype`` (e.g. ``jnp.bfloat16``) switches backward from
-    rematerialise-the-chunk to save-the-logits — the loop-level analogue of
-    the reference kernel's save-the-half-precision-softmax mode
-    (``half_to_float=False``, ``xentropy_kernel.cu`` bprop reading the
-    saved fp16 softmax): forward keeps each chunk's logits in the given
-    compact dtype (``[N, V]`` total, half the fp32 footprint) and backward
-    skips the logits GEMM replay entirely. Costs O(N*V) saved memory for
-    one fewer GEMM pass + one fewer reduce pass per chunk; measured ~5
-    ms/step on the GPT-2 345M v5e bench. Logit precision: bf16 keeps
-    |logit| <= ~40 to ~0.3% relative, well inside half-softmax parity.
     """
     n, h = hidden.shape
-    if n % chunk_size:
-        raise ValueError(f"N ({n}) must be divisible by chunk_size ({chunk_size})")
-    if save_logits_dtype is not None:
-        return _lm_head_ce_saved(
-            hidden, head_weight, labels, chunk_size,
-            jnp.dtype(save_logits_dtype),
-        )
-    hc = hidden.reshape(n // chunk_size, chunk_size, h)
-    lc = labels.reshape(n // chunk_size, chunk_size)
+    nc = _chunks(n, chunk_size)
+    hc = hidden.reshape(nc, chunk_size, h)
+    lc = labels.reshape(nc, chunk_size)
 
     @jax.checkpoint
     def chunk_loss(w, xs):
         hrow, lrow = xs
-        logits = jnp.einsum(
-            "ch,vh->cv", hrow, w.astype(hrow.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lrow[:, None], axis=-1)[:, 0]
+        _, lse, gold = _chunk_head(hrow, w, lrow)
         return lse - gold
 
     def body(carry, xs):
@@ -126,79 +142,89 @@ def lm_head_cross_entropy(
     return losses.reshape(n)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _lm_head_ce_saved(hidden, head_weight, labels, chunk_size, logits_dtype):
-    losses, _ = _lm_head_ce_saved_fwd(
-        hidden, head_weight, labels, chunk_size, logits_dtype
-    )
-    return losses
+@jax.named_scope("apex_tpu.cross_entropy")
+def lm_head_cross_entropy_sum(
+    hidden: jax.Array,  # [N, h] pre-head activations (any float dtype)
+    head_weight: jax.Array,  # [V, h] (tied-embedding layout)
+    labels: jax.Array,  # [N] int
+    weights: jax.Array,  # [N] float row weights
+    *,
+    chunk_size: int = 2048,
+) -> jax.Array:
+    """``sum_i weights[i] * loss_i`` over the rows, as a float32 scalar, with
+    ``loss_i`` the cross entropy of row ``i`` through the head
+    (:func:`lm_head_cross_entropy`'s per-row loss).
+
+    The gradient is computed in the forward chunk loop: per chunk,
+    ``d = (softmax(logits) - onehot) * weights`` in float32,
+    ``d(hidden) = d @ W`` kept per chunk in the activations' dtype and
+    ``d(W) += d^T @ hidden`` summed in the head weight's dtype. Backward
+    scales what forward kept by the cotangent and runs no GEMM; the
+    residuals are ``d(hidden)`` ``[N, h]``, ``d(W)`` and the per-row losses
+    (``weights``' cotangent). Where the cotangent is a power of two, as a
+    loss scale is, the gradients round as the replaying backward's do.
+    That needs float32's exponent range: with 1/n weights ``d`` lies far
+    below float16's smallest normal until the loss scale reaches it, so
+    where the rows or the head are float16 the replaying per-row loss is
+    summed instead. A call that is not differentiated runs the losses
+    alone. ``N`` must be divisible by ``chunk_size``.
+    """
+    _chunks(hidden.shape[0], chunk_size)
+    weights = weights.astype(jnp.float32)
+    if not all(_has_float32_range(a.dtype) for a in (hidden, head_weight)):
+        return jnp.sum(weights * lm_head_cross_entropy(
+            hidden, head_weight, labels, chunk_size=chunk_size))
+    args = (hidden, head_weight, labels, weights)
+    # under shard_map every operand varies where any does, so a replicated
+    # head weight's gradient is summed over the shards by the pvary's
+    # transpose, outside the rule
+    return _ce_sum(*(pvary_union_like(a, args) for a in args), chunk_size)
 
 
-def _lm_head_ce_saved_fwd(hidden, head_weight, labels, chunk_size,
-                          logits_dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _ce_sum(hidden, head_weight, labels, weights, chunk_size):
+    return jnp.sum(weights * lm_head_cross_entropy(
+        hidden, head_weight, labels, chunk_size=chunk_size))
+
+
+def _ce_sum_fwd(hidden, head_weight, labels, weights, chunk_size):
     n, h = hidden.shape
     nc = n // chunk_size
-    hc = hidden.reshape(nc, chunk_size, h)
-    lc = labels.reshape(nc, chunk_size)
+    dtype = hidden.dtype
+    w = head_weight.astype(dtype)
 
-    def body(carry, xs):
-        hrow, lrow = xs
-        logits = jnp.einsum(
-            "ch,vh->cv", hrow, head_weight.astype(hrow.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(logits_dtype)
-        # the loss IS the CE of the quantized logits (the reference
-        # xentropy's fp16-logits convention): lse/gold derive from the
-        # SAVED values, so forward and backward see one tensor — and XLA
-        # writes the compact buffer straight out of the GEMM epilogue
-        # instead of materialising fp32 logits first (~4 ms/step on the
-        # 345M bench)
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        gold = jnp.take_along_axis(lf, lrow[:, None], axis=-1)[:, 0]
-        return carry, (lse - gold, logits, lse)
-
-    _, (losses, saved_logits, lse) = jax.lax.scan(body, None, (hc, lc))
-    return losses.reshape(n), (hidden, head_weight, labels, saved_logits, lse)
-
-
-def _lm_head_ce_saved_bwd(chunk_size, logits_dtype, res, g):
-    hidden, head_weight, labels, saved_logits, lse = res
-    n, h = hidden.shape
-    nc = n // chunk_size
-    hc = hidden.reshape(nc, chunk_size, h)
-    lc = labels.reshape(nc, chunk_size)
-    gc = g.reshape(nc, chunk_size)
-    w_c = head_weight.astype(hidden.dtype)
-
-    def body(dw_acc, xs):
-        hrow, lrow, grow, lgt, ls = xs
-        # d(logits) = (softmax - onehot) * dloss, straight from the saved
-        # compact logits — no GEMM replay. Cast to the activation dtype
-        # before the two GEMMs so they run at MXU rate (bf16 gradient
-        # discipline, same as the dense layers').
-        p = jnp.exp(lgt.astype(jnp.float32) - ls[:, None])
+    def body(dw, xs):
+        hrow, lrow, wrow = xs
+        logits, lse, gold = _chunk_head(hrow, w, lrow)
         # onehot as a broadcast iota-compare (fuses into the exp pass; a
         # scatter here forces an extra full [chunk, V] memory pass)
-        onehot = (
-            jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
-            == lrow[:, None]
-        )
-        dlogits = ((p - onehot) * grow[:, None]).astype(hidden.dtype)
-        dh = jnp.einsum("cv,vh->ch", dlogits, w_c,
-                        preferred_element_type=jnp.float32)
-        dw_acc = dw_acc + jnp.einsum(
-            "cv,ch->vh", dlogits, hrow, preferred_element_type=jnp.float32
-        )
-        return dw_acc, dh.astype(hidden.dtype)
+        onehot = (jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+                  == lrow[:, None])
+        d = (jnp.exp(logits - lse[:, None]) - onehot) * wrow[:, None]
+        dh = jnp.einsum("cv,vh->ch", d, w,
+                        preferred_element_type=jnp.float32).astype(dtype)
+        # summed in the head weight's dtype, as the replaying backward's
+        # scan sums it: a float32 carry is another [V, h] float32 buffer
+        # live beside the forward's activations
+        dw = (dw + jnp.einsum("cv,ch->vh", d, hrow,
+                              preferred_element_type=jnp.float32)
+              ).astype(dw.dtype)
+        return dw, (lse - gold, dh)
 
-    dw0 = jnp.zeros(head_weight.shape, jnp.float32)
-    dw, dhc = jax.lax.scan(body, dw0, (hc, lc, gc, saved_logits, lse))
-    return (
-        dhc.reshape(n, h).astype(hidden.dtype),
-        dw.astype(head_weight.dtype),
-        None,
-    )
+    xs = (hidden.reshape(nc, chunk_size, h), labels.reshape(nc, chunk_size),
+          weights.reshape(nc, chunk_size))
+    dw0 = pvary_union_like(jnp.zeros(head_weight.shape, head_weight.dtype),
+                           (head_weight,))
+    # a rolled scan, as lm_head_cross_entropy's
+    dw, (losses, dh) = jax.lax.scan(body, dw0, xs)
+    losses = losses.reshape(n)
+    return jnp.sum(weights * losses), (dh.reshape(n, h), dw, losses)
 
 
-_lm_head_ce_saved.defvjp(_lm_head_ce_saved_fwd, _lm_head_ce_saved_bwd)
+def _ce_sum_bwd(chunk_size, res, g):
+    dh, dw, losses = res
+    return ((g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None,
+            g * losses)
+
+
+_ce_sum.defvjp(_ce_sum_fwd, _ce_sum_bwd)

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ... import parallel_state
-from ..utils import pvary_union_like
+from ...._vma import pvary_union_like
 from .common import emit_tick
 
 Pytree = Any

@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ... import parallel_state
-from ..utils import pvary_union_like, vma_tracking_active
+from ...._vma import pvary_union_like
+from ..utils import vma_tracking_active
 from .common import (
     emit_tick,
     warn_hook_under_autodiff,

@@ -12,6 +12,7 @@ from typing import Any, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..._vma import pvary
 from ...ops.multi_tensor import multi_tensor_l2norm
 from .. import parallel_state
 from ..microbatches import (
@@ -241,15 +242,6 @@ def get_ltor_masks_and_position_ids(
     return attention_mask, loss_mask, position_ids
 
 
-def pvary(x: jax.Array, axis_names) -> jax.Array:
-    """Mark ``x`` varying over ``axis_names`` (``jax.lax.pcast``)."""
-    if isinstance(axis_names, str):
-        axis_names = (axis_names,)
-    if not axis_names:
-        return x
-    return jax.lax.pcast(x, tuple(axis_names), to="varying")
-
-
 def vma_tracking_active(axis_name: str) -> bool:
     """True when the enclosing shard_map tracks varying-manual-axes
     (``check_vma=True``). ``axis_index`` is varying over its axis by
@@ -257,18 +249,6 @@ def vma_tracking_active(axis_name: str) -> bool:
     probing a data value, whose vma is legitimately empty when replicated."""
     probe = jax.lax.axis_index(axis_name)
     return axis_name in getattr(probe.aval, "vma", ())
-
-
-def pvary_union_like(init: jax.Array, operands, extra_axes=()) -> jax.Array:
-    """pvary ``init`` with every axis any of ``operands``' leaves vary on,
-    plus ``extra_axes`` — the closure rule for zero-initialised scan carries
-    whose body mixes the operands (carry in/out types must match)."""
-    want = set(extra_axes)
-    for op in operands:
-        for leaf in jax.tree_util.tree_leaves(op):
-            want |= set(getattr(leaf.aval, "vma", ()))
-    missing = tuple(a for a in want if a not in getattr(init.aval, "vma", ()))
-    return pvary(init, missing)
 
 
 def pvary_full(tree: Pytree, axis_names: Sequence[str]) -> Pytree:

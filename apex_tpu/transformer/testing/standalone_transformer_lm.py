@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -147,17 +148,6 @@ class GPTConfig:
     # zigzag_indices builds the permutation).
     context_parallel_axis: Optional[str] = None
     context_parallel_zigzag: bool = False
-    # Single-device chunked LM-head CE: save each chunk's logits in the
-    # compute dtype instead of rematerialising the chunk GEMM in backward
-    # (the reference xentropy kernel's save-the-half-softmax mode). Costs
-    # [b*s, vocab] saved memory in compute_dtype; saves one GEMM + one
-    # reduce pass per chunk (~5 ms/step on the 345M v5e bench).
-    # Numerics caveat: this changes the FORWARD loss value itself, not
-    # just backward memory — the CE is computed over the compute_dtype-
-    # quantized logits, perturbing the loss by up to ~0.3% relative per
-    # logit at bf16 (see contrib.xentropy.lm_head_cross_entropy's
-    # save_logits_dtype docstring, where the behavior is parity-tested).
-    ce_save_logits: bool = False
     # fp8 (e4m3 fwd + e5m2 grads, TE-style delayed scaling) on the four
     # projection GEMMs per layer (qkv / proj / fc1 / fc2). Thread
     # ``init_gpt_fp8_states(cfg)`` through ``gpt_loss(...,
@@ -1618,6 +1608,20 @@ def _lm_head(cfg, params, hidden, axis_name):
     )
 
 
+def _mean_weights(loss_mask, shape, cp_axis):
+    """The masked mean over losses of ``shape`` as weights of their sum:
+    ``1 / n`` without a mask, else ``m / sum(m)``, the sum taken across
+    ``cp_axis`` where the sequence is sharded over it."""
+    if loss_mask is None and cp_axis is None:
+        return jnp.full(shape, 1.0 / math.prod(shape), jnp.float32)
+    m = (jnp.ones(shape, jnp.float32) if loss_mask is None
+         else loss_mask.astype(jnp.float32))
+    den = jnp.sum(m)
+    if cp_axis is not None:
+        den = jax.lax.psum(den, cp_axis)
+    return m / jnp.maximum(den, 1.0)
+
+
 def gpt_loss(
     cfg: GPTConfig,
     params: Pytree,
@@ -1639,9 +1643,10 @@ def gpt_loss(
     found no row (``telemetry.accumulate(..., moe_stats=stats)``).
 
     Single-device path: the head GEMM and the CE are chunk-fused
-    (``contrib.xentropy.lm_head_cross_entropy``) so the ``[b*s, vocab]``
-    fp32 logits tensor is never fully materialised; TP path: vocab-parallel
-    CE over the sharded logits.
+    (``contrib.xentropy.lm_head_cross_entropy_sum``, the mean handed in as
+    row weights) so the ``[b*s, vocab]`` fp32 logits tensor is never fully
+    materialised and the head's gradient is computed in the forward chunk
+    loop; TP path: vocab-parallel CE over the sharded logits.
 
     With ``fp8_states``/``fp8_carriers`` (see :func:`init_gpt_fp8_states`)
     the layer projections run the fp8 recipe and ``(loss,
@@ -1649,6 +1654,7 @@ def gpt_loss(
     fold their cotangent with :func:`record_gpt_grad_amaxes`.
     """
     new_fp8 = None
+    cp = cfg.context_parallel_axis
     if axis_name is not None:
         logits = gpt_forward(
             cfg, params, tokens, axis_name, dropout_key, deterministic,
@@ -1659,8 +1665,9 @@ def gpt_loss(
         with jax.named_scope("apex_tpu.cross_entropy"):
             losses = vocab_parallel_cross_entropy(
                 logits, labels, 0.0, axis_name)
+            loss = jnp.sum(losses * _mean_weights(loss_mask, losses.shape, cp))
     else:
-        from apex_tpu.contrib.xentropy import lm_head_cross_entropy
+        from apex_tpu.contrib.xentropy import lm_head_cross_entropy_sum
 
         hidden = gpt_hidden(
             cfg, params, tokens, axis_name, dropout_key, deterministic,
@@ -1683,31 +1690,17 @@ def gpt_loss(
                 if n % cand == 0:
                     chunk = cand
                     break
-            losses = lm_head_cross_entropy(
+            loss = lm_head_cross_entropy_sum(
                 hidden.reshape(n, h),
                 _head_weight(cfg, params),
                 jnp.transpose(labels, (1, 0)).reshape(n),  # [s, b] rows
+                jnp.transpose(_mean_weights(loss_mask, (b, s), cp)).reshape(n),
                 chunk_size=chunk,
-                save_logits_dtype=(
-                    cfg.compute_dtype if cfg.ce_save_logits else None
-                ),
-            ).reshape(s, b)
-            losses = jnp.transpose(losses, (1, 0))  # [b, s]
-    with jax.named_scope("apex_tpu.cross_entropy"):
-        if cfg.context_parallel_axis is not None:
-            # global masked mean over the sequence-sharded losses: psum
-            # the numerator/denominator over the cp axis (equal shard sizes)
-            a = cfg.context_parallel_axis
-            m = (jnp.ones_like(losses) if loss_mask is None
-                 else loss_mask.astype(jnp.float32))
-            num = jax.lax.psum(jnp.sum(losses * m), a)
-            den = jax.lax.psum(jnp.sum(m), a)
-            loss = num / jnp.maximum(den, 1.0)
-        elif loss_mask is None:
-            loss = jnp.mean(losses)
-        else:
-            m = loss_mask.astype(jnp.float32)
-            loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
+            )
+    if cp is not None:
+        # the shards' sums over the sequence-sharded rows
+        with jax.named_scope("apex_tpu.cross_entropy"):
+            loss = jax.lax.psum(loss, cp)
     if fp8_states is not None:
         return loss, new_fp8
     if moe_stats:

@@ -369,31 +369,144 @@ def test_lm_head_cross_entropy_matches_unfused():
         lm_head_cross_entropy(hid, w, labels, chunk_size=24)
 
 
-@pytest.mark.parametrize("save_dtype,tol", [(jnp.float32, 1e-6),
-                                            (jnp.bfloat16, 2e-2)])
-def test_lm_head_cross_entropy_saved_logits_matches_remat(save_dtype, tol):
-    """``save_logits_dtype`` (backward from the saved chunk logits, no
-    GEMM replay) against the rematerialising path, loss and gradients:
-    equal at float32, within the logits' rounding at bfloat16."""
-    from apex_tpu.contrib.xentropy import lm_head_cross_entropy
-
-    n, h, v = 64, 16, 96
-    hid = jax.random.normal(jax.random.PRNGKey(0), (n, h))
-    w = jax.random.normal(jax.random.PRNGKey(1), (v, h)) * 0.3
+def _ce_case(n=64, h=16, v=96, dtype=jnp.float32):
+    hid = jax.random.normal(jax.random.PRNGKey(0), (n, h)).astype(dtype)
+    w = (jax.random.normal(jax.random.PRNGKey(1), (v, h)) * 0.3).astype(dtype)
     labels = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, v)
+    return hid, w, labels
 
-    def loss(hid, w, save):
-        return jnp.mean(lm_head_cross_entropy(
-            hid, w, labels, chunk_size=16, save_logits_dtype=save))
 
-    l0, g0 = jax.value_and_grad(
-        lambda a, b: loss(a, b, None), argnums=(0, 1))(hid, w)
-    l1, g1 = jax.value_and_grad(
-        lambda a, b: loss(a, b, save_dtype), argnums=(0, 1))(hid, w)
-    np.testing.assert_allclose(float(l1), float(l0), rtol=tol)
-    for a, b in zip(g1, g0):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=tol, atol=tol * 0.1)
+def _row_weights(kind, n):
+    if kind == "uniform":
+        return jnp.full((n,), 1.0 / n)
+    if kind == "mask":          # a masked mean: zero rows and 1 / count
+        m = (jax.random.uniform(jax.random.PRNGKey(3), (n,)) > 0.4)
+        return m.astype(jnp.float32) / jnp.sum(m)
+    return jax.random.uniform(jax.random.PRNGKey(4), (n,), minval=0.1,
+                              maxval=3.0)
+
+
+@pytest.mark.parametrize("chunk", [64, 16], ids=["one_chunk", "four_chunks"])
+@pytest.mark.parametrize("weights", ["uniform", "mask", "arbitrary"])
+def test_lm_head_cross_entropy_sum_matches_full_logits(weights, chunk):
+    """The weighted sum with its gradient from the forward chunk loop
+    against the full-logits float32 reference: the loss, d(hidden),
+    d(head_weight) and d(weights)."""
+    from apex_tpu.contrib.xentropy import lm_head_cross_entropy_sum
+
+    hid, w, labels = _ce_case()
+    rw = _row_weights(weights, hid.shape[0])
+
+    def fused(hid, w, rw):
+        return lm_head_cross_entropy_sum(hid, w, labels, rw, chunk_size=chunk)
+
+    def reference(hid, w, rw):
+        logp = jax.nn.log_softmax(hid @ w.T, axis=-1)
+        return jnp.sum(rw * -jnp.take_along_axis(logp, labels[:, None], 1)[:, 0])
+
+    with jax.default_matmul_precision("highest"):
+        lf, gf = jax.value_and_grad(fused, argnums=(0, 1, 2))(hid, w, rw)
+        lr, gr = jax.value_and_grad(reference, argnums=(0, 1, 2))(hid, w, rw)
+    np.testing.assert_allclose(float(lf), float(lr), rtol=1e-6)
+    for a, b, name in zip(gf, gr, ("d_hidden", "d_head_weight", "d_weights")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6, err_msg=name)
+    with pytest.raises(ValueError, match="divisible"):
+        lm_head_cross_entropy_sum(hid, w, labels, rw, chunk_size=24)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16],
+                         ids=["bfloat16", "float16"])
+def test_lm_head_cross_entropy_sum_in_half_precision_with_a_loss_scale(dtype):
+    """Half-precision rows and head under amp's 2**16 loss scale and a
+    mean's 1/n weights: the gradients keep the operands' dtype and agree
+    with the float32 reference to the dtype's epsilon in norm. A flat
+    softmax over 4096 classes at n = 2048 puts ``softmax * weight`` near
+    1e-7, in float16's subnormals: a gradient rounded to float16 before
+    the scale reaches it reads 3-4 times the epsilon off."""
+    from apex_tpu.contrib.xentropy import lm_head_cross_entropy_sum
+
+    hid, w, labels = _ce_case(n=2048, h=32, v=4096, dtype=dtype)
+    rw = _row_weights("uniform", hid.shape[0])
+    scale = 2.0 ** 16
+    dh, dw = jax.grad(lambda a, b: scale * lm_head_cross_entropy_sum(
+        a, b, labels, rw, chunk_size=512), argnums=(0, 1))(hid, w)
+    assert dh.dtype == dw.dtype == dtype
+
+    def reference(a, b):
+        logp = jax.nn.log_softmax(a @ b.T, axis=-1)
+        return scale * jnp.mean(
+            -jnp.take_along_axis(logp, labels[:, None], 1)[:, 0])
+
+    with jax.default_matmul_precision("highest"):
+        rh, rwt = jax.grad(reference, argnums=(0, 1))(
+            hid.astype(jnp.float32), w.astype(jnp.float32))
+    for a, b, name in ((dh, rh, "d_hidden"), (dw, rwt, "d_head_weight")):
+        err = jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b)
+        assert float(err) <= float(jnp.finfo(dtype).eps), name
+
+
+def test_lm_head_cross_entropy_sum_traces_alike_at_any_vocabulary():
+    """The differentiated loss is the same number of equations at 96 and
+    at 192 vocabulary rows: nothing walks the head's rows at trace time
+    (cell 1's 50,304 rows once cost ten seconds of set-up so)."""
+    from apex_tpu.analysis import walk
+    from apex_tpu.contrib.xentropy import lm_head_cross_entropy_sum
+
+    def equations(v):
+        hid, w, labels = _ce_case(v=v)
+        rw = _row_weights("uniform", hid.shape[0])
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(
+            lambda a, b: lm_head_cross_entropy_sum(a, b, labels, rw,
+                                                   chunk_size=16),
+            argnums=(0, 1)))(hid, w)
+        return sum(1 for _ in walk(jaxpr.jaxpr))
+
+    assert equations(96) == equations(192)
+
+
+def _head_gemms(jaxpr, v, h):
+    """dot_generals of a jaxpr (sub-jaxprs included) that take the
+    ``[V, h]`` head weight with a block of hidden rows ``[*, h]``."""
+    from apex_tpu.analysis import walk
+
+    found = 0
+    for eqn, _ in walk(jaxpr):
+        if eqn.primitive.name != "dot_general":
+            continue
+        shapes = sorted(tuple(x.aval.shape) for x in eqn.invars)
+        found += (v, h) in shapes and any(
+            s != (v, h) and len(s) == 2 and s[1] == h for s in shapes)
+    return found
+
+
+@pytest.mark.parametrize("fn, forward, backward", [
+    ("lm_head_cross_entropy_sum", 1, 0),
+    ("lm_head_cross_entropy", 1, 1),
+])
+def test_the_head_gemm_runs_once_with_the_sum_and_is_replayed_per_row(
+        fn, forward, backward):
+    """Counted in the jaxprs of ``jax.vjp``: the weighted sum's backward
+    holds no product of the head weight with the hidden rows, where the
+    per-row loss's backward replays it."""
+    from apex_tpu.contrib import xentropy
+
+    hid, w, labels = _ce_case()
+    rw = _row_weights("uniform", hid.shape[0])
+    if fn == "lm_head_cross_entropy_sum":
+        def loss(hid, w):
+            return xentropy.lm_head_cross_entropy_sum(hid, w, labels, rw,
+                                                      chunk_size=16)
+    else:
+        def loss(hid, w):
+            return jnp.mean(xentropy.lm_head_cross_entropy(
+                hid, w, labels, chunk_size=16))
+    v, h = w.shape
+    fwd = jax.make_jaxpr(lambda a, b: jax.vjp(loss, a, b)[0])(hid, w)
+    _, vjp_fn = jax.vjp(loss, hid, w)
+    bwd = jax.make_jaxpr(vjp_fn)(jnp.float32(1.0))
+    assert _head_gemms(fwd.jaxpr, v, h) == forward
+    assert _head_gemms(bwd.jaxpr, v, h) == backward
 
 
 # ---------------------------------------------------------------------------

@@ -684,6 +684,44 @@ def test_every_layer_under_full_shares_one_policy_object():
     assert len(policies) == 2 and policies[0] is policies[1]
 
 
+def _replaying_sum(hidden, head_weight, labels, weights, *, chunk_size):
+    """The weighted sum over the per-row chunked CE, whose backward replays
+    each chunk's head GEMM."""
+    from apex_tpu.contrib.xentropy import lm_head_cross_entropy
+
+    return jnp.sum(weights * lm_head_cross_entropy(
+        hidden, head_weight, labels, chunk_size=chunk_size))
+
+
+@pytest.mark.parametrize("block", list(FULL_BLOCKS))
+def test_the_loss_computes_the_head_s_gradient_in_its_forward_loop(block):
+    """Through either block, ``gpt_loss``'s backward holds no product with
+    the head weight, where the replaying CE's holds two (the replay and
+    d(hidden)); the loss and every gradient equal the replaying CE's at
+    float32."""
+    cfg, params, loss = _full_case(block)
+    head = lm._head_weight(cfg, params).shape
+
+    def head_gemms_in_backward():
+        _, vjp_fn = jax.vjp(loss, params)
+        return sum(
+            eqn.primitive.name == "dot_general"
+            and any(tuple(x.aval.shape) == head for x in eqn.invars)
+            for eqn, _ in walk(jax.make_jaxpr(vjp_fn)(jnp.float32(1)).jaxpr))
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(loss))(params)
+        assert head_gemms_in_backward() == 0
+        with mock.patch("apex_tpu.contrib.xentropy.lm_head_cross_entropy_sum",
+                        _replaying_sum):
+            want = jax.jit(jax.value_and_grad(loss))(params)
+            assert head_gemms_in_backward() == 2
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 def _saved_by_a_layer(block, **kw):
     """What one layer under ``"full"`` hands to its backward pass, beyond
     its parameters: ``[(shape, dtype, where from)]``."""
@@ -732,8 +770,6 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 #: leaves this list when a cell, an example or a tool sets it, or when it
 #: goes.
 TESTS_ONLY = {
-    "ce_save_logits": "test_standalone_models holds its parity; ROADMAP "
-                      "Q1.6 owes it an A/B on the chip",
     "add_binary_head": "BERT's next-sentence head (test_standalone_models); "
                        "the bert cell does not build it (PERF.md §4)",
 }

@@ -115,29 +115,43 @@ def test_gpt_recompute_matches_plain():
     )
 
 
-def test_gpt_ce_save_logits_matches_remat():
-    """`ce_save_logits=True` (save-the-compact-logits CE backward, the
-    round-5 bench configuration) must match the default remat-chunk CE
-    in both loss and gradients (fp32: the saved dtype = compute dtype,
-    so the comparison is exact up to reduction order)."""
+@pytest.mark.parametrize("masked", [False, True], ids=["mean", "loss_mask"])
+def test_gpt_loss_matches_the_replaying_ce(masked):
+    """``gpt_loss`` (the mean handed to the chunked CE as row weights, the
+    head's gradient from its forward loop) against the per-row chunked CE
+    that replays the head GEMM in backward, then the mean or the masked
+    mean: equal loss and gradients at float32."""
+    from apex_tpu.contrib.xentropy import lm_head_cross_entropy
+    from apex_tpu.transformer.testing import standalone_transformer_lm as lm
+
     cfg = _small_cfg()
-    cfg_s = _small_cfg(ce_save_logits=True)
     params = init_gpt_params(cfg, jax.random.PRNGKey(5))
     tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 8), 0, 128)
     labels = jax.random.randint(jax.random.PRNGKey(7), (2, 8), 0, 128)
-    l1 = gpt_loss(cfg, params, tokens, labels)
-    l2 = gpt_loss(cfg_s, params, tokens, labels)
+    mask = (jax.random.uniform(jax.random.PRNGKey(8), (2, 8)) > 0.3
+            if masked else None)
+
+    def replaying(p):
+        hidden = lm.gpt_hidden(cfg, p, tokens, None, None, True)
+        s, b, h = hidden.shape
+        losses = lm_head_cross_entropy(
+            hidden.reshape(s * b, h), lm._head_weight(cfg, p),
+            labels.T.reshape(s * b), chunk_size=s * b).reshape(s, b).T
+        if mask is None:
+            return jnp.mean(losses)
+        m = mask.astype(jnp.float32)
+        return jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+    with jax.default_matmul_precision("highest"):
+        l1, g1 = jax.value_and_grad(
+            lambda p: gpt_loss(cfg, p, tokens, labels, loss_mask=mask))(params)
+        l2, g2 = jax.value_and_grad(replaying)(params)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
-    g1 = jax.grad(lambda p: gpt_loss(cfg, p, tokens, labels))(params)
-    g2 = jax.grad(lambda p: gpt_loss(cfg_s, p, tokens, labels))(params)
-    np.testing.assert_allclose(
-        np.asarray(g1["embedding"]["word"]),
-        np.asarray(g2["embedding"]["word"]), atol=1e-5,
-    )
-    np.testing.assert_allclose(
-        np.asarray(g1["layers"]["qkv_w"]), np.asarray(g2["layers"]["qkv_w"]),
-        atol=1e-5,
-    )
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g1),
+                            jax.tree_util.tree_leaves(g2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
 
 
 def test_gpt_cpu_offload_matches():
